@@ -868,7 +868,12 @@ class ReplicatedStore(CRDT):
             entry = self.entries.get(k)
             if entry is None:
                 continue
-            d = entry.delta_since(vv_map.get(k))
+            seen = vv_map.get(k)
+            d = entry.delta_since(seen)
+            if d is None and seen is None:
+                # the receiver lacks the key itself: ship the (empty) entry
+                # so that it exists there too, as a full merge would make it
+                d = entry.copy()
             if d is not None:
                 out[k] = d
         return out

@@ -72,7 +72,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
 def flash_attention_bhsd(q: jax.Array, k: jax.Array, v: jax.Array, *,
                          causal: bool = True, window: int = 0,
                          bq: int = DEFAULT_BQ, bk: int = DEFAULT_BK,
-                         interpret: bool = True) -> jax.Array:
+                         interpret: bool) -> jax.Array:
     """q,k,v: (B, H, S, hd).  Returns (B, H, Sq, hd)."""
     B, H, Sq, hd = q.shape
     Sk = k.shape[2]
@@ -104,7 +104,7 @@ def flash_attention_bhsd(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((bq, 1), jnp.float32),      # running sum l
         ],
         interpret=interpret,
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
     )(q, k, v)

@@ -83,7 +83,7 @@ def _mlstm_kernel(q_ref, k_ref, v_ref, li_ref, lf_ref, C0_ref, n0_ref, m0_ref,
 def mlstm_scan_bhsd(q: jax.Array, k: jax.Array, v: jax.Array,
                     log_i: jax.Array, log_f: jax.Array,
                     C0: jax.Array, n0: jax.Array, m0: jax.Array, *,
-                    chunk: int = DEFAULT_CHUNK, interpret: bool = True):
+                    chunk: int = DEFAULT_CHUNK, interpret: bool):
     """q,k,v: (B,H,S,hd) with k pre-scaled; log_i/log_f: (B,H,S);
     C0: (B,H,hd,hd), n0: (B,H,hd), m0: (B,H).
     Returns (h (B,H,S,hd), C_T, n_T, m_T)."""
@@ -124,6 +124,6 @@ def mlstm_scan_bhsd(q: jax.Array, k: jax.Array, v: jax.Array,
             pltpu.VMEM((1, 1), jnp.float32),
         ],
         interpret=interpret,
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
     )(q, k, v, log_i, log_f, C0, n0, m0)

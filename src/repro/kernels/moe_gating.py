@@ -46,7 +46,7 @@ def _gating_kernel(logits_ref, w_ref, idx_ref, probs_ref, *, K: int, E: int):
 
 
 def moe_gating_tokens(logits: jax.Array, k: int, *, bt: int = DEFAULT_BT,
-                      interpret: bool = True):
+                      interpret: bool):
     """logits: (T, E) → (weights (T,k), experts (T,k) int32, probs (T,E))."""
     T, E = logits.shape
     bt = min(bt, T)

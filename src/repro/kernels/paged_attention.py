@@ -141,8 +141,8 @@ def _paged_kernel(bt_ref, len_ref, q_ref, kn_ref, vn_ref, k_ref, v_ref,
     k = k_ref[0].astype(jnp.float32)               # (page, Hk, hd)
     v = v_ref[0].astype(jnp.float32)
     if quantized:
-        k = k * ks_ref[0][None, :, None]
-        v = v * vs_ref[0][None, :, None]
+        k = k * ks_ref[0][:, :, None]               # (1, Hk, 1) scales
+        v = v * vs_ref[0][:, :, None]
     kT = jnp.transpose(k, (1, 0, 2))               # (Hk, page, hd)
     vT = jnp.transpose(v, (1, 0, 2))
     s = jax.lax.dot_general(q3, kT,
@@ -173,7 +173,7 @@ def paged_attention_pallas(q: jax.Array, k_pool: jax.Array,
                            v_new: jax.Array,
                            k_scales: Optional[jax.Array] = None,
                            v_scales: Optional[jax.Array] = None, *,
-                           interpret: bool = True) -> jax.Array:
+                           interpret: bool) -> jax.Array:
     """Same contract as :func:`paged_attention_jnp`, as a pallas_call."""
     M, H, hd = q.shape
     P, page, Hk, _ = k_pool.shape
@@ -197,11 +197,14 @@ def paged_attention_pallas(q: jax.Array, k_pool: jax.Array,
     ]
     args = [q, k_new, v_new, k_pool, v_pool]
     if quantized:
+        # scales viewed as (P, 1, Hk): a (1, 1, Hk) block spans the full
+        # last two dims, which the TPU tiling rule accepts; a (1, Hk)
+        # block over (P, Hk) would be a sub-8-row slice and is refused
         in_specs += [
-            pl.BlockSpec((1, Hk), lambda m, p, bt, ln: (bt[m, p], 0)),
-            pl.BlockSpec((1, Hk), lambda m, p, bt, ln: (bt[m, p], 0)),
+            pl.BlockSpec((1, 1, Hk), lambda m, p, bt, ln: (bt[m, p], 0, 0)),
+            pl.BlockSpec((1, 1, Hk), lambda m, p, bt, ln: (bt[m, p], 0, 0)),
         ]
-        args += [k_scales, v_scales]
+        args += [k_scales.reshape(P, 1, Hk), v_scales.reshape(P, 1, Hk)]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
@@ -219,6 +222,6 @@ def paged_attention_pallas(q: jax.Array, k_pool: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((M, H, hd), q.dtype),
         interpret=interpret,
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
     )(block_tables.astype(jnp.int32), lengths.astype(jnp.int32), *args)

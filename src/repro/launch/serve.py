@@ -27,11 +27,13 @@ def main() -> None:
     args = ap.parse_args()
 
     from repro.configs import get_config
+    from repro.launch.compile_cache import configure_compile_cache
     from repro.models import ops_for
     from repro.serving import GenerationEngine
 
+    configure_compile_cache()
     cfg = get_config(args.arch)
-    if args.reduced or jax.default_backend() == "cpu":
+    if args.reduced:
         cfg = cfg.reduced()
     ops = ops_for(cfg)
     key = jax.random.PRNGKey(args.seed)

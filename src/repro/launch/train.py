@@ -3,11 +3,12 @@
     PYTHONPATH=src python -m repro.launch.train --arch granite-8b \
         --reduced --steps 100 --batch 8 --seq 256
 
-On this CPU container ``--reduced`` trains a smoke-scale variant of the
-chosen family.  On a real TPU slice, drop ``--reduced`` and the same entry
-point builds the production mesh and pjit-shards the full config with the
-dry-run's shardings (the step function and sharding rules are exactly the
-ones ``repro.launch.dryrun`` proves out).
+Trains on one device of whatever backend JAX finds (no mesh, no
+sharding), so without ``--reduced`` the config must fit that device
+whole.  ``--reduced`` trains a smoke-scale variant of the family
+(``--d-model``/``--layers``/``--vocab``); the config is never cut unless
+the flag is given.  The multi-device shardings are only compiled, not run,
+by ``repro.launch.dryrun``.
 """
 
 from __future__ import annotations
@@ -26,7 +27,7 @@ def main() -> None:
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=256)
     ap.add_argument("--reduced", action="store_true",
-                    help="train the reduced (smoke) variant on CPU")
+                    help="train the reduced (smoke) variant")
     ap.add_argument("--d-model", type=int, default=256)
     ap.add_argument("--layers", type=int, default=2)
     ap.add_argument("--vocab", type=int, default=2048)
@@ -39,11 +40,13 @@ def main() -> None:
 
     from repro.configs import get_config
     from repro.data import make_batch_iterator
+    from repro.launch.compile_cache import configure_compile_cache
     from repro.optim import cosine_schedule, wsd_schedule
     from repro.train import Trainer, make_train_step, train_state_init
 
+    configure_compile_cache()
     cfg = get_config(args.arch)
-    if args.reduced or jax.default_backend() == "cpu":
+    if args.reduced:
         cfg = cfg.reduced(n_layers=args.layers, d_model=args.d_model,
                           vocab=args.vocab)
     n_params = sum(int(np.prod(s.shape)) for s in jax.tree.leaves(

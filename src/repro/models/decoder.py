@@ -56,14 +56,27 @@ def init_block(cfg: ModelConfig, key: jax.Array, dtype: Any) -> Params:
 
 def init_params(cfg: ModelConfig, key: jax.Array,
                 dtype: Any = jnp.float32) -> Params:
+    return init_stage(cfg, key, 0, cfg.n_layers, True, True, dtype)
+
+
+def init_stage(cfg: ModelConfig, key: jax.Array, lo: int, hi: int,
+               embed: bool, head: bool, dtype: Any = jnp.float32) -> Params:
+    """Layers ``[lo, hi)`` of :func:`init_params`, plus the embedding and
+    the final norm + output head when asked, without making the rest of
+    the model.  One key per layer, so every leaf is bitwise equal to the
+    same slice of the whole init (run eagerly, as the whole init is: a
+    jitted init may round differently)."""
     ks = jax.random.split(key, 4)
-    params: Params = {
-        "embed": dense_init(ks[0], (cfg.vocab, cfg.d_model), dtype, scale=0.02),
-        "final_norm": jnp.ones((cfg.d_model,), dtype),
-    }
-    if not cfg.tie_embeddings:
-        params["lm_head"] = dense_init(ks[1], (cfg.d_model, cfg.vocab), dtype)
-    layer_keys = jax.random.split(ks[2], cfg.n_layers)
+    params: Params = {}
+    if embed:
+        params["embed"] = dense_init(ks[0], (cfg.vocab, cfg.d_model), dtype,
+                                     scale=0.02)
+    if head:
+        params["final_norm"] = jnp.ones((cfg.d_model,), dtype)
+        if not cfg.tie_embeddings:
+            params["lm_head"] = dense_init(ks[1], (cfg.d_model, cfg.vocab),
+                                           dtype)
+    layer_keys = jax.random.split(ks[2], cfg.n_layers)[lo:hi]
     if cfg.arch == "ssm":
         params["blocks"] = [init_block(cfg, k, dtype) for k in layer_keys]
     else:
@@ -159,29 +172,38 @@ def _embed(cfg: ModelConfig, params: Params, batch: Dict[str, jax.Array],
     return x, positions
 
 
+def apply_blocks(cfg: ModelConfig, blocks: Any, x: jax.Array,
+                 positions: jax.Array, first_layer: int = 0,
+                 ) -> Tuple[jax.Array, jax.Array]:
+    """Full-sequence pass of ``x`` through a stack of blocks (all of the
+    model's, or one pipeline stage's starting at ``first_layer``).
+    Returns (x, aux_loss)."""
+    if cfg.arch == "ssm":
+        aux = jnp.zeros((), jnp.float32)
+        for i, bp in enumerate(blocks):
+            x, _, a = run_block(cfg, bp, x, positions,
+                                layer_idx=first_layer + i)
+            aux = aux + a
+        return x, aux
+
+    def body(carry, bp):
+        x, aux = carry
+        fn = run_block
+        if cfg.remat:
+            fn = jax.checkpoint(
+                functools.partial(run_block), static_argnums=(0,))
+        x, _, a = fn(cfg, bp, x, positions)
+        return (x, aux + a), None
+
+    (x, aux), _ = jax.lax.scan(body, (x, jnp.zeros((), jnp.float32)), blocks)
+    return x, aux
+
+
 def forward(params: Params, cfg: ModelConfig,
             batch: Dict[str, jax.Array]) -> Tuple[jax.Array, jax.Array]:
     """Full-sequence logits.  Returns (logits (B,S,V), aux_loss)."""
     x, positions = _embed(cfg, params, batch)
-
-    if cfg.arch == "ssm":
-        aux = jnp.zeros((), jnp.float32)
-        for i, bp in enumerate(params["blocks"]):
-            x, _, a = run_block(cfg, bp, x, positions, layer_idx=i)
-            aux = aux + a
-    else:
-        def body(carry, bp):
-            x, aux = carry
-            fn = run_block
-            if cfg.remat:
-                fn = jax.checkpoint(
-                    functools.partial(run_block), static_argnums=(0,))
-            x, _, a = fn(cfg, bp, x, positions)
-            return (x, aux + a), None
-
-        (x, aux), _ = jax.lax.scan(
-            body, (x, jnp.zeros((), jnp.float32)), params["blocks"])
-
+    x, aux = apply_blocks(cfg, params["blocks"], x, positions)
     x = rms_norm(constrain_batch(x, cfg), params["final_norm"], cfg.norm_eps)
     head = params.get("lm_head")
     if head is None:

@@ -48,6 +48,7 @@ greedy decode through the batched plane still matches
 
 from __future__ import annotations
 
+import copy
 import itertools
 from collections import deque
 from typing import Any, Deque, Dict, Generator, List, Optional, Tuple
@@ -166,6 +167,16 @@ class KVPool:
         self.vp[:, pid, offset:offset + t] = v
 
 
+def _with_params(module: Any, params: Any) -> Any:
+    """A shallow copy of ``module`` that reads ``params``: lets a jitted
+    function take the weights as an argument.  A closed-over array would
+    be embedded in the executable as a constant — at published widths,
+    gigabytes of literals in every compiled program."""
+    bound = copy.copy(module)
+    bound.params = params
+    return bound
+
+
 class SlotState:
     """One occupied decode slot: a session pinned to a paged KV cache."""
 
@@ -235,10 +246,11 @@ class BatchEngine:
         # FIFO of (session, event) waiting for a slot; a freed slot is
         # succeed()ed straight into the head waiter's event
         self._queue: Deque[Tuple[Any, Any]] = deque()
-        # params are closed over as jit constants; shapes key the trace
-        # cache, so steady-state decode is one compiled call per shape
+        # params are jit arguments (never closed over); shapes key the
+        # trace cache, so steady-state decode is one compiled call per shape
         self._apply = jax.jit(
-            lambda x, pos, cache: module.apply(x, pos, cache))
+            lambda params, x, pos, cache:
+            _with_params(module, params).apply(x, pos, cache))
         supported = self._supports_fused(module)
         self.fused = supported if fused is None else (fused and supported)
         self.kv_dtype = kv_dtype if self.fused else "fp32"
@@ -267,10 +279,10 @@ class BatchEngine:
                 and hasattr(module, "_layer_params"))
 
     def _build_fused_apply(self):
-        m = self.module
-        cfg = m.cfg
+        cfg = self.module.cfg
 
-        def fused(x, positions, bt, lengths, kp, vp, ks, vs):
+        def fused(params, x, positions, bt, lengths, kp, vp, ks, vs):
+            m = _with_params(self.module, params)
             if m.is_first and x.dtype == jnp.int32:
                 h = m.embed(x[:, None])                      # (M, 1, D)
             else:
@@ -525,7 +537,8 @@ class BatchEngine:
             # prefill runs through the unchanged dense path, then the
             # resulting k/v move into pool pages and the dense cache is
             # dropped — steady-state decode never touches it again
-            out, cache = self._apply(xj, self._positions(0, 1, S), cache)
+            out, cache = self._apply(m.params, xj,
+                                     self._positions(0, 1, S), cache)
             st.cache = None
             st.length = S
             st.pages = self._pool.alloc(cap // self.page_size)
@@ -534,8 +547,8 @@ class BatchEngine:
             self._pool_write_prefill(st, k, v)
         else:
             self._fallback_pages += cap // self.page_size
-            out, st.cache = self._apply(xj, self._positions(0, 1, S),
-                                        st.cache)
+            out, st.cache = self._apply(m.params, xj,
+                                        self._positions(0, 1, S), st.cache)
         self._note_pages()
         if m.is_last:
             out = m.head(out[:, -1:])[:, 0]       # (1, vocab)
@@ -600,7 +613,7 @@ class BatchEngine:
             lengths[r] = st.length
         pool = self._pool
         out, nk, nv = self._fused_apply(
-            jnp.asarray(xb), jnp.asarray(lengths[:, None]),
+            m.params, jnp.asarray(xb), jnp.asarray(lengths[:, None]),
             jnp.asarray(bt), jnp.asarray(lengths),
             jnp.asarray(pool.kp), jnp.asarray(pool.vp),
             None if pool.ks is None else jnp.asarray(pool.ks),
@@ -639,7 +652,7 @@ class BatchEngine:
             cur = int(st.cache["len"])
             self._ensure_capacity(st, cur + 1)
             out, st.cache = self._apply(
-                xi, self._positions(cur, 1, 1), st.cache)
+                m.params, xi, self._positions(cur, 1, 1), st.cache)
             if m.is_last:
                 out = m.head(out)[:, 0]           # (1, vocab)
             else:

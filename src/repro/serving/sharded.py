@@ -96,6 +96,23 @@ def split_params(cfg: ModelConfig, params: Any,
     return shards
 
 
+def init_shard_params(cfg: ModelConfig, key: jax.Array,
+                      plan: List[Tuple[int, int]], i: int,
+                      dtype: Any = jnp.float32) -> Dict[str, Any]:
+    """Shard ``i`` of ``plan`` initialised alone: bitwise equal to
+    ``split_params(cfg, decoder.init_params(cfg, key, dtype), plan)[i]``
+    without making the rest of the model.  Arrays land on the current
+    default device (``jax.default_device``)."""
+    lo, hi = plan[i]
+    first, last = i == 0, i == len(plan) - 1
+    tied_head = last and cfg.tie_embeddings
+    sub = decoder.init_stage(cfg, key, lo, hi, embed=first or tied_head,
+                             head=last, dtype=dtype)
+    if tied_head:
+        sub["embed_out"] = sub["embed"] if first else sub.pop("embed")
+    return sub
+
+
 class ShardModule:
     """Applies one shard's layer range, with per-session decode caches."""
 
@@ -811,21 +828,54 @@ class ShardClient:
         return None
 
 
-def deploy_sharded(nodes: List[LatticaNode], cfg: ModelConfig, params: Any,
-                   fleet: str, replicas: int = 1, n_slots: int = 8,
-                   page_size: int = 32,
-                   kv_dtype: str = "fp32") -> List[ShardServer]:
+def deploy_sharded(nodes: List[LatticaNode], cfg: ModelConfig,
+                   params: Optional[Any], fleet: str, replicas: int = 1,
+                   n_slots: int = 8, page_size: int = 32,
+                   kv_dtype: str = "fp32",
+                   init_key: Optional[jax.Array] = None,
+                   devices: Optional[List[Any]] = None) -> List[ShardServer]:
     """Place ``n_shards = len(nodes) // replicas`` pipeline shards, each
-    replicated ``replicas`` times across the given nodes."""
+    replicated ``replicas`` times across the given nodes.
+
+    Servers go round-robin over ``devices`` (default ``jax.devices()``),
+    and each server's params live on its device: sliced from the whole
+    tree ``params``, or — with ``init_key`` instead — initialised there
+    shard by shard (:func:`init_shard_params`), so the whole model never
+    exists on one device.  A shard's params exist once per device, shared
+    by replicas placed together, and a tied embedding is shared by the
+    first and last shards when they share a device."""
+    if (params is None) == (init_key is None):
+        raise ValueError("give exactly one of params and init_key")
+    devices = list(devices or jax.devices())
     n_shards = len(nodes) // replicas
     plan = plan_shards(cfg, n_shards)
-    parts = split_params(cfg, params, plan)
+    whole = None if params is None else split_params(cfg, params, plan)
+    placed: Dict[Tuple[int, Any], Dict[str, Any]] = {}
+    embeds: Dict[Any, jax.Array] = {}
+
+    def part_on(i: int, dev: Any) -> Dict[str, Any]:
+        if (i, dev) not in placed:
+            if whole is not None:
+                part = jax.device_put(whole[i], dev)
+            else:
+                with jax.default_device(dev):
+                    part = init_shard_params(cfg, init_key, plan, i)
+                # commits the arrays to ``dev`` (same buffers, no copy)
+                part = jax.device_put(part, dev)
+            for name in ("embed", "embed_out"):
+                if name in part and cfg.tie_embeddings:
+                    part[name] = embeds.setdefault(dev, part[name])
+            placed[(i, dev)] = part
+        return placed[(i, dev)]
+
     servers = []
     for r in range(replicas):
         for i, (lo, hi) in enumerate(plan):
-            node = nodes[r * n_shards + i]
-            module = ShardModule(cfg, parts[i], (lo, hi),
-                                 is_first=(i == 0), is_last=(i == n_shards - 1))
+            k = r * n_shards + i
+            node = nodes[k]
+            module = ShardModule(cfg, part_on(i, devices[k % len(devices)]),
+                                 (lo, hi), is_first=(i == 0),
+                                 is_last=(i == n_shards - 1))
             servers.append(ShardServer(node, cfg, fleet, i, module,
                                        n_slots=n_slots, page_size=page_size,
                                        kv_dtype=kv_dtype))
@@ -849,7 +899,7 @@ def serve_fleet(nodes: List[LatticaNode], cfg: ModelConfig, params: Any,
         yield from s.announce()
     n_shards = len(servers) // replicas
     plan = plan_shards(cfg, n_shards)
-    parts = split_params(cfg, params, plan)
+    parts = [s.module.params for s in servers[:n_shards]]
     pub = publisher or nodes[0]
     yield from publish_serving_plan(pub, fleet, plan, parts)
     for s in servers:

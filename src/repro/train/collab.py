@@ -62,7 +62,8 @@ from repro.models import ops_for
 from repro.models.config import ModelConfig
 
 from .compress import (average_flat, compress_pseudograd, flat_digest,
-                       flat_from_entries, pseudo_gradient, tree_to_flat)
+                       flat_from_entries, pseudo_gradient, slabs,
+                       tree_to_flat)
 from .step import TrainState, make_train_step
 
 __all__ = ["CollabConfig", "CollabService", "CollabWorker", "serve_collab"]
@@ -145,7 +146,10 @@ class CollabWorker:
         self.name = node.host.name
         self.step_seconds = step_seconds
         self.data = data
-        self._like = state.params
+        #: the params' tree structure and shapes, not the arrays: holding
+        #: the initial params would keep a dead copy on the device
+        self._like = jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), state.params)
         self._state = state
         self.step_fn = jax.jit(make_train_step(cfg, schedule))
         ops = ops_for(cfg)
@@ -273,6 +277,7 @@ class CollabWorker:
         # -- compress + publish the pseudo-gradient as a content DAG
         end_flat = tree_to_flat(self._state.params)
         grad = pseudo_gradient(start_flat, end_flat)
+        del start_flat, end_flat    # host copies of the model, no longer needed
         for k in grad:
             grad[k] = grad[k] + self.residual[k]
         parts, sent, cstats = compress_pseudograd(
@@ -297,6 +302,7 @@ class CollabWorker:
         else:
             # our contribution missed the close: defer the WHOLE delta
             self.residual = grad
+        del grad, sent              # a model's worth of host memory each
         yield from self._apply_round(r, closed)
         if self.eval_batch is not None and self._eval_fn is not None:
             loss = float(self._eval_fn(self.outer_params(), self.eval_batch))
@@ -360,10 +366,12 @@ class CollabWorker:
         for w in closed:                # sorted tuple: deterministic order
             flat = yield from self._fetch_contrib(r, w)
             grads.append(flat)
+        avg = average_flat(grads)
+        del grads                       # one decoded model per contributor
         self._pre_round[r] = (
             {k: v.copy() for k, v in self.outer_flat.items()},
             {k: v.copy() for k, v in self.outer_mom.items()})
-        self._outer_step(average_flat(grads))
+        self._outer_step(avg)
         self._applied[r] = closed
         self.outer_round = r + 1
         self.stats["rounds_closed"] += 1
@@ -373,13 +381,19 @@ class CollabWorker:
     def _outer_step(self, g: Dict[str, np.ndarray]) -> None:
         lr, mu = self.ccfg.outer_lr, self.ccfg.outer_momentum
         for k in sorted(g):
-            m = mu * self.outer_mom[k].astype(np.float64) \
-                + g[k].astype(np.float64)
-            upd = g[k].astype(np.float64) + mu * m if self.ccfg.nesterov else m
-            self.outer_flat[k] = (
-                self.outer_flat[k].astype(np.float64) - lr * upd
-            ).astype(np.float32)
-            self.outer_mom[k] = m.astype(np.float32)
+            shape = self.outer_flat[k].shape
+            p = self.outer_flat[k].reshape(-1)
+            mom = self.outer_mom[k].reshape(-1)
+            gk = g[k].reshape(-1)
+            new_p, new_m = np.empty_like(p), np.empty_like(mom)
+            for s in slabs(p.size):
+                gs = gk[s].astype(np.float64)
+                m = mu * mom[s].astype(np.float64) + gs
+                upd = gs + mu * m if self.ccfg.nesterov else m
+                new_p[s] = p[s].astype(np.float64) - lr * upd
+                new_m[s] = m
+            self.outer_flat[k] = new_p.reshape(shape)
+            self.outer_mom[k] = new_m.reshape(shape)
 
     def _fetch_contrib(self, r: int, worker: str) -> Generator:
         """Resolve + swarm-fetch one contribution DAG; decode to a flat

@@ -48,6 +48,17 @@ DEFAULT_TOPK_FRAC = 0.05
 #: entry would cost more than it saves
 SPARSE_MIN_SIZE = 256
 
+#: elements per slab of the float64 elementwise math: a leaf's float64
+#: temporaries stay tens of MB however large the leaf (a published
+#: vocabulary's embedding is ~3e8 elements, gigabytes per temporary)
+SLAB = 1 << 22
+
+
+def slabs(n: int):
+    """Slices covering ``range(n)`` in steps of :data:`SLAB`.  Elementwise
+    math done slab by slab is bitwise equal to the whole-array math."""
+    return (slice(i, min(i + SLAB, n)) for i in range(0, n, SLAB))
+
 
 def tree_to_flat(params: Any) -> Dict[str, np.ndarray]:
     """``{path: float32 ndarray}`` view of a pytree, sorted-path keyed
@@ -61,9 +72,14 @@ def pseudo_gradient(start: Dict[str, np.ndarray],
     """Outer delta ``start - end`` per leaf: the direction the inner
     optimizer moved, expressed as a gradient for the outer optimizer
     (which *subtracts* it)."""
-    return {k: (start[k].astype(np.float64)
-                - end[k].astype(np.float64)).astype(np.float32)
-            for k in start}
+    out: Dict[str, np.ndarray] = {}
+    for k in start:
+        a, b = start[k].reshape(-1), end[k].reshape(-1)
+        d = np.empty(a.shape, np.float32)
+        for s in slabs(a.size):
+            d[s] = a[s].astype(np.float64) - b[s].astype(np.float64)
+        out[k] = d.reshape(start[k].shape)
+    return out
 
 
 def topk_select(arr: np.ndarray, k: int) -> Tuple[np.ndarray, np.ndarray]:
@@ -139,10 +155,14 @@ def average_flat(grads: List[Dict[str, np.ndarray]],
         raise ValueError("cannot average zero contributions")
     out: Dict[str, np.ndarray] = {}
     for k in sorted(grads[0]):
-        acc = np.zeros(grads[0][k].shape, np.float64)
-        for g in grads:
-            acc += g[k].astype(np.float64)
-        out[k] = (acc / len(grads)).astype(np.float32)
+        flats = [g[k].reshape(-1) for g in grads]
+        mean = np.empty(flats[0].shape, np.float32)
+        for s in slabs(mean.size):
+            acc = np.zeros(s.stop - s.start, np.float64)
+            for f in flats:
+                acc += f[s].astype(np.float64)
+            mean[s] = acc / len(grads)
+        out[k] = mean.reshape(grads[0][k].shape)
     return out
 
 

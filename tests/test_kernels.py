@@ -33,7 +33,7 @@ def test_flash_attention_sweep(B, H, Sq, Sk, hd, dtype, window):
     k = jax.random.normal(ks[1], (B, H, Sk, hd), jnp.float32).astype(dtype)
     v = jax.random.normal(ks[2], (B, H, Sk, hd), jnp.float32).astype(dtype)
     out = flash_attention_bhsd(q, k, v, causal=True, window=window,
-                               bq=128, bk=128)
+                               bq=128, bk=128, interpret=True)
     ref = attention_ref(q, k, v, causal=True, window=window)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32),
@@ -45,7 +45,8 @@ def test_flash_attention_noncausal():
     q = jax.random.normal(ks[0], (1, 2, 128, 64))
     k = jax.random.normal(ks[1], (1, 2, 256, 64))
     v = jax.random.normal(ks[2], (1, 2, 256, 64))
-    out = flash_attention_bhsd(q, k, v, causal=False, bq=128, bk=128)
+    out = flash_attention_bhsd(q, k, v, causal=False, bq=128, bk=128,
+                               interpret=True)
     ref = attention_ref(q, k, v, causal=False)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
 
@@ -56,7 +57,7 @@ def test_flash_attention_noncausal():
                                    (1024, 64, 8)])
 def test_moe_gating_sweep(T, E, K):
     logits = jax.random.normal(jax.random.PRNGKey(T + E), (T, E)) * 2
-    w, idx, p = moe_gating_tokens(logits, K, bt=256)
+    w, idx, p = moe_gating_tokens(logits, K, bt=256, interpret=True)
     wr, ir, pr = moe_gating_ref(logits, K)
     np.testing.assert_allclose(np.asarray(p), np.asarray(pr), atol=1e-6)
     np.testing.assert_allclose(np.asarray(w), np.asarray(wr), atol=1e-6)
@@ -87,7 +88,7 @@ def test_mlstm_scan_sweep(B, H, S, hd, chunk, dtype):
     m0 = jnp.full((B, H), -1e30)
     h, C, n, m = mlstm_scan_bhsd(q.astype(dtype), k.astype(dtype),
                                  v.astype(dtype), li, lf, C0, n0, m0,
-                                 chunk=chunk)
+                                 chunk=chunk, interpret=True)
     hr, Cr, nr, mr = mlstm_chunk_ref(q, k, v, li, lf, C0, n0, m0)
     tol = _tol(dtype) * 8
     np.testing.assert_allclose(np.asarray(h, np.float32),
@@ -113,10 +114,12 @@ def test_mlstm_scan_nonzero_initial_state():
     # kernel: first half, then second half from the carried state
     h1, C1, n1, m1 = mlstm_scan_bhsd(
         q[:, :, :128], k[:, :, :128], v[:, :, :128],
-        li[:, :, :128], lf[:, :, :128], *zero, chunk=64)
+        li[:, :, :128], lf[:, :, :128], *zero, chunk=64,
+        interpret=True)
     h2, *_ = mlstm_scan_bhsd(
         q[:, :, 128:], k[:, :, 128:], v[:, :, 128:],
-        li[:, :, 128:], lf[:, :, 128:], C1, n1, m1, chunk=64)
+        li[:, :, 128:], lf[:, :, 128:], C1, n1, m1, chunk=64,
+        interpret=True)
     got = jnp.concatenate([h1, h2], axis=2)
     np.testing.assert_allclose(np.asarray(got), np.asarray(hr),
                                atol=1e-4, rtol=1e-4)

@@ -128,3 +128,24 @@ def test_cache_spec_rules():
     cfg2 = get_config("qwen2-moe-a2.7b")  # Hk=16: heads divide
     spec2 = cache_spec("layers/k", (24, 128, 32768, 16, 128), mesh, cfg2)
     assert spec2 == P(None, "data", None, "model", None)
+
+
+def test_compile_cache_follows_env_var_else_repo_dir(monkeypatch):
+    """``JAX_COMPILATION_CACHE_DIR`` wins and nothing else is set;
+    otherwise the cache is the checkout's fixed ``.jax_cache``."""
+    import jax
+
+    from repro.launch import compile_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv(compile_cache.ENV_VAR, "/elsewhere/cache")
+        assert compile_cache.configure_compile_cache() == "/elsewhere/cache"
+        assert jax.config.jax_compilation_cache_dir == before
+        monkeypatch.delenv(compile_cache.ENV_VAR)
+        path = compile_cache.configure_compile_cache()
+        assert path == str(compile_cache.REPO_ROOT / ".jax_cache")
+        assert (compile_cache.REPO_ROOT / "chip_smoke.py").exists()
+        assert jax.config.jax_compilation_cache_dir == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

@@ -23,6 +23,10 @@ HQ = HK * REP
 LENGTHS = [0, PAGE - 1, PAGE, 2 * PAGE + 5]
 
 
+def _pallas_interpret(*args, **kw):
+    return paged_attention_pallas(*args, interpret=True, **kw)
+
+
 def _problem(seed=0, lengths=LENGTHS, pool_pages=None):
     rng = np.random.default_rng(seed)
     M = len(lengths)
@@ -99,7 +103,7 @@ def test_stale_page_contents_never_leak():
         flat_v[ln[m]:] = -1e4
         kp[bt_np[m]] = flat_k.reshape(NP, PAGE, HK, HD)
         vp[bt_np[m]] = flat_v.reshape(NP, PAGE, HK, HD)
-    for fn in (paged_attention_jnp, paged_attention_pallas):
+    for fn in (paged_attention_jnp, _pallas_interpret):
         poisoned = np.asarray(fn(jnp.asarray(q), jnp.asarray(kp),
                                  jnp.asarray(vp), bt, lengths, kn, vn))
         np.testing.assert_allclose(poisoned, base, rtol=2e-5, atol=2e-6)
@@ -125,7 +129,7 @@ def test_int8_pool_error_is_bounded():
         assert (err <= bound[:, None, :, None]).all()
     fp = np.asarray(paged_attention_jnp(jnp.asarray(q), kp, vp, bt,
                                         lengths, kn, vn))
-    for fn in (paged_attention_jnp, paged_attention_pallas):
+    for fn in (paged_attention_jnp, _pallas_interpret):
         qa = np.asarray(fn(jnp.asarray(q), jnp.asarray(kq), jnp.asarray(vq),
                            bt, lengths, kn, vn,
                            k_scales=jnp.asarray(ks), v_scales=jnp.asarray(vs)))
@@ -143,7 +147,8 @@ def test_int8_quantized_pallas_matches_jnp():
     jn = np.asarray(paged_attention_jnp(
         *common, k_scales=jnp.asarray(ks), v_scales=jnp.asarray(vs)))
     pa = np.asarray(paged_attention_pallas(
-        *common, k_scales=jnp.asarray(ks), v_scales=jnp.asarray(vs)))
+        *common, k_scales=jnp.asarray(ks), v_scales=jnp.asarray(vs),
+        interpret=True))
     np.testing.assert_allclose(pa, jn, rtol=2e-5, atol=2e-6)
 
 

@@ -370,3 +370,34 @@ def test_pressure_monitor_spawns_replica_on_hot_shard(served_v2):
     assert mon.stats["spawned"] >= 1
     spawned = getattr(idle, "shard_servers", [])
     assert spawned and all(s.alive for s in spawned)
+
+
+def _largest_constant(lowered_text):
+    """Elements in the largest literal array of a lowered program."""
+    import re
+    sizes = [int(np.prod([int(d) for d in t.split("x")[:-1]] or [1]))
+             for t in re.findall(r"stablehlo\.constant [^\n]*: tensor<([^>]*)>",
+                                 lowered_text)]
+    return max(sizes, default=0)
+
+
+def test_params_are_jit_arguments_not_constants(model):
+    """Weights enter the prefill and fused decode programs as arguments: a
+    closed-over array would be a literal in every executable (gigabytes
+    at published widths)."""
+    cfg, params = model
+    eng = BatchEngine(_full_module(cfg, params), Sim(seed=0), n_slots=2,
+                      page_size=8)
+    L, Hk, hd = cfg.n_layers, cfg.n_kv_heads, cfg.hd
+    cache = eng.module.init_cache(1, 16)
+    prefill = eng._apply.lower(params, jnp.zeros((1, 8, cfg.d_model)),
+                               jnp.zeros((1, 8), jnp.int32), cache)
+    pool = jnp.zeros((L, 4, 8, Hk, hd))
+    fused = eng._fused_apply.lower(
+        params, jnp.zeros((2,), jnp.int32), jnp.zeros((2, 1), jnp.int32),
+        jnp.zeros((2, 2), jnp.int32), jnp.zeros((2,), jnp.int32), pool,
+        pool, None, None)
+    smallest_weight = min(a.size for a in jax.tree.leaves(params)
+                          if a.ndim >= 2)
+    for lowered in (prefill, fused):
+        assert _largest_constant(lowered.as_text()) < smallest_weight
