@@ -1,0 +1,11 @@
+"""Mean wait of a fused decode step for its arguments: the host-to-device
+copy of the shard's whole KV page pool (and the small step inputs)."""
+
+from chipbench.record import calls_in_window
+
+
+def read(run):
+    calls = calls_in_window(run.calls.get("fused", []), run.window)
+    if not calls:
+        return None
+    return 1e3 * sum(c.info["input_wait"] for c in calls) / len(calls)
