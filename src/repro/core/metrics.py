@@ -60,7 +60,8 @@ def node_snapshot(node: "LatticaNode") -> Dict[str, Any]:
         snap["serving.slots_used"] = sum(s.engine.slots_used for s in servers)
         snap["serving.queue_depth"] = sum(s.engine.queue_depth for s in servers)
         for key in ("admitted", "evicted", "steps", "step_sessions",
-                    "slot_reuse", "queue_peak", "pages_peak", "idle_evicted"):
+                    "slot_reuse", "queue_peak", "pages_peak", "idle_evicted",
+                    "kv_bytes_uploaded", "kv_bytes_live"):
             snap[f"serving.{key}"] = sum(s.engine.stats[key] for s in servers)
     clients = getattr(node, "shard_clients", [])
     if clients:

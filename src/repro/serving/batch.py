@@ -57,6 +57,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import tracing
 from repro.core.simnet import Sim
 from repro.kernels.paged_attention import paged_attention_jnp
 from repro.models.common import apply_rope, rms_norm, run_mlp
@@ -247,10 +248,12 @@ class BatchEngine:
         # succeed()ed straight into the head waiter's event
         self._queue: Deque[Tuple[Any, Any]] = deque()
         # params are jit arguments (never closed over); shapes key the
-        # trace cache, so steady-state decode is one compiled call per shape
-        self._apply = jax.jit(
-            lambda params, x, pos, cache:
-            _with_params(module, params).apply(x, pos, cache))
+        # trace cache, so steady-state decode is one compiled call per shape.
+        # Named, so that prefill shows in a profile as ``jit_dense_apply``.
+        def dense_apply(params, x, pos, cache):
+            return _with_params(module, params).apply(x, pos, cache)
+
+        self._apply = jax.jit(dense_apply)
         supported = self._supports_fused(module)
         self.fused = supported if fused is None else (fused and supported)
         self.kv_dtype = kv_dtype if self.fused else "fp32"
@@ -262,9 +265,12 @@ class BatchEngine:
                                 page_size, quant=(self.kv_dtype == "int8"))
             self._fused_apply = jax.jit(self._build_fused_apply())
         self.stats = {
-            "admitted": 0, "evicted": 0, "prefills": 0, "steps": 0,
+            "admitted": 0, "evicted": 0, "steps": 0,
             "step_sessions": 0, "queue_peak": 0, "slot_reuse": 0,
             "pages": 0, "pages_peak": 0, "idle_evicted": 0,
+            # fused steps: pool bytes handed to the device, and of those the
+            # live rows' cached bytes
+            "kv_bytes_uploaded": 0, "kv_bytes_live": 0,
         }
         sim.register_leak_check(
             f"kv.pages:{next(_ENGINE_SEQ)}", self._pages_in_use)
@@ -521,40 +527,43 @@ class BatchEngine:
     def _prefill(self, session: Any, slot: int, x: np.ndarray,
                  max_len: int) -> Tuple[np.ndarray, float]:
         m = self.module
-        self.stats["prefills"] += 1
-        self.stats["admitted"] += 1
-        if self._slot_last_session[slot] not in (None, session):
-            self.stats["slot_reuse"] += 1
-        self._slot_last_session[slot] = session
-        xj = jnp.asarray(x)
-        if m.is_first and xj.dtype == jnp.int32:
-            xj = m.embed(xj)
-        S = xj.shape[1]
-        cache, cap = self._alloc_cache(S + 1)
-        st = SlotState(session, slot, cache, cap, max_len, self.sim.now)
-        self.by_session[session] = st
-        if self.fused:
-            # prefill runs through the unchanged dense path, then the
-            # resulting k/v move into pool pages and the dense cache is
-            # dropped — steady-state decode never touches it again
-            out, cache = self._apply(m.params, xj,
-                                     self._positions(0, 1, S), cache)
-            st.cache = None
-            st.length = S
-            st.pages = self._pool.alloc(cap // self.page_size)
-            k = np.asarray(cache["layers"]["k"][:, 0, :S], np.float32)
-            v = np.asarray(cache["layers"]["v"][:, 0, :S], np.float32)
-            self._pool_write_prefill(st, k, v)
-        else:
-            self._fallback_pages += cap // self.page_size
-            out, st.cache = self._apply(m.params, xj,
-                                        self._positions(0, 1, S), st.cache)
-        self._note_pages()
-        if m.is_last:
-            out = m.head(out[:, -1:])[:, 0]       # (1, vocab)
-        cost = self._cost(m.flops(S),
-                          self._weight_bytes() + self._slot_kv_bytes(st))
-        return np.asarray(out), cost
+        with tracing.span("engine.prefill", flow=session, session=session,
+                          tokens=int(x.shape[1])):
+            self.stats["admitted"] += 1
+            if self._slot_last_session[slot] not in (None, session):
+                self.stats["slot_reuse"] += 1
+            self._slot_last_session[slot] = session
+            xj = jnp.asarray(x)
+            if m.is_first and xj.dtype == jnp.int32:
+                xj = m.embed(xj)
+            S = xj.shape[1]
+            cache, cap = self._alloc_cache(S + 1)
+            st = SlotState(session, slot, cache, cap, max_len, self.sim.now)
+            self.by_session[session] = st
+            if self.fused:
+                # prefill runs through the unchanged dense path, then the
+                # resulting k/v move into pool pages and the dense cache is
+                # dropped — steady-state decode never touches it again
+                out, cache = self._apply(m.params, xj,
+                                         self._positions(0, 1, S), cache)
+                st.cache = None
+                st.length = S
+                st.pages = self._pool.alloc(cap // self.page_size)
+                k = np.asarray(cache["layers"]["k"][:, 0, :S], np.float32)
+                v = np.asarray(cache["layers"]["v"][:, 0, :S], np.float32)
+                with tracing.span("kv.write_prefill", pages=len(st.pages)):
+                    self._pool_write_prefill(st, k, v)
+            else:
+                self._fallback_pages += cap // self.page_size
+                out, st.cache = self._apply(m.params, xj,
+                                            self._positions(0, 1, S),
+                                            st.cache)
+            self._note_pages()
+            if m.is_last:
+                out = m.head(out[:, -1:])[:, 0]       # (1, vocab)
+            cost = self._cost(m.flops(S),
+                              self._weight_bytes() + self._slot_kv_bytes(st))
+            return np.asarray(out), cost
 
     def step(self, sessions: List[Any], x: np.ndarray,
              evict: Optional[List[Any]] = None,
@@ -570,12 +579,18 @@ class BatchEngine:
         slots are available to queued admissions within the same step.
         Returns ``(out, served, cost_seconds)``.
         """
-        if evict:
-            self.close(evict)
-        self.stats["steps"] += 1
-        if self.fused:
-            return self._step_fused(sessions, x)
-        return self._step_unfused(sessions, x)
+        lead = sessions[0] if sessions else None    # names the round's flow
+        with tracing.span("engine.step", flow=lead,
+                          sessions=len(sessions)) as sp:
+            if evict:
+                self.close(evict)
+            self.stats["steps"] += 1
+            if self.fused:
+                out, served, cost = self._step_fused(sessions, x)
+            else:
+                out, served, cost = self._step_unfused(sessions, x)
+            sp.set(rows=len(served))
+            return out, served, cost
 
     def _step_fused(self, sessions: List[Any], x: np.ndarray,
                     ) -> Tuple[np.ndarray, List[Any], float]:
@@ -612,21 +627,35 @@ class BatchEngine:
             bt[r, :len(st.pages)] = st.pages
             lengths[r] = st.length
         pool = self._pool
-        out, nk, nv = self._fused_apply(
-            m.params, jnp.asarray(xb), jnp.asarray(lengths[:, None]),
-            jnp.asarray(bt), jnp.asarray(lengths),
-            jnp.asarray(pool.kp), jnp.asarray(pool.vp),
-            None if pool.ks is None else jnp.asarray(pool.ks),
-            None if pool.vs is None else jnp.asarray(pool.vs))
-        out = np.asarray(out)
-        nk = np.asarray(nk, np.float32)
-        nv = np.asarray(nv, np.float32)
+        # the whole pool goes to the device every step; the live rows'
+        # pages (allocated above, so appends do not change them) are the
+        # part the step reads
+        uploaded = sum(a.nbytes for a in (pool.kp, pool.vp, pool.ks, pool.vs)
+                       if a is not None)
+        kv_read = sum(self._slot_kv_bytes(st) for _, _, st in live)
+        self.stats["kv_bytes_uploaded"] += uploaded
+        self.stats["kv_bytes_live"] += int(kv_read)
+        # the step's inputs go to the device before the pool, as they did
+        # before the spans: a small copy queued behind the pool's would wait
+        # for it outside the pool's copy
+        inputs = (jnp.asarray(xb), jnp.asarray(lengths[:, None]),
+                  jnp.asarray(bt), jnp.asarray(lengths))
+        with tracing.span("kv.upload", bytes=uploaded,
+                          live_bytes=int(kv_read)):
+            kp, vp = jnp.asarray(pool.kp), jnp.asarray(pool.vp)
+            ks = None if pool.ks is None else jnp.asarray(pool.ks)
+            vs = None if pool.vs is None else jnp.asarray(pool.vs)
+        with tracing.span("engine.fused", rows=len(live)):
+            out, nk, nv = self._fused_apply(m.params, *inputs,
+                                            kp, vp, ks, vs)
+            out = np.asarray(out)
+            nk = np.asarray(nk, np.float32)
+            nv = np.asarray(nv, np.float32)
         served: List[Any] = []
-        kv_read = 0.0
-        for r, (_, sid, st) in enumerate(live):
-            self._pool_append(st, nk[:, r], nv[:, r])
-            served.append(sid)
-            kv_read += self._slot_kv_bytes(st)
+        with tracing.span("kv.append"):
+            for r, (_, sid, st) in enumerate(live):
+                self._pool_append(st, nk[:, r], nv[:, r])
+                served.append(sid)
         self.stats["step_sessions"] += len(served)
         # one pass over the weights for the whole batch — the fused win
         cost = self._cost(m.flops(1) * len(served),

@@ -35,12 +35,14 @@ from __future__ import annotations
 import hashlib
 import itertools
 from collections import deque
-from typing import Any, Deque, Dict, Generator, List, Optional, Set, Tuple
+from typing import (Any, Callable, Deque, Dict, Generator, List, Optional,
+                    Set, Tuple)
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import tracing
 from repro.core.dht import PeerInfo
 from repro.core.node import LatticaNode
 from repro.core.rpc import RpcContext, RpcError
@@ -55,6 +57,7 @@ from .batch import PEER_FLOPS, BatchEngine
 from .router import LoadAwareRouter, hedged_call
 
 _session_seq = itertools.count(1)
+_request_seq = itertools.count(1)
 
 
 def shard_key(fleet: str, idx: int) -> bytes:
@@ -247,10 +250,15 @@ class InferenceV2Service(Service):
     def open(self, payload: Any, ctx: RpcContext) -> Generator:
         self._check_alive()
         eng = self.server.engine
-        out, cost = yield from eng.open(
-            tuple(payload["session"]), payload["x"], payload["max_len"])
+        sid = tuple(payload["session"])
+        shard = self.server.shard_idx
+        tracing.phase(sid, "engine.admit_wait", session=sid, shard=shard)
+        out, cost = yield from eng.open(sid, payload["x"], payload["max_len"])
         self._check_alive()     # died while we waited for a slot / computed
+        tracing.phase(sid, "rpc.cpu_charge", session=sid, shard=shard,
+                      virtual_s=cost)
         yield ctx.cpu(cost)
+        tracing.phase(sid, "rpc.open.reply", session=sid, shard=shard)
         return {"x": out}
 
     @unary("infer.v2.step", request=TensorDictCodec(),
@@ -261,7 +269,11 @@ class InferenceV2Service(Service):
         sessions = [tuple(s) for s in payload["sessions"]]
         evict = [tuple(s) for s in payload.get("evict", [])]
         out, served, cost = eng.step(sessions, payload["x"], evict=evict)
+        lead = sessions[0] if sessions else None     # names the round's flow
+        shard = self.server.shard_idx
+        tracing.phase(lead, "rpc.cpu_charge", shard=shard, virtual_s=cost)
         yield ctx.cpu(cost)
+        tracing.phase(lead, "rpc.step.reply", shard=shard)
         return {"x": out, "served": served}
 
     @unary("infer.v2.close", request=pickled(floor=96),
@@ -396,12 +408,20 @@ class ShardServer:
 class _Request:
     """One in-flight generation request inside the v2 driver."""
 
-    __slots__ = ("prompt", "n_tokens", "temperature", "rng", "generated",
-                 "session", "chain", "done", "attempts", "migrations",
-                 "submitted_at", "finished_at")
+    __slots__ = ("rid", "prompt", "n_tokens", "temperature", "rng",
+                 "generated", "session", "chain", "done", "attempts",
+                 "migrations", "submitted_at", "finished_at", "trace", "flow")
 
     def __init__(self, prompt: np.ndarray, n_tokens: int, temperature: float,
                  seed: int, done: Any, now: float):
+        self.rid = next(_request_seq)
+        # host-time spans (repro.core.tracing): the whole request, and the
+        # phases up to its first token
+        self.trace = tracing.begin("request", request=self.rid,
+                                   prompt_tokens=prompt.shape[1],
+                                   n_tokens=n_tokens)
+        self.flow = tracing.start_flow(request=self.rid, parent=self.trace)
+        tracing.phase(self.flow, "client.queue")
         self.prompt = prompt                 # (1, S) int32
         self.n_tokens = n_tokens
         self.temperature = temperature
@@ -431,7 +451,8 @@ class ShardClient:
     def __init__(self, node: LatticaNode, cfg: ModelConfig, fleet: str,
                  n_shards: int, resolve_ttl: float = 5.0,
                  hedge_after: float = 0.08, max_session_attempts: int = 8,
-                 max_migrations: int = 10):
+                 max_migrations: int = 10,
+                 on_token: Optional[Callable[..., None]] = None):
         self.node = node
         self.cfg = cfg
         self.fleet = fleet
@@ -440,6 +461,10 @@ class ShardClient:
         self.hedge_after = hedge_after
         self.max_session_attempts = max_session_attempts
         self.max_migrations = max_migrations
+        #: ``on_token(request, token, logits)``, called for every token
+        #: ``submit``'s requests sample, in order; ``request.done`` is the
+        #: event ``submit`` returned, ``request.rid`` the id its spans carry
+        self.on_token = on_token
         self.router = LoadAwareRouter(node.sim)
         self._providers: Dict[int, List[PeerInfo]] = {}
         self._resolved_at: Dict[int, float] = {}
@@ -663,6 +688,7 @@ class ShardClient:
         if status == "active":
             self._active.append(req)
         elif status == "retry":
+            tracing.phase(req.flow, "client.queue")
             req.attempts += 1
             if req.attempts >= self.max_session_attempts:
                 self._fail(req)
@@ -681,7 +707,9 @@ class ShardClient:
              np.asarray(req.generated, np.int32).reshape(1, -1)], axis=1)
         max_len = req.prompt.shape[1] + req.n_tokens + 1
         chain: List[PeerInfo] = []
+        tracing.link(req.flow, sid)
         for i in range(self.n_shards):
+            tracing.phase(req.flow, "rpc.open.send", session=sid, hop=i)
             provs = yield from self._resolve(i)
             if not provs:
                 provs = yield from self._resolve(i, refresh=True)
@@ -714,6 +742,7 @@ class ShardClient:
         req.session = sid
         req.chain = chain
         req.generated.append(self._sample(req, np.asarray(x)[0]))
+        tracing.close_flow(req.flow)
         if len(req.generated) >= req.n_tokens:
             self._finish(req, in_active=False)
             return "done"
@@ -738,37 +767,45 @@ class ShardClient:
         chain = reqs[0].chain
         live = list(reqs)
         x: Any = np.asarray([r.generated[-1] for r in live], np.int32)
-        for i, info in enumerate(chain):
-            payload = {"sessions": [r.session for r in live], "x": x}
-            self.stats["calls"] += 1
-            t0 = self.node.sim.now
-            self.router.begin(i, info.peer_id)
-            try:
-                stub = self.node.stub(InferenceV2Service, info,
-                                      scope=f"{self.fleet}.{i}")
-                resp = yield from stub.step(payload)
-                self.router.observe(i, info.peer_id,
-                                    self.node.sim.now - t0, True)
-            except (RpcError, DialError):
-                self.router.observe(i, info.peer_id,
-                                    self.node.sim.now - t0, False)
-                self.stats["failovers"] += 1
-                self._drop_provider(i, info)
-                for r in live:
+        # the round's phases per hop; the shards name it by its sessions
+        flow = tracing.start_flow()
+        try:
+            for i, info in enumerate(chain):
+                sessions = [r.session for r in live]
+                payload = {"sessions": sessions, "x": x}
+                tracing.link(flow, *sessions)
+                tracing.phase(flow, "rpc.step.send", hop=i, sessions=sessions)
+                self.stats["calls"] += 1
+                t0 = self.node.sim.now
+                self.router.begin(i, info.peer_id)
+                try:
+                    stub = self.node.stub(InferenceV2Service, info,
+                                          scope=f"{self.fleet}.{i}")
+                    resp = yield from stub.step(payload)
+                    self.router.observe(i, info.peer_id,
+                                        self.node.sim.now - t0, True)
+                except (RpcError, DialError):
+                    self.router.observe(i, info.peer_id,
+                                        self.node.sim.now - t0, False)
+                    self.stats["failovers"] += 1
+                    self._drop_provider(i, info)
+                    for r in live:
+                        self._migrate(r)
+                    return None
+                finally:
+                    self.router.end(i, info.peer_id)
+                served = {tuple(s) for s in resp["served"]}
+                missing = [r for r in live if r.session not in served]
+                for r in missing:
                     self._migrate(r)
-                return None
-            finally:
-                self.router.end(i, info.peer_id)
-            served = {tuple(s) for s in resp["served"]}
-            missing = [r for r in live if r.session not in served]
-            for r in missing:
-                self._migrate(r)
-            # response rows align with the engine's served order, which is
-            # the payload order filtered to sessions the shard still holds
-            live = [r for r in live if r.session in served]
-            if not live:
-                return None
-            x = resp["x"]
+                # response rows align with the engine's served order, which
+                # is the payload order filtered to sessions the shard holds
+                live = [r for r in live if r.session in served]
+                if not live:
+                    return None
+                x = resp["x"]
+        finally:
+            tracing.close_flow(flow)
         for r, row in zip(live, x):
             r.generated.append(self._sample(r, row))
             if len(r.generated) >= r.n_tokens:
@@ -776,13 +813,18 @@ class ShardClient:
         return None
 
     def _sample(self, req: _Request, logits: np.ndarray) -> int:
-        if req.temperature <= 0.0:
-            return int(np.argmax(logits))
-        z = logits.astype(np.float64) / req.temperature
-        z -= z.max()
-        p = np.exp(z)
-        p /= p.sum()
-        return int(req.rng.choice(len(p), p=p))
+        with tracing.span("client.sample", flow=req.flow):
+            if req.temperature <= 0.0:
+                tok = int(np.argmax(logits))
+            else:
+                z = logits.astype(np.float64) / req.temperature
+                z -= z.max()
+                p = np.exp(z)
+                p /= p.sum()
+                tok = int(req.rng.choice(len(p), p=p))
+            if self.on_token is not None:
+                self.on_token(req, tok, logits)
+        return tok
 
     def _migrate(self, req: _Request) -> None:
         """Provider died (or lost the session) mid-generation: replay
@@ -806,10 +848,13 @@ class ShardClient:
         req.finished_at = self.node.sim.now
         self._spawn_close(req.session, req.chain)
         self.stats["completed"] += 1
+        tracing.end(req.trace, tokens=len(req.generated))
         req.done.succeed(np.asarray(req.generated, np.int32))
 
     def _fail(self, req: _Request) -> None:
         self.stats["failed_sessions"] += 1
+        tracing.close_flow(req.flow)
+        tracing.end(req.trace, tokens=len(req.generated), failed=True)
         req.done.succeed(None)
 
     def _spawn_close(self, sid: Any, chain: List[PeerInfo]) -> None:

@@ -60,6 +60,22 @@ def test_l001_sim_rng_is_fine(tmp_path):
     assert rep.active == []
 
 
+@pytest.mark.parametrize("name,fires", [
+    ("repro/core/tracing.py", False),
+    ("repro/core/tracing_util.py", True),
+    ("repro/serving/batch.py", True),
+    ("repro/core/service.py", True),
+])
+def test_l001_exempts_only_the_tracer(tmp_path, name, fires):
+    rep = lint_src(tmp_path, """\
+        import time
+
+        def stamp():
+            return time.perf_counter_ns()
+        """, name=name)
+    assert [v.rule for v in rep.active] == (["L001"] if fires else [])
+
+
 def test_l002_raw_rpc_fires_once(tmp_path):
     rep = lint_src(tmp_path, """\
         def wire(node, handler):
