@@ -17,19 +17,22 @@ Two decode paths share the slot table:
 
 * **Fused paged decode** (attention-family archs: dense/moe/vlm/audio,
   no mrope, no sliding window).  KV lives in an engine-owned *page
-  pool* — per layer ``(P, page, Hk, hd)`` numpy arrays plus a free-page
-  list — and each slot holds a block table of page ids.  One jitted
-  forward advances *every* live slot per step: per layer, project
-  q/k/v for the whole batch, run paged single-query attention
-  (:mod:`repro.kernels.paged_attention`) over the block tables, and
-  return the new k/v rows, which the engine appends into the pool
-  host-side.  The unfused path re-reads the shard weights once per
-  session per token; the fused path reads them once per *batch* — in a
-  roofline cost model that is where batched decode actually wins.
-  ``kv_dtype="int8"`` stores pool pages quantized (per-page per-kv-head
-  scales, dequantized inside the attention kernel) for ~4x fewer
-  cache-resident bytes; the partial (current) page keeps an fp32
-  staging master per slot, so requantization never compounds error.
+  pool* — per layer ``(P, page, Hk, hd)`` arrays resident on the
+  shard's device, plus a free-page list — and each slot holds a block
+  table of page ids.  One jitted forward advances *every* live slot per
+  step: per layer, project q/k/v for the whole batch, run paged
+  single-query attention (:mod:`repro.kernels.paged_attention`) over
+  the block tables, and return the new k/v rows, which one donated
+  scatter then writes into the pool in place.  Prefill writes its pages
+  the same way, straight from the dense cache on the device: no step
+  copies the pool between host and device.  The unfused path re-reads
+  the shard weights once per session per token; the fused path reads
+  them once per *batch* — in a roofline cost model that is where
+  batched decode actually wins.  ``kv_dtype="int8"`` stores pool pages
+  quantized (per-page per-kv-head scales, dequantized inside the
+  attention kernel) for ~4x fewer cache-resident bytes; the partial
+  (current) page keeps an fp32 staging master per slot on the device,
+  so requantization never compounds error.
 
 * **Per-slot fallback** (ssm/hybrid/mrope/windowed): the original
   batch=1 ``module.apply`` loop with whole-page dense cache growth,
@@ -49,6 +52,7 @@ greedy decode through the batched plane still matches
 from __future__ import annotations
 
 import copy
+import functools
 import itertools
 from collections import deque
 from typing import Any, Deque, Dict, Generator, List, Optional, Tuple
@@ -78,40 +82,64 @@ _FUSED_ARCHS = ("dense", "moe", "vlm", "audio")
 _ENGINE_SEQ = itertools.count()
 
 
-def _quant_page_int8(x: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
-    """Symmetric int8 quantization of one page ``(L, page, Hk, hd)`` with
-    per-(layer, kv-head) scales: |x - x̂| <= absmax/254 elementwise."""
-    amax = np.abs(x).max(axis=(1, 3))
-    scale = np.where(amax > 0, amax / 127.0, 1.0).astype(np.float32)
-    q = np.rint(x / scale[:, None, :, None]).astype(np.int8)
+def _quant_page_int8(x: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    """Symmetric int8 quantization of pages ``(..., page, Hk, hd)`` with
+    one scale per (leading index, kv-head): |x - x̂| <= absmax/254
+    elementwise; ties round half to even."""
+    amax = jnp.abs(x).max(axis=(-3, -1))
+    # a true division: XLA would turn one by a literal into a product with
+    # its rounded reciprocal, 1 ulp off the scale in some elements
+    q_max = jax.lax.optimization_barrier(jnp.float32(127.0))
+    scale = jnp.where(amax > 0, amax / q_max, 1.0).astype(jnp.float32)
+    q = jnp.rint(x / scale[..., None, :, None]).astype(jnp.int8)
     return q, scale
+
+
+def _device_of(params: Any) -> Any:
+    """The device that holds ``params`` (None: JAX's default device)."""
+    for leaf in jax.tree.leaves(params):
+        if isinstance(leaf, jax.Array):
+            return next(iter(leaf.devices()))
+    return None
 
 
 class KVPool:
     """Shared paged KV storage for one shard's fused decode path.
 
-    Per layer ``k/v`` pools of shape ``(L, P, page, Hk, hd)`` grown
-    geometrically, plus a free-page list — alloc and free are exact and
-    symmetric.  ``quant`` stores int8 pages with per-(page, kv-head)
-    dequant scales ``(L, P, Hk)``.
+    Per layer ``k/v`` pools of shape ``(L, P, page, Hk, hd)``, held on
+    ``device`` and grown geometrically, plus a free-page list — alloc and
+    free are exact and symmetric.  ``quant`` stores int8 pages with
+    per-(page, kv-head) dequant scales ``(L, P, Hk)``.  Writes replace
+    the arrays with the outputs of donated in-place scatters
+    (``_write_prefill``, ``_append_rows``); nothing copies the pool.
     """
 
     def __init__(self, n_layers: int, n_kv_heads: int, head_dim: int,
-                 page_size: int, quant: bool = False):
+                 page_size: int, quant: bool = False, device: Any = None):
         self.L = n_layers
         self.Hk = n_kv_heads
         self.hd = head_dim
         self.page = page_size
         self.quant = quant
+        self.device = device
         self.n_pages = 0
         self._free: List[int] = []
-        dt = np.int8 if quant else np.float32
-        self.kp = np.zeros((self.L, 0, page_size, self.Hk, self.hd), dt)
-        self.vp = np.zeros_like(self.kp)
-        self.ks = (np.ones((self.L, 0, self.Hk), np.float32)
+        dt = jnp.int8 if quant else jnp.float32
+        shape = (self.L, 0, page_size, self.Hk, self.hd)
+        self.kp = jnp.zeros(shape, dt, device=device)
+        self.vp = jnp.zeros(shape, dt, device=device)
+        self.ks = (jnp.ones((self.L, 0, self.Hk), jnp.float32, device=device)
                    if quant else None)
-        self.vs = (np.ones((self.L, 0, self.Hk), np.float32)
+        self.vs = (jnp.ones((self.L, 0, self.Hk), jnp.float32, device=device)
                    if quant else None)
+
+    @property
+    def arrays(self) -> Tuple[Any, ...]:
+        return self.kp, self.vp, self.ks, self.vs
+
+    @arrays.setter
+    def arrays(self, new: Tuple[Any, ...]) -> None:
+        self.kp, self.vp, self.ks, self.vs = new
 
     @property
     def page_bytes(self) -> int:
@@ -119,6 +147,14 @@ class KVPool:
         per = self.L * self.page * self.Hk * self.hd * self.kp.dtype.itemsize
         scales = 2 * self.L * self.Hk * 4 if self.quant else 0
         return 2 * per + scales
+
+    @property
+    def append_bytes(self) -> int:
+        """Pool bytes one appended token writes: its k/v row, or for int8
+        pages the whole requantized page and its scales."""
+        if self.quant:
+            return self.page_bytes
+        return 2 * self.L * self.Hk * self.hd * self.kp.dtype.itemsize
 
     def pages_in_use(self) -> int:
         return self.n_pages - len(self._free)
@@ -130,9 +166,10 @@ class KVPool:
         total = max(min_total, self.n_pages * 2, 8)
         add = total - self.n_pages
 
-        def ext(a: np.ndarray, fill: float = 0.0) -> np.ndarray:
-            blk = np.full((self.L, add) + a.shape[2:], fill, a.dtype)
-            return np.concatenate([a, blk], axis=1)
+        def ext(a: jax.Array, fill: float = 0.0) -> jax.Array:
+            blk = jnp.full((self.L, add) + a.shape[2:], fill, a.dtype,
+                           device=self.device)
+            return jnp.concatenate([a, blk], axis=1)
 
         self.kp = ext(self.kp)
         self.vp = ext(self.vp)
@@ -150,22 +187,65 @@ class KVPool:
     def free(self, pages: List[int]) -> None:
         self._free.extend(pages)
 
-    def write_page(self, pid: int, k: np.ndarray, v: np.ndarray) -> None:
-        """Store one full page ``(L, page, Hk, hd)`` fp32 (zero-padded
-        past the valid tokens — zeros quantize to 0 under any scale)."""
-        if self.quant:
-            self.kp[:, pid], self.ks[:, pid] = _quant_page_int8(k)
-            self.vp[:, pid], self.vs[:, pid] = _quant_page_int8(v)
-        else:
-            self.kp[:, pid] = k
-            self.vp[:, pid] = v
 
-    def write_tokens(self, pid: int, offset: int, k: np.ndarray,
-                     v: np.ndarray) -> None:
-        """fp32 pools only: in-place write of ``t`` tokens at ``offset``."""
-        t = k.shape[1]
-        self.kp[:, pid, offset:offset + t] = k
-        self.vp[:, pid, offset:offset + t] = v
+@functools.partial(jax.jit, donate_argnums=(0, 1))
+def _write_prefill(pool: Tuple[Any, ...], tails: Any, slot: jax.Array,
+                   pages: jax.Array, n_full: jax.Array, k: jax.Array,
+                   v: jax.Array) -> Tuple[Tuple[Any, ...], Any]:
+    """Write a prefilled slot's dense cache ``k/v (L, 1, cap, Hk, hd)``
+    into its ``cap // page`` pool ``pages`` in place.  Past the prompt
+    the dense cache holds zeros, which quantize to 0 under any scale.
+    int8 pools also keep page ``n_full`` (the partial one) in fp32 as
+    the slot's staging master (``tails``, row ``slot``), so appends
+    requantize from it and error never compounds."""
+    kp, vp, ks, vs = pool
+    L, _, cap, Hk, hd = k.shape
+    n = pages.shape[0]
+    kpg = k[:, 0].reshape(L, n, cap // n, Hk, hd).astype(jnp.float32)
+    vpg = v[:, 0].reshape(L, n, cap // n, Hk, hd).astype(jnp.float32)
+    if ks is None:
+        return (kp.at[:, pages].set(kpg), vp.at[:, pages].set(vpg),
+                None, None), tails
+    qk, sk = _quant_page_int8(kpg)
+    qv, sv = _quant_page_int8(vpg)
+    kt, vt = tails
+    kt = kt.at[slot].set(jax.lax.dynamic_index_in_dim(kpg, n_full, 1, False))
+    vt = vt.at[slot].set(jax.lax.dynamic_index_in_dim(vpg, n_full, 1, False))
+    return (kp.at[:, pages].set(qk), vp.at[:, pages].set(qv),
+            ks.at[:, pages].set(sk), vs.at[:, pages].set(sv)), (kt, vt)
+
+
+@functools.partial(jax.jit, donate_argnums=(0, 1))
+def _append_rows(pool: Tuple[Any, ...], tails: Any, slots: jax.Array,
+                 pages: jax.Array, offs: jax.Array, kn: Tuple[jax.Array, ...],
+                 vn: Tuple[jax.Array, ...]) -> Tuple[Tuple[Any, ...], Any]:
+    """Write row ``r``'s token k/v ``kn[r], vn[r] (L, Hk, hd)`` at
+    ``(pages[r], offs[r])`` in place, for a fixed number of rows; padding
+    rows carry an out-of-range page (and slot) and are dropped.  int8
+    pools write the token into its slot's fp32 staging page (cleared at
+    offset 0) and requantize that whole page."""
+    kp, vp, ks, vs = pool
+    k = jnp.stack(kn, axis=1)                       # (L, M, Hk, hd)
+    v = jnp.stack(vn, axis=1)
+    if ks is None:
+        return (kp.at[:, pages, offs].set(k, mode="drop"),
+                vp.at[:, pages, offs].set(v, mode="drop"), None, None), tails
+    rows = jnp.arange(pages.shape[0])
+    fresh = (offs == 0)[:, None, None, None, None]
+
+    def stage(t: jax.Array, x: jax.Array) -> Tuple[jax.Array, jax.Array]:
+        cur = jnp.where(fresh, 0.0, t[slots])       # (M, L, page, Hk, hd)
+        cur = cur.at[rows, :, offs].set(x.swapaxes(0, 1))
+        return t.at[slots].set(cur, mode="drop"), cur.swapaxes(0, 1)
+
+    kt, kcur = stage(tails[0], k)
+    vt, vcur = stage(tails[1], v)
+    qk, sk = _quant_page_int8(kcur)
+    qv, sv = _quant_page_int8(vcur)
+    return (kp.at[:, pages].set(qk, mode="drop"),
+            vp.at[:, pages].set(qv, mode="drop"),
+            ks.at[:, pages].set(sk, mode="drop"),
+            vs.at[:, pages].set(sv, mode="drop")), (kt, vt)
 
 
 def _with_params(module: Any, params: Any) -> Any:
@@ -182,7 +262,7 @@ class SlotState:
     """One occupied decode slot: a session pinned to a paged KV cache."""
 
     __slots__ = ("session", "slot", "cache", "capacity", "max_len",
-                 "last_used", "length", "pages", "k_tail", "v_tail")
+                 "last_used", "length", "pages")
 
     def __init__(self, session: Any, slot: int, cache: Optional[Dict[str, Any]],
                  capacity: int, max_len: int, now: float):
@@ -194,8 +274,6 @@ class SlotState:
         self.last_used = now
         self.length = 0               # cached tokens (fused path)
         self.pages: List[int] = []    # pool page ids (fused path)
-        self.k_tail: Optional[np.ndarray] = None   # fp32 staging master for
-        self.v_tail: Optional[np.ndarray] = None   # the partial page (int8)
 
 
 def _fused_block(cfg: Any, p: Any, x: jax.Array, positions: jax.Array,
@@ -258,19 +336,33 @@ class BatchEngine:
         self.fused = supported if fused is None else (fused and supported)
         self.kv_dtype = kv_dtype if self.fused else "fp32"
         self._pool: Optional[KVPool] = None
+        # int8 pools: each slot's fp32 staging master of its partial page,
+        # (n_slots, L, page, Hk, hd) for k and for v, on the pool's device
+        self._tails: Optional[Tuple[jax.Array, jax.Array]] = None
+        self._tail_bytes = 0          # both staging pages of one slot
+        # this step's appends, written by one scatter after the step
+        self._appends: List[Tuple[int, int, int, jax.Array, jax.Array]] = []
         self._fallback_pages = 0      # exact page counter for the dense path
         if self.fused:
             cfg = module.cfg
+            dev = _device_of(module.params)
             self._pool = KVPool(module.n_layers, cfg.n_kv_heads, cfg.hd,
-                                page_size, quant=(self.kv_dtype == "int8"))
+                                page_size, quant=(self.kv_dtype == "int8"),
+                                device=dev)
+            if self._pool.quant:
+                shape = (n_slots, module.n_layers, page_size,
+                         cfg.n_kv_heads, cfg.hd)
+                self._tails = (jnp.zeros(shape, jnp.float32, device=dev),
+                               jnp.zeros(shape, jnp.float32, device=dev))
+                self._tail_bytes = 2 * int(np.prod(shape[1:])) * 4
             self._fused_apply = jax.jit(self._build_fused_apply())
         self.stats = {
             "admitted": 0, "evicted": 0, "steps": 0,
             "step_sessions": 0, "queue_peak": 0, "slot_reuse": 0,
             "pages": 0, "pages_peak": 0, "idle_evicted": 0,
-            # fused steps: pool bytes handed to the device, and of those the
-            # live rows' cached bytes
-            "kv_bytes_uploaded": 0, "kv_bytes_live": 0,
+            # fused path: pool bytes written in place on the device (prefill
+            # pages and appends), and the live rows' cached bytes per step
+            "kv_bytes_written": 0, "kv_bytes_live": 0,
         }
         sim.register_leak_check(
             f"kv.pages:{next(_ENGINE_SEQ)}", self._pages_in_use)
@@ -304,7 +396,12 @@ class BatchEngine:
                 new_k.append(kn)
                 new_v.append(vn)
             out = m.head(h)[:, 0] if m.is_last else h[:, 0]
-            return out, jnp.stack(new_k), jnp.stack(new_v)
+            # each row's k/v (L, Hk, hd) as an output of its own, so the
+            # engine hands rows to the append without slicing on the host
+            nk, nv = jnp.stack(new_k), jnp.stack(new_v)
+            rows = range(nk.shape[1])
+            return (out, tuple(nk[:, r] for r in rows),
+                    tuple(nv[:, r] for r in rows))
 
         return fused
 
@@ -374,10 +471,8 @@ class BatchEngine:
 
     def _slot_kv_bytes(self, st: SlotState) -> float:
         if self.fused:
-            b = len(st.pages) * self._pool.page_bytes
-            if st.k_tail is not None:
-                b += st.k_tail.nbytes + st.v_tail.nbytes
-            return float(b)
+            return float(len(st.pages) * self._pool.page_bytes
+                         + self._tail_bytes)
         if st.cache is None:
             return 0.0
         return float(sum(leaf.nbytes
@@ -387,11 +482,8 @@ class BatchEngine:
         """Current cache-resident bytes across all live slots (pool pages
         + fp32 staging tails, or dense per-slot caches)."""
         if self.fused:
-            b = float(self._pool.bytes_in_use())
-            for st in self.by_session.values():
-                if st.k_tail is not None:
-                    b += st.k_tail.nbytes + st.v_tail.nbytes
-            return b
+            return float(self._pool.bytes_in_use()
+                         + self._tail_bytes * len(self.by_session))
         return sum(self._slot_kv_bytes(st) for st in self.by_session.values())
 
     def _cost(self, flops: float, bytes_moved: float) -> float:
@@ -480,49 +572,51 @@ class BatchEngine:
             pos = jnp.broadcast_to(pos[None], (3,) + pos.shape)
         return pos
 
-    def _pool_write_prefill(self, st: SlotState, k: np.ndarray,
-                            v: np.ndarray) -> None:
-        """Copy a prefilled slot's k/v ``(L, S, Hk, hd)`` into its pool
-        pages; the partial last page keeps an fp32 staging master when
-        the pool is quantized (appends requantize from it, so error never
-        compounds)."""
-        pool, page = self._pool, self.page_size
-        L, S = k.shape[0], k.shape[1]
-        n_full = S // page
-        for pi in range(n_full):
-            sl = slice(pi * page, (pi + 1) * page)
-            pool.write_page(st.pages[pi], k[:, sl], v[:, sl])
-        rem = S - n_full * page
-        if pool.quant:
-            st.k_tail = np.zeros((L, page) + k.shape[2:], np.float32)
-            st.v_tail = np.zeros_like(st.k_tail)
-            if rem:
-                st.k_tail[:, :rem] = k[:, n_full * page:]
-                st.v_tail[:, :rem] = v[:, n_full * page:]
-                pool.write_page(st.pages[n_full], st.k_tail, st.v_tail)
-        elif rem:
-            pool.write_tokens(st.pages[n_full], 0,
-                              k[:, n_full * page:], v[:, n_full * page:])
+    def _pool_write_prefill(self, st: SlotState, k: jax.Array,
+                            v: jax.Array) -> int:
+        """Write a prefilled slot's dense cache ``k/v (L, 1, cap, Hk, hd)``,
+        as ``_apply`` left it on the device, into its pool pages in place
+        (int8: and its fp32 staging page).  Returns the pool bytes
+        written."""
+        pool = self._pool
+        pool.arrays, self._tails = _write_prefill(
+            pool.arrays, self._tails, st.slot,
+            np.asarray(st.pages, np.int32), st.length // self.page_size, k, v)
+        written = len(st.pages) * pool.page_bytes
+        self.stats["kv_bytes_written"] += written
+        return written
 
-    def _pool_append(self, st: SlotState, kn: np.ndarray,
-                     vn: np.ndarray) -> None:
+    def _pool_append(self, st: SlotState, kn: jax.Array,
+                     vn: jax.Array) -> None:
         """Append one token's k/v ``(L, Hk, hd)`` at position
-        ``st.length`` (the page was allocated before the fused call)."""
-        pool, page = self._pool, self.page_size
+        ``st.length`` (the page was allocated before the fused call).
+        The write is queued; ``_flush_appends`` makes the step's writes."""
         pos = st.length
-        off = pos % page
-        pid = st.pages[pos // page]
-        if pool.quant:
-            if off == 0:
-                st.k_tail[:] = 0.0
-                st.v_tail[:] = 0.0
-            st.k_tail[:, off] = kn
-            st.v_tail[:, off] = vn
-            pool.write_page(pid, st.k_tail, st.v_tail)
-        else:
-            pool.kp[:, pid, off] = kn
-            pool.vp[:, pid, off] = vn
+        self._appends.append((st.slot, st.pages[pos // self.page_size],
+                              pos % self.page_size, kn, vn))
         st.length = pos + 1
+
+    def _flush_appends(self) -> int:
+        """Write the queued appends in place with one scatter of
+        ``n_slots`` rows (padding rows are dropped), so it compiles once
+        per pool size.  Returns the pool bytes written."""
+        queued, self._appends = self._appends, []
+        if not queued:
+            return 0
+        pool, M = self._pool, self.n_slots
+        slots = np.full((M,), M, np.int32)
+        pages = np.full((M,), pool.n_pages, np.int32)
+        offs = np.zeros((M,), np.int32)
+        for r, (slot, pid, off, _, _) in enumerate(queued):
+            slots[r], pages[r], offs[r] = slot, pid, off
+        pad = M - len(queued)
+        kn = tuple(q[3] for q in queued) + (queued[0][3],) * pad
+        vn = tuple(q[4] for q in queued) + (queued[0][4],) * pad
+        pool.arrays, self._tails = _append_rows(
+            pool.arrays, self._tails, slots, pages, offs, kn, vn)
+        written = len(queued) * pool.append_bytes
+        self.stats["kv_bytes_written"] += written
+        return written
 
     def _prefill(self, session: Any, slot: int, x: np.ndarray,
                  max_len: int) -> Tuple[np.ndarray, float]:
@@ -549,10 +643,10 @@ class BatchEngine:
                 st.cache = None
                 st.length = S
                 st.pages = self._pool.alloc(cap // self.page_size)
-                k = np.asarray(cache["layers"]["k"][:, 0, :S], np.float32)
-                v = np.asarray(cache["layers"]["v"][:, 0, :S], np.float32)
-                with tracing.span("kv.write_prefill", pages=len(st.pages)):
-                    self._pool_write_prefill(st, k, v)
+                with tracing.span("kv.write_prefill",
+                                  pages=len(st.pages)) as sp:
+                    sp.set(bytes=self._pool_write_prefill(
+                        st, cache["layers"]["k"], cache["layers"]["v"]))
             else:
                 self._fallback_pages += cap // self.page_size
                 out, st.cache = self._apply(m.params, xj,
@@ -627,35 +721,21 @@ class BatchEngine:
             bt[r, :len(st.pages)] = st.pages
             lengths[r] = st.length
         pool = self._pool
-        # the whole pool goes to the device every step; the live rows'
-        # pages (allocated above, so appends do not change them) are the
-        # part the step reads
-        uploaded = sum(a.nbytes for a in (pool.kp, pool.vp, pool.ks, pool.vs)
-                       if a is not None)
+        # the live rows' pages (allocated above, so appends do not change
+        # them) are the part of the resident pool the step reads
         kv_read = sum(self._slot_kv_bytes(st) for _, _, st in live)
-        self.stats["kv_bytes_uploaded"] += uploaded
         self.stats["kv_bytes_live"] += int(kv_read)
-        # the step's inputs go to the device before the pool, as they did
-        # before the spans: a small copy queued behind the pool's would wait
-        # for it outside the pool's copy
-        inputs = (jnp.asarray(xb), jnp.asarray(lengths[:, None]),
-                  jnp.asarray(bt), jnp.asarray(lengths))
-        with tracing.span("kv.upload", bytes=uploaded,
-                          live_bytes=int(kv_read)):
-            kp, vp = jnp.asarray(pool.kp), jnp.asarray(pool.vp)
-            ks = None if pool.ks is None else jnp.asarray(pool.ks)
-            vs = None if pool.vs is None else jnp.asarray(pool.vs)
         with tracing.span("engine.fused", rows=len(live)):
-            out, nk, nv = self._fused_apply(m.params, *inputs,
-                                            kp, vp, ks, vs)
+            out, nk, nv = self._fused_apply(
+                m.params, jnp.asarray(xb), jnp.asarray(lengths[:, None]),
+                jnp.asarray(bt), jnp.asarray(lengths), *pool.arrays)
             out = np.asarray(out)
-            nk = np.asarray(nk, np.float32)
-            nv = np.asarray(nv, np.float32)
         served: List[Any] = []
-        with tracing.span("kv.append"):
+        with tracing.span("kv.append", rows=len(live)) as sp:
             for r, (_, sid, st) in enumerate(live):
-                self._pool_append(st, nk[:, r], nv[:, r])
+                self._pool_append(st, nk[r], nv[r])
                 served.append(sid)
+            sp.set(bytes=self._flush_appends())
         self.stats["step_sessions"] += len(served)
         # one pass over the weights for the whole batch — the fused win
         cost = self._cost(m.flops(1) * len(served),

@@ -70,7 +70,7 @@ def prompts(cfg, n, length=12):
 def test_off_records_nothing_and_allocates_no_span(tracer):
     assert not tracer.TRACER.on
     assert tracer.span("engine.step", rows=3) is tracer.NOOP
-    assert tracer.span("kv.upload") is tracer.NOOP
+    assert tracer.span("kv.append") is tracer.NOOP
     assert tracer.begin("request", request=1) is None
     assert tracer.start_flow(request=1) is None
     with tracer.span("engine.step") as sp:
@@ -188,17 +188,21 @@ def test_request_phases_tile_submit_to_first_token(model, tracer):
 
     # decode rounds: every hop's step phases, and the counters in the spans
     steps = [r for r in recs if r.name == "engine.step"]
-    ups = [r for r in recs if r.name == "kv.upload"]
+    appends = [r for r in recs if r.name == "kv.append"]
+    prefills = [r for r in recs if r.name == "kv.write_prefill"]
     d = {k: sum(s.engine.stats[k] - b[k] for s, b in zip(servers, before))
-         for k in ("steps", "step_sessions", "kv_bytes_uploaded",
+         for k in ("steps", "step_sessions", "kv_bytes_written",
                    "kv_bytes_live")}
     assert len(steps) == d["steps"] > 0
     assert sum(r.attrs["rows"] for r in steps) == d["step_sessions"]
-    assert sum(r.attrs["bytes"] for r in ups) == d["kv_bytes_uploaded"]
-    assert sum(r.attrs["live_bytes"] for r in ups) == d["kv_bytes_live"]
-    assert 0 < d["kv_bytes_live"] < d["kv_bytes_uploaded"]
+    # every byte written into the pools is an append's or a prefill's
+    assert sum(r.attrs["rows"] for r in appends) == d["step_sessions"]
+    assert (sum(r.attrs["bytes"] for r in appends)
+            + sum(r.attrs["bytes"] for r in prefills)) == d["kv_bytes_written"]
+    assert sum(r.attrs["bytes"] for r in appends) > 0 and d["kv_bytes_live"] > 0
+    assert not any(r.name == "kv.upload" for r in recs)
     ids = {r.span_id: r for r in recs}
-    for name in ("kv.upload", "engine.fused", "kv.append"):
+    for name in ("engine.fused", "kv.append"):
         kids = [r for r in recs if r.name == name]
         assert kids and all(ids[r.parent_id].name == "engine.step"
                             for r in kids)
