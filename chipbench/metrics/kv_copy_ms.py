@@ -1,5 +1,5 @@
-"""Mean wait of a fused decode step for its arguments: the host-to-device
-copy of the shard's whole KV page pool (and the small step inputs)."""
+"""Mean wait of a fused decode step for its arguments: the step's small
+inputs and the previous step's append into the resident KV page pool."""
 
 from chipbench.record import calls_in_window
 

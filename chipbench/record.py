@@ -29,8 +29,9 @@ class Request:
 
 @dataclasses.dataclass
 class Run:
-    """A run's record: the cell, the window, the requests, the wrapped
-    calls (traced runs only) and the reduced trace (traced runs only)."""
+    """A run's record: the cell, the family module that counts its work,
+    the window, the requests, and (traced runs only) the wrapped calls, the
+    reduced trace and the program tracer's records."""
 
     config: Dict[str, Any]
     traffic: Dict[str, Any]
@@ -41,6 +42,8 @@ class Run:
     requests: List[Request]
     calls: Dict[str, list] = dataclasses.field(default_factory=dict)
     trace: Optional[Any] = None
+    spans: List[Any] = dataclasses.field(default_factory=list)
+    family: Optional[Any] = None
 
     @property
     def window_s(self) -> float:

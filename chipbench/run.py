@@ -1,8 +1,8 @@
 #!/usr/bin/env python3
 """Runs one benchmark cell on the chip and prints one JSON line.
 
-    python3 chipbench/run.py --workload minicpm-2b.decode --seed 7 \\
-        --seconds 40 --trace 0
+    python3 chipbench/run.py --workload granite-8b-9l.docqa --seed 7 \\
+        --seconds 50 --trace 0
 
 The cell (a configuration under a traffic mix) is looked up in
 ``BENCHMARK.json``.  Set-up (process start to the window: weights made
@@ -11,8 +11,10 @@ mix uses) is ``setup_s``.  The window runs for ``--seconds`` and nothing
 compiles in it; then the served tokens are checked against the plain
 reference.  ``--trace 0`` reports the cell's end-to-end metrics, ``--trace
 1`` its per-layer metrics from wrapped calls and a profiler trace of the
-window.  The last line of standard output is the result; the numbers
-compared, each beside its limit, are the last lines of standard error.
+window, with the program's tracer (``repro.core.tracing``) on from just
+before the window until its requests have drained.  The last line of
+standard output is the result; the numbers compared, each beside its
+limit, are the last lines of standard error.
 Without a TPU, or with fewer chips than the cell asks for, it exits 1 and
 prints no result.
 """
@@ -62,7 +64,9 @@ def run(cell: Dict[str, Any], bench: Dict[str, Any], *, seed: int,
     check.  Returns the result line (``main`` makes sure it is a TPU)."""
     import jax
 
-    from chipbench import check, serve, spec, trace as tr
+    from repro.core import tracing
+
+    from chipbench import check, phases, serve, spec, trace as tr
 
     config, mix = cell["config"], cell["traffic"]
     family, program = spec.family(config)
@@ -94,6 +98,10 @@ def run(cell: Dict[str, Any], bench: Dict[str, Any], *, seed: int,
         result["breakdown"] = {
             "device_ops": tr.top_ops(used[0], win) if used else [],
             "idle_gaps": tr.idle_gaps(used[0] if used else [], t.host, win)}
+        log(f"tracer: {len(run_rec.spans)} records, "
+            f"{tracing.TRACER.dropped} dropped; first-token phases against "
+            f"the stamps {json.dumps(phases.tiling(run_rec))}; where the "
+            f"first-token wait went {json.dumps(phases.where_ttft_goes(run_rec))}")
 
     in_window = [r for r in run_rec.requests
                  if run_rec.window[0] <= r.submitted <= run_rec.window[1]]

@@ -28,12 +28,13 @@ from typing import Any, Callable, Dict, List
 import jax
 import numpy as np
 
+from repro.core import tracing
 from repro.core.fleet import make_fleet
 from repro.serving.batch import BatchEngine
 from repro.serving.sharded import (ShardClient, ShardModule, ShardServer,
                                    plan_shards)
 
-from chipbench import flops, record, trace as tr, traffic as tf
+from chipbench import record, trace as tr, traffic as tf
 from chipbench.timing import Call, CompileClock, Spanned, Timed
 
 #: every peer public: a relayed circuit's upgrade would reset the RPC
@@ -149,10 +150,12 @@ def warm_up(dep: Deployment, mix: Dict[str, Any], vocab: int,
 
 
 def instrument(dep: Deployment, config: Dict[str, Any], clock: CompileClock,
-               ) -> Dict[str, List[Call]]:
+               family: Any) -> Dict[str, List[Call]]:
     """Traced runs only: wrap each engine's fused step (argument wait and
-    compute wait, with the step's live lengths), its ``_prefill`` and its
-    ``step`` in named host spans."""
+    compute wait; the shard's layers and place, the live rows' cached
+    lengths, and the family's count of the step's operations and bytes),
+    its ``_prefill`` and its ``step`` in named host spans; then turn the
+    program's tracer on."""
     calls: Dict[str, List[Call]] = {"fused": [], "prefill": [], "step": []}
     for i, (eng, (lo, hi)) in enumerate(zip(dep.engines, dep.plan)):
         first, last = i == 0, i == dep.n_shards - 1
@@ -161,7 +164,9 @@ def instrument(dep: Deployment, config: Dict[str, Any], clock: CompileClock,
                  _n=hi - lo, _f=first, _l=last):
             live = [int(v) for v in np.asarray(lengths) if v > 0]
             pb = jax.tree_util.tree_leaves(params)[0].dtype.itemsize
-            return flops.fused_step(config, _n, _f, _l, live, pb)
+            info = family.fused_step(config, _n, _f, _l, live, pb)
+            info.update(lengths=live, n_layers=_n, first=_f, last=_l)
+            return info
 
         eng._fused_apply = Timed(eng._fused_apply, clock, note, "kv_copy",
                                  calls["fused"])
@@ -169,6 +174,7 @@ def instrument(dep: Deployment, config: Dict[str, Any], clock: CompileClock,
             eng._prefill, "prefill", calls["prefill"],
             note=lambda session, slot, x, max_len: {"tokens": x.shape[1]})
         eng.step = Spanned(eng.step, "fused_step", calls["step"])
+    tracing.enable()
     return calls
 
 
@@ -205,7 +211,7 @@ def drive(config: Dict[str, Any], mix: Dict[str, Any], *, seed: int,
         pools = dep.pool_pages()
         log(f"set-up {time.perf_counter() - t_start:.3f} s, of which "
             f"compiles {clock.seconds:.3f} s ({clock.count} programs)")
-        calls = instrument(dep, config, clock) if traced else {}
+        calls = instrument(dep, config, clock, family) if traced else {}
         gen = tf.Traffic(mix, config["vocab_size"], seed)
         requests: List[record.Request] = []
         trace_dir = tempfile.mkdtemp(prefix="chipbench-trace-") if traced else None
@@ -221,12 +227,11 @@ def drive(config: Dict[str, Any], mix: Dict[str, Any], *, seed: int,
                 with jax.profiler.TraceAnnotation("client"):
                     dep.sim.run(until=dep.sim.now + SLICE)
         t_end = time.perf_counter()
-        if traced:
-            jax.profiler.stop_trace()
         compiles = clock.count - compiles0
         # the window's last requests wait for their first token, and the
         # rest of the window's requests for their last one while fewer have
-        # finished than the check samples
+        # finished than the check samples; the profiler stops after that, so
+        # that the seconds it takes to stop fall in no phase of theirs
         due = [r for r in requests if r.submitted <= t1]
         limit = time.perf_counter() + DRAIN_S
 
@@ -239,6 +244,9 @@ def drive(config: Dict[str, Any], mix: Dict[str, Any], *, seed: int,
 
         while waiting() and time.perf_counter() < limit:
             dep.sim.run(until=dep.sim.now + SLICE)
+        if traced:
+            jax.profiler.stop_trace()
+        tracing.disable()
         for r in due:
             if not r.stamps:
                 r.failed = True
@@ -252,7 +260,8 @@ def drive(config: Dict[str, Any], mix: Dict[str, Any], *, seed: int,
             raise RuntimeError("the KV pool grew inside the window")
         run = record.Run(config=config, traffic=mix, chips=chips,
                          peaks=peaks, window=(t0, t1),
-                         setup_s=t0 - t_start, requests=requests, calls=calls)
+                         setup_s=t0 - t_start, requests=requests, calls=calls,
+                         spans=tracing.drain(), family=family)
         del dep, gen
         gc.collect()
         log("device bytes in use after the deployment is freed: "
@@ -263,4 +272,5 @@ def drive(config: Dict[str, Any], mix: Dict[str, Any], *, seed: int,
             log(tr.summary(run.trace))
         return {"run": run, "peak": peak, "compiles": compiles}
     finally:
+        tracing.disable()
         clock.close()

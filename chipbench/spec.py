@@ -5,7 +5,9 @@ metric is a file of its own, found by name, so a later cell, mix,
 configuration or metric is a new file plus a new entry:
 
 * ``configs[].file``                 the configuration, as it is run
-* ``chipbench/models/<family>.py``   its weight maker and plain reference
+* ``chipbench/models/<family>.py``   its weight maker, plain reference
+                                     and counts (``prefill_flops``,
+                                     ``decode_flops``, ``fused_step``)
 * ``chipbench/models/<family>_program.py``  its system-side settings
 * ``chipbench/traffic/<traffic>.json``  the mix's parameters
 * ``chipbench/limits/<cell>.json``   the limit of each number compared

@@ -39,6 +39,31 @@ def test_untraced_run_reports_end_to_end_metrics():
     assert line["correct"] is True
 
 
+def test_untraced_run_keeps_the_program_tracer_off(monkeypatch):
+    """End-to-end metrics are measured with the program's tracer off: an
+    untraced run records nothing, and a traced one leaves it off."""
+    from chipbench import serve
+    from repro.core import tracing
+
+    held = []
+    drive = serve.drive
+
+    def keep(*args, **kw):
+        out = drive(*args, **kw)
+        held.append(out["run"])
+        return out
+
+    monkeypatch.setattr(serve, "drive", keep)
+    enabled = []
+    monkeypatch.setattr(tracing, "enable", lambda: enabled.append(1))
+    line = bench_run.run(chipbench_tiny.cell(), chipbench_tiny.bench(),
+                         seed=6, seconds=0.5, traced=False,
+                         peaks=chipbench_tiny.PEAKS, t_start=time.perf_counter())
+    assert line["correct"] is True
+    assert held[0].spans == [] and held[0].calls == {}
+    assert not enabled and not tracing.TRACER.on
+
+
 def test_main_refuses_a_host_without_a_tpu(capsys):
     rc = bench_run.main(["--workload", "minicpm-2b.decode", "--seed", "1",
                          "--seconds", "1", "--trace", "0"])

@@ -84,3 +84,83 @@ def test_a_new_cell_and_metric_are_new_files_only(tmp_path):
     assert hasattr(ref, "reference_compare") and hasattr(prog, "program_config")
     for p, data in before.items():
         assert open(p, "rb").read() == data, f"{p} was edited"
+
+
+#: a second family's two files: llama's weights and reference, with counts
+#: of its own (each a plain multiple, so a reading shows whose it is)
+TWIN = '''
+from chipbench import spec
+
+_llama = spec.family({"family": "llama"})[0]
+plan, make_shards = _llama.plan, _llama.make_shards
+reference_compare, control_logits = (_llama.reference_compare,
+                                     _llama.control_logits)
+
+
+def prefill_flops(c, prompt_len):
+    return 1000 * prompt_len
+
+
+def decode_flops(c, pos):
+    return 10 * (pos + 1)
+
+
+def fused_step(c, n_layers, first, last, lengths, param_bytes):
+    return {"flops": 7 * sum(lengths), "bytes": 11 * n_layers * len(lengths)}
+'''
+TWIN_PROGRAM = '''
+from chipbench import spec
+
+program_config = spec.family({"family": "llama"})[1].program_config
+'''
+
+
+def test_a_second_family_brings_its_own_counts(tmp_path):
+    """A family found by its file name alone sets the fused calls' counts
+    and ``mfu``; no file of the harness knows it."""
+    import time
+
+    from chipbench import serve
+
+    models = tmp_path / "chipbench" / "models"
+    models.mkdir(parents=True)
+    (models / "twin.py").write_text(TWIN)
+    (models / "twin_program.py").write_text(TWIN_PROGRAM)
+    config = dict(chipbench_tiny.CONFIG, name="tiny-twin", family="twin")
+    family, program = spec.family(config, str(tmp_path))
+    out = serve.drive(config, chipbench_tiny.MIX, seed=21, seconds=0.5,
+                      traced=True, chips=1, peaks=chipbench_tiny.PEAKS,
+                      t_start=time.perf_counter(), family=family,
+                      program=program, log=lambda msg: None)
+    run = out["run"]
+    assert run.family is family
+    calls = run.calls["fused"]
+    assert calls
+    for c in calls:
+        assert c.info["flops"] == 7 * sum(c.info["lengths"])
+        assert c.info["bytes"] == 11 * c.info["n_layers"] * len(c.info["lengths"])
+    total = sum(1000 * r.prompt_len if i == 0 else 10 * (r.prompt_len + i)
+                for r in run.requests for i, t in enumerate(r.stamps)
+                if run.in_window(t))
+    assert total > 0
+    want = 100.0 * total / (run.window_s * chipbench_tiny.PEAKS["bf16_flops"])
+    assert spec.reader("mfu")(run) == pytest.approx(want, rel=1e-12)
+
+
+def test_every_block_sends_each_length_of_the_mix():
+    from chipbench import traffic as tf
+
+    mix = spec.load_json(os.path.join(chipbench_tiny.ROOT, "chipbench",
+                                      "traffic", "docqa.json"))
+    size = len(tf.block(mix)[0])
+    sent = {}
+    for seed in (1, 2 ** 31 + 5, 2 ** 33 + 7):
+        gen = tf.Traffic(mix, 1000, seed)
+        reqs = [gen.next() for _ in range(3 * size)]
+        sent[seed] = [(len(p), n) for p, n in reqs]
+        for b in range(3):
+            got = sent[seed][b * size:(b + 1) * size]
+            assert sorted(p for p, _ in got) == sorted(tf.block(mix)[0])
+            assert sorted(n for _, n in got) == sorted(tf.block(mix)[1])
+    orders = list(sent.values())
+    assert orders[0] != orders[1] and orders[1] != orders[2]

@@ -1,17 +1,29 @@
-"""The tracer metrics: each reader on small synthetic runs, the tiny cell
-run through ``traced.py`` on the CPU, and the program's synchronous spans
-in a recorded profiler trace."""
+"""The tracer metrics: each reader on small synthetic runs, and the tiny
+cell's traced run on the CPU (``run.run``), which carries the program's
+records, its synchronous spans in the profiler trace, and each fused
+call's live lengths."""
 
+import contextlib
+import io
+import json
+import re
 import time
 
 import pytest
 
 import chipbench_tiny
-from chipbench import phases, record, spec, traced
-from chipbench import trace as tr
+from chipbench import phases, record, serve, spec
+from chipbench import run as bench_run
+from repro.core import tracing
 from repro.core.tracing import Record
 
 MS = 1_000_000        # ns
+#: the per-layer metrics that read the program tracer's records
+TRACER_METRICS = sorted(m["name"] for m in spec.load_benchmark()["per_layer"]
+                        if m["source"] == "program_span")
+#: the program's synchronous spans, mirrored into the profiler's trace
+PROGRAM_SPANS = ("engine.prefill", "kv.write_prefill", "engine.step",
+                 "engine.fused", "kv.append", "client.sample")
 
 
 def rec(name, t0_ms, t1_ms, rid=None, **attrs):
@@ -45,7 +57,7 @@ def read(metric, run):
     return spec.reader(metric)(run)
 
 
-@pytest.mark.parametrize("metric", sorted(traced.TRACER_METRICS))
+@pytest.mark.parametrize("metric", TRACER_METRICS)
 def test_reader_is_silent_without_tracer_records(metric):
     assert read(metric, run_with(None)) is None
     assert read(metric, run_with([])) is None
@@ -72,16 +84,15 @@ def test_charge_share_and_admit_wait_read_first_token_phases():
 
 
 def test_rows_per_step_and_useful_upload_share():
+    """``decode_rows_per_step`` over the window's ``engine.step`` spans; the
+    upload share's reader went with the pool copy it read."""
     spans = [rec("engine.step", 100, 110, rows=1),
              rec("engine.step", 120, 130, rows=3),
              rec("engine.step", 140, 150, rows=0),
              rec("engine.step", 1200, 1210, rows=4),      # after the window
-             rec("kv.upload", 101, 105, bytes=1000, live_bytes=50),
-             rec("kv.upload", 121, 125, bytes=1000, live_bytes=150),
-             rec("kv.upload", 1201, 1205, bytes=1000, live_bytes=1000)]
+             rec("kv.append", 101, 105, rows=1, bytes=1000)]
     run = run_with(spans)
     assert read("decode_rows_per_step", run) == pytest.approx(4 / 3)
-    assert read("kv_upload_useful_share", run) == pytest.approx(10.0)
 
 
 def test_tiling_matches_phases_to_the_benchmarks_stamps():
@@ -92,66 +103,78 @@ def test_tiling_matches_phases_to_the_benchmarks_stamps():
                          stamps=[0.0999 + ttft * 1.01])
     run = run_with(a)
     run.requests = [req]
-    got = traced.tiling(run)
+    got = phases.tiling(run)
     assert got["requests"] == got["with_phases"] == got["matched"] == 1
     assert got["worst_rel_gap"] == pytest.approx(0.01 / 1.01, rel=1e-3)
 
 
-def test_tiny_cell_reports_the_tracer_metrics():
-    line = traced.run_traced(chipbench_tiny.cell(), chipbench_tiny.bench(),
-                             seed=2 ** 31 + 78, seconds=1.0,
+@pytest.fixture(scope="module")
+def traced_tiny():
+    """One traced run of the tiny cell: its result line, its record and
+    what it logged."""
+    held = {}
+    drive = serve.drive
+
+    def keep(*args, **kw):
+        out = drive(*args, **kw)
+        held["run"] = out["run"]
+        return out
+
+    err = io.StringIO()
+    with pytest.MonkeyPatch.context() as mp, contextlib.redirect_stderr(err):
+        mp.setattr(serve, "drive", keep)
+        line = bench_run.run(chipbench_tiny.cell(), chipbench_tiny.bench(),
+                             seed=2 ** 31 + 78, seconds=1.0, traced=True,
                              peaks=chipbench_tiny.PEAKS,
                              t_start=time.perf_counter())
+    return line, held["run"], err.getvalue()
+
+
+def test_tiny_cell_reports_the_tracer_metrics(traced_tiny):
+    line, run, log = traced_tiny
     assert line["correct"] is True
     m = line["metrics"]
-    for name in traced.TRACER_METRICS:
+    for name in TRACER_METRICS:
         assert m[name]["value"] > 0, name
     assert 0 < m["ttft_charge_share"]["value"] < 100
     rows = m["decode_rows_per_step"]["value"]
     assert 1 <= rows <= chipbench_tiny.MIX["n_slots"]
-    assert 0 < m["kv_upload_useful_share"]["value"] < 100
-    for name in ("tokens_per_s", "ttft_p95_ms", "setup_s", "kv_copy_ms"):
-        assert name in m, name
-    til = line["tracer"]["tiling"]
+    assert "kv_copy_ms" in m and "mfu" in m
+    assert run.spans and not tracing.TRACER.on
+    got = re.search(r"tracer: (\d+) records, (\d+) dropped; first-token "
+                    r"phases against the stamps (\{.*?\});", log)
+    assert got and int(got[1]) == len(run.spans) and got[2] == "0"
+    til = json.loads(got[3])
     assert til["with_phases"] == til["matched"] == til["requests"] > 0
     assert til["worst_rel_gap"] < 0.02
-    assert line["tracer"]["dropped"] == 0
     assert list(line)[-1] == "checks"
 
 
-def test_program_spans_reach_the_profiler_trace(tmp_path, monkeypatch):
-    import jax
-    import numpy as np
+def test_program_spans_reach_the_profiler_trace(traced_tiny):
+    _, run, _ = traced_tiny
+    names = {n for n, _, _ in run.trace.host}
+    for name in PROGRAM_SPANS:
+        assert name in names, (name, sorted(names))
+    # the profiler runs from the window's start until after the drain, so
+    # steps lie in the window or after it, never before
+    window = run.trace.window()
+    steps = [s for n, s, _ in run.trace.host if n == "engine.step"]
+    assert steps and all(window[0] <= s for s in steps)
+    assert any(s <= window[1] for s in steps)
 
-    from repro.configs import get_config
-    from repro.core import tracing
-    from repro.core.simnet import Sim
-    from repro.models import ops_for
-    from repro.serving.batch import BatchEngine
-    from repro.serving.sharded import ShardModule
 
-    cfg = get_config("granite-8b").reduced(n_layers=2, d_model=32, vocab=128)
-    params = ops_for(cfg).init(cfg, jax.random.PRNGKey(0))
-    eng = BatchEngine(ShardModule(cfg, params, (0, 2), True, True), Sim(),
-                      n_slots=2, page_size=8)
-    x = np.arange(10, dtype=np.int32)[None]
-
-    def drive(sid):
-        eng.sim.run_process(eng.open(sid, x, 16))
-        eng.step([sid], np.asarray([3], np.int32))
-
-    drive("warm")                                        # compile first
-    monkeypatch.setattr(tr, "HOST_SPANS", tr.HOST_SPANS + traced.PROGRAM_SPANS)
-    tracing.enable()
-    try:
-        jax.profiler.start_trace(str(tmp_path))
-        with jax.profiler.TraceAnnotation("window"):
-            drive("traced")
-        jax.profiler.stop_trace()
-    finally:
-        tracing.disable()
-        tracing.drain()
-    names = [n for n, _, _ in tr.extract(str(tmp_path)).host]
-    for name in ("engine.prefill", "kv.write_prefill", "engine.step",
-                 "kv.upload", "engine.fused", "kv.append"):
-        assert names.count(name) == 1, (name, names)
+def test_fused_calls_carry_their_live_lengths(traced_tiny):
+    _, run, _ = traced_tiny
+    family, _ = spec.family(run.config)
+    calls = run.calls["fused"]
+    assert calls
+    for c in calls:
+        info = c.info
+        assert info["lengths"] and all(n > 0 for n in info["lengths"])
+        assert len(info["lengths"]) <= chipbench_tiny.MIX["n_slots"]
+        recount = family.fused_step(run.config, info["n_layers"],
+                                    info["first"], info["last"],
+                                    info["lengths"], 4)
+        assert {k: info[k] for k in ("flops", "bytes")} == recount
+    assert {(c.info["n_layers"], c.info["first"], c.info["last"])
+            for c in calls} == {(1, True, False), (1, False, True)}

@@ -5,7 +5,7 @@ the numbers the per-layer readers take.
 those lists, so it is checked on small synthetic ones.  Device planes are
 ``/device:TPU:<n>``: their ``XLA Ops`` line gives busy time, their ``XLA
 Modules`` line one event per launched program (``jit_<name>``).  Host
-spans are the ``jax.profiler.TraceAnnotation`` names the benchmark writes.
+spans are the ``jax.profiler.TraceAnnotation`` names of ``HOST_SPANS``.
 Times are in seconds on the trace's clock.
 """
 
@@ -20,8 +20,11 @@ from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 Interval = Tuple[float, float]
 Event = Tuple[str, float, float]          # (name, start, end)
 
-#: host spans the benchmark writes (see serve.py)
-HOST_SPANS = ("window", "client", "prefill", "fused_step", "kv_copy")
+#: host spans the benchmark writes (see serve.py), then the program's
+#: synchronous spans, which its tracer mirrors into the trace while it is on
+HOST_SPANS = ("window", "client", "prefill", "fused_step", "kv_copy",
+              "engine.prefill", "kv.write_prefill", "engine.step",
+              "engine.fused", "kv.append", "client.sample")
 
 
 @dataclasses.dataclass
