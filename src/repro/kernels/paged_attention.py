@@ -27,6 +27,11 @@ Two storage formats share the kernel:
   of shape ``(P, Hk)``) — dequantised inside the kernel, quartering
   pool bytes for a bounded logit error (|x̂-x| <= page_absmax/254).
 
+``paged_latent_attention_jnp`` is the same gather-based decode over
+latent-attention (MLA) pools, whose pages hold one row per token: the
+row is the key and its first ``value_dim`` numbers the value, so each
+cached row is read once.
+
 ``paged_attention_jnp`` is the gather-based reference formulation used
 on CPU (Pallas interpret mode is far too slow for the serving hot loop)
 and by tests; it reproduces ``models/common.attention_scores`` decode
@@ -106,6 +111,37 @@ def paged_attention_jnp(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     logits = logits + amask[:, None, :]
     probs = jax.nn.softmax(logits, axis=-1)
     out = jnp.einsum("mht,mthd->mhd", probs, vv)
+    return out.astype(q.dtype)
+
+
+def paged_latent_attention_jnp(q: jax.Array, pool: jax.Array,
+                               block_tables: jax.Array, lengths: jax.Array,
+                               row_new: jax.Array, value_dim: int,
+                               scale: float) -> jax.Array:
+    """Gather-based paged decode attention over latent rows (MLA).
+
+    q:            (M, H, W)    one latent-space query per slot and head
+    pool:         (P, page, W) fp32 latent rows ``[c, k_pe]``
+    block_tables: (M, NP) int32 pool page ids (padded entries masked out)
+    lengths:      (M,) int32   cached tokens per slot (query position)
+    row_new:      (M, W)       this step's row, attended at ``lengths``
+
+    Each cached row is gathered once and serves as key (all ``W``
+    numbers) and as value (its first ``value_dim``).  Returns ``(M, H,
+    value_dim)``: per head the softmax-weighted sum of the rows' latents.
+    """
+    M, H, W = q.shape
+    page = pool.shape[1]
+    T = block_tables.shape[1] * page
+    rows = pool[block_tables].reshape(M, T, W).astype(jnp.float32)
+    rows = jax.vmap(lambda c, n, l: jax.lax.dynamic_update_slice(
+        c, n[None], (l, 0)))(rows, row_new.astype(jnp.float32), lengths)
+    kpos = jnp.arange(T, dtype=jnp.int32)
+    amask = jnp.where(kpos[None] <= lengths[:, None], 0.0,
+                      -1e9).astype(jnp.float32)    # (M, T)
+    logits = jnp.einsum("mhw,mtw->mht", q.astype(jnp.float32), rows) * scale
+    probs = jax.nn.softmax(logits + amask[:, None, :], axis=-1)
+    out = jnp.einsum("mht,mtc->mhc", probs, rows[..., :value_dim])
     return out.astype(q.dtype)
 
 

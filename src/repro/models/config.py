@@ -35,6 +35,27 @@ class ModelConfig:
     capacity_factor: float = 1.25
     router_aux_weight: float = 0.01
     moe_groups: int = 1          # token-dispatch groups (= data shards at scale)
+    norm_topk_prob: bool = True  # renormalise the top-k gate weights to sum 1
+    #: leading layers whose feed-forward is a dense SwiGLU of ``d_ff``
+    #: (DeepSeek's ``first_k_dense_replace``); the rest are MoE layers
+    first_dense_layers: int = 0
+    #: expert ids this device holds (expert parallelism); the router still
+    #: scores all ``n_experts``, and the layer returns its experts' part.
+    #: Empty: every expert is held, with capacity-based dispatch
+    experts_held: Tuple[int, ...] = ()
+
+    # --- multi-head latent attention (DeepSeek-V2); kv_lora_rank 0 = off ---
+    kv_lora_rank: int = 0        # latent width cached per token
+    qk_nope_head_dim: int = 0    # per-head query/key width without rope
+    qk_rope_head_dim: int = 0    # rope width, one key shared by every head
+    v_head_dim: int = 0
+
+    # --- YaRN rope scaling; rope_factor 1 = plain rope ---
+    rope_factor: float = 1.0
+    rope_orig_max_pos: int = 0
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_mscale: float = 1.0     # mscale = mscale_all_dim
 
     # --- SSM / hybrid ---
     ssm_state: int = 0           # mamba state size N
@@ -89,6 +110,22 @@ class ModelConfig:
     def d_in(self) -> int:
         return self.d_inner or 2 * self.d_model
 
+    @property
+    def mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def latent_dim(self) -> int:
+        """Numbers cached per token and layer under latent attention."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def n_held(self) -> int:
+        return len(self.experts_held) or self.n_experts
+
+    def is_moe_layer(self, layer: int) -> bool:
+        return self.arch == "moe" and layer >= self.first_dense_layers
+
     def reduced(self, n_layers: int = 2, d_model: int = 256,
                 vocab: int = 512, **kw) -> "ModelConfig":
         """Smoke-test variant of the same family (CPU-friendly)."""
@@ -121,35 +158,52 @@ class ModelConfig:
         updates.update(kw)
         return replace(self, **updates)
 
+    def _attn_params(self) -> int:
+        D = self.d_model
+        if self.mla:
+            H, C = self.n_heads, self.kv_lora_rank
+            return (D * H * (self.qk_nope_head_dim + self.qk_rope_head_dim)
+                    + D * self.latent_dim + C
+                    + C * H * (self.qk_nope_head_dim + self.v_head_dim)
+                    + H * self.v_head_dim * D)
+        return D * self.q_dim + 2 * D * self.kv_dim + self.q_dim * D
+
     def param_count(self) -> int:
-        """Approximate parameter count N (for 6·N·D roofline math)."""
+        """Parameters held (for 6·N·D roofline math): per layer attention
+        (q/k/v/o, or the latent projections), norms and feed-forward
+        (dense, or router, held experts and shared experts); then the
+        embedding, the final norm and the head."""
         D, L, V = self.d_model, self.n_layers, self.vocab
-        attn = D * self.q_dim + 2 * D * self.kv_dim + self.q_dim * D
+        attn = self._attn_params()
         if self.arch == "ssm":
             # mLSTM block: qkv projections + gates + out + ff
             blk = 4 * D * self.hd * self.n_heads + 2 * D
         else:
-            blk = attn
-        if self.n_experts:
-            moe = self.n_experts * 3 * D * self.d_exp + D * self.n_experts
-            moe += self.n_shared_experts * 3 * D * self.d_exp
-            blk += moe
-        elif self.d_ff:
-            blk += 3 * D * self.d_ff
+            blk = attn + 2 * D           # and the block's two norms
         if self.arch in ("hybrid",):
             d_in = self.d_in
             blk += 2 * D * d_in + d_in * (2 * self.ssm_state + 2) + d_in * D
+        dense = 3 * D * self.d_ff if self.d_ff else 0
         total = L * blk + V * D * (1 if self.tie_embeddings else 2) + D
+        if self.n_experts:
+            n_moe = L - min(self.first_dense_layers, L)
+            moe = self.n_held * 3 * D * self.d_exp + D * self.n_experts
+            moe += self.n_shared_experts * 3 * D * self.d_exp
+            total += n_moe * moe + (L - n_moe) * dense
+        else:
+            total += L * dense
         if self.enc_layers:
             total += self.enc_layers * (attn + 3 * D * self.d_ff)
         return int(total)
 
     def active_param_count(self) -> int:
-        """Active params per token (MoE: only routed-to experts count)."""
+        """Active params per token (MoE: only the routed-to experts among
+        those held count, ``moe_top_k`` of ``n_experts`` on average)."""
         if not self.n_experts:
             return self.param_count()
-        D, L = self.d_model, self.n_layers
-        full = self.param_count()
-        all_expert = L * self.n_experts * 3 * D * self.d_exp
-        active_expert = L * self.moe_top_k * 3 * D * self.d_exp
-        return int(full - all_expert + active_expert)
+        D = self.d_model
+        n_moe = self.n_layers - min(self.first_dense_layers, self.n_layers)
+        expert = 3 * D * self.d_exp
+        held = n_moe * self.n_held * expert
+        active = n_moe * expert * self.moe_top_k * self.n_held / self.n_experts
+        return int(self.param_count() - held + active)

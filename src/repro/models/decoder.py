@@ -1,8 +1,9 @@
 """Unified decoder-only model covering dense / moe / ssm / hybrid / vlm.
 
 Homogeneous stacks (dense, moe, hybrid, vlm) scan over stacked per-layer
-params (MaxText-style) so lowering stays fast at 64 layers; the
-heterogeneous xLSTM stack (mLSTM/sLSTM interleave) uses a python loop.
+params (MaxText-style) so lowering stays fast at 64 layers; heterogeneous
+stacks (the xLSTM mLSTM/sLSTM interleave, and MoE models whose leading
+layers are dense) keep a list of per-layer params and use a python loop.
 
 Three entry points per architecture:
   * ``forward``      — full-sequence logits (training / teacher forcing)
@@ -23,6 +24,7 @@ from .common import (Params, causal_mask, constrain_batch,
                      constrain_batch_seq, dense_init, init_attention,
                      init_mlp, rms_norm, run_attention, run_mlp)
 from .config import ModelConfig
+from .mla import init_mla, run_mla
 from .moe import init_moe, run_moe
 from .ssm import init_mamba, init_mlstm, init_slstm, run_mamba, run_mlstm, run_slstm
 
@@ -33,13 +35,21 @@ CONV_K = 4
 # init
 # ======================================================================
 
-def init_block(cfg: ModelConfig, key: jax.Array, dtype: Any) -> Params:
+def blocks_listed(cfg: ModelConfig) -> bool:
+    """Whether the layers' params are a list (kinds differ by layer)
+    rather than one tree stacked by layer."""
+    return cfg.arch == "ssm" or cfg.first_dense_layers > 0
+
+
+def init_block(cfg: ModelConfig, key: jax.Array, dtype: Any,
+               layer: int = 0) -> Params:
     ks = jax.random.split(key, 4)
     p: Params = {"ln1": jnp.ones((cfg.d_model,), dtype)}
     if cfg.arch in ("dense", "vlm", "moe", "hybrid", "audio"):
-        p["attn"] = init_attention(cfg, ks[0], dtype)
+        p["attn"] = (init_mla(cfg, ks[0], dtype) if cfg.mla
+                     else init_attention(cfg, ks[0], dtype))
         p["ln2"] = jnp.ones((cfg.d_model,), dtype)
-        if cfg.arch == "moe":
+        if cfg.is_moe_layer(layer):
             p["moe"] = init_moe(cfg, ks[1], dtype)
         else:
             p["mlp"] = init_mlp(ks[1], cfg.d_model, cfg.d_ff, dtype)
@@ -77,8 +87,9 @@ def init_stage(cfg: ModelConfig, key: jax.Array, lo: int, hi: int,
             params["lm_head"] = dense_init(ks[1], (cfg.d_model, cfg.vocab),
                                            dtype)
     layer_keys = jax.random.split(ks[2], cfg.n_layers)[lo:hi]
-    if cfg.arch == "ssm":
-        params["blocks"] = [init_block(cfg, k, dtype) for k in layer_keys]
+    if blocks_listed(cfg):
+        params["blocks"] = [init_block(cfg, k, dtype, lo + i)
+                            for i, k in enumerate(layer_keys)]
     else:
         params["blocks"] = jax.vmap(
             lambda k: init_block(cfg, k, dtype))(layer_keys)
@@ -97,8 +108,11 @@ def run_block(cfg: ModelConfig, p: Params, x: jax.Array,
               positions: jax.Array,
               cache: Optional[Dict[str, jax.Array]] = None,
               cache_len: Optional[jax.Array] = None,
-              layer_idx: int = 0) -> Tuple[jax.Array, Optional[Dict[str, jax.Array]], jax.Array]:
-    """One transformer-ish block.  Returns (x, new_cache, aux_loss)."""
+              layer_idx: int = 0,
+              rows_out: Optional[list] = None,
+              ) -> Tuple[jax.Array, Optional[Dict[str, jax.Array]], jax.Array]:
+    """One transformer-ish block.  Returns (x, new_cache, aux_loss).
+    ``rows_out`` collects a MoE layer's rows per held expert (``run_moe``)."""
     aux = jnp.zeros((), jnp.float32)
     new_cache: Optional[Dict[str, jax.Array]] = None
     seq_par = (cfg.arch == "ssm" and cfg.seq_segments > 1 and x.shape[1] > 1
@@ -107,22 +121,31 @@ def run_block(cfg: ModelConfig, p: Params, x: jax.Array,
     x = constrain_batch_seq(x, cfg) if seq_par else constrain_batch(x, cfg)
     if cfg.arch in ("dense", "vlm", "moe", "hybrid", "audio"):
         h = rms_norm(x, p["ln1"], cfg.norm_eps)
-        kv = (cache["k"], cache["v"]) if cache is not None else None
-        attn_out, new_kv = run_attention(p["attn"], cfg, h, positions, kv, cache_len)
+        if cfg.mla:
+            attn_out, new_ckv = run_mla(
+                p["attn"], cfg, h, positions,
+                cache["ckv"] if cache is not None else None, cache_len)
+        else:
+            kv = (cache["k"], cache["v"]) if cache is not None else None
+            attn_out, new_kv = run_attention(p["attn"], cfg, h, positions,
+                                             kv, cache_len)
         if cfg.arch == "hybrid":
             mstate = ((cache["h"], cache["conv"]) if cache is not None else None)
             ssm_out, new_mstate = run_mamba(p["mamba"], cfg, h, mstate)
             attn_out = 0.5 * (attn_out + ssm_out)
         x = x + attn_out
         h = rms_norm(x, p["ln2"], cfg.norm_eps)
-        if cfg.arch == "moe":
+        if cfg.is_moe_layer(layer_idx):
             ffn_out, aux = run_moe(p["moe"], cfg, h,
                                    use_kernel=cfg.use_flash_kernel,
-                                   no_drop=cache is not None)
+                                   no_drop=cache is not None,
+                                   rows_out=rows_out)
         else:
             ffn_out = run_mlp(p["mlp"], h)
         x = x + ffn_out
-        if cache is not None:
+        if cache is not None and cfg.mla:
+            new_cache = {"ckv": new_ckv}
+        elif cache is not None:
             new_cache = {"k": new_kv[0], "v": new_kv[1]}
             if cfg.arch == "hybrid":
                 new_cache["h"], new_cache["conv"] = new_mstate
@@ -178,7 +201,7 @@ def apply_blocks(cfg: ModelConfig, blocks: Any, x: jax.Array,
     """Full-sequence pass of ``x`` through a stack of blocks (all of the
     model's, or one pipeline stage's starting at ``first_layer``).
     Returns (x, aux_loss)."""
-    if cfg.arch == "ssm":
+    if blocks_listed(cfg):
         aux = jnp.zeros((), jnp.float32)
         for i, bp in enumerate(blocks):
             x, _, a = run_block(cfg, bp, x, positions,
@@ -253,7 +276,9 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int,
 
     def per_layer() -> Dict[str, jax.Array]:
         c: Dict[str, jax.Array] = {}
-        if cfg.arch in ("dense", "vlm", "moe", "hybrid", "audio"):
+        if cfg.mla:
+            c["ckv"] = jnp.zeros((batch, kv_len, cfg.latent_dim), dtype)
+        elif cfg.arch in ("dense", "vlm", "moe", "hybrid", "audio"):
             c["k"] = jnp.zeros((batch, kv_len, Hk, hd), dtype)
             c["v"] = jnp.zeros((batch, kv_len, Hk, hd), dtype)
         if cfg.arch == "hybrid":
@@ -280,22 +305,40 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int,
     return cache
 
 
+def layer_cache(cfg: ModelConfig, layers: Any, i: int) -> Any:
+    """Layer ``i``'s cache: an entry of the recurrent stacks' list, else a
+    slice of the caches stacked by layer (every attention layer's cache
+    has one shape, whatever its params' kind)."""
+    if cfg.arch == "ssm":
+        return layers[i]
+    return jax.tree.map(lambda a: a[i], layers)
+
+
+def stack_layer_caches(cfg: ModelConfig, caches: list) -> Any:
+    """Inverse of :func:`layer_cache` over every layer."""
+    if cfg.arch == "ssm":
+        return caches
+    return jax.tree.map(lambda *xs: jnp.stack(xs), *caches)
+
+
 def _apply_layers_cached(params: Params, cfg: ModelConfig, x: jax.Array,
                          positions: jax.Array, cache: Dict[str, Any],
                          ) -> Tuple[jax.Array, Dict[str, Any]]:
     cache_len = cache["len"]
-    if cfg.arch == "ssm":
+    if blocks_listed(cfg):
         new_layers = []
         for i, bp in enumerate(params["blocks"]):
-            x, nc, _ = run_block(cfg, bp, x, positions, cache["layers"][i],
+            x, nc, _ = run_block(cfg, bp, x, positions,
+                                 layer_cache(cfg, cache["layers"], i),
                                  cache_len, layer_idx=i)
             new_layers.append(nc)
-        new_cache: Dict[str, Any] = {"layers": new_layers}
+        new_cache: Dict[str, Any] = {"layers": stack_layer_caches(cfg,
+                                                                  new_layers)}
     else:
         def body(carry, inputs):
             x = carry
-            bp, layer_cache = inputs
-            x, nc, _ = run_block(cfg, bp, x, positions, layer_cache, cache_len)
+            bp, lc = inputs
+            x, nc, _ = run_block(cfg, bp, x, positions, lc, cache_len)
             return x, nc
 
         x, new_layer_caches = jax.lax.scan(

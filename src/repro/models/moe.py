@@ -9,11 +9,17 @@ built to feed.
 
 Router gating (softmax → top-k → renormalize) has a Pallas kernel in
 ``repro.kernels.moe_gating``; the jnp path below doubles as its oracle.
+
+A layer told which experts it holds (``cfg.experts_held``: expert
+parallelism, one device's share) runs :func:`expert_share` instead: the
+router scores every expert, and the layer computes, exactly and dropping
+nothing, its own experts' part of the result for the tokens routed to
+them.  What the absent experts add comes from the devices that hold them.
 """
 
 from __future__ import annotations
 
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -46,35 +52,69 @@ def _constrain_groups(x: jax.Array, cfg: ModelConfig, dim: int = 0,
 
 
 def init_moe(cfg: ModelConfig, key: jax.Array, dtype: Any) -> Params:
+    """Router over all ``n_experts``; weights of the ``n_held`` held."""
     E, D, F = cfg.n_experts, cfg.d_model, cfg.d_exp
     ks = jax.random.split(key, 5)
     p: Params = {
         "router": dense_init(ks[0], (D, E), jnp.float32, scale=0.02),
-        "w_gate": dense_init(ks[1], (E, D, F), dtype),
-        "w_up": dense_init(ks[2], (E, D, F), dtype),
-        "w_down": dense_init(ks[3], (E, F, D), dtype),
+        "w_gate": dense_init(ks[1], (cfg.n_held, D, F), dtype),
+        "w_up": dense_init(ks[2], (cfg.n_held, D, F), dtype),
+        "w_down": dense_init(ks[3], (cfg.n_held, F, D), dtype),
     }
     if cfg.n_shared_experts:
         p["shared"] = init_mlp(ks[4], D, cfg.n_shared_experts * F, dtype)
     return p
 
 
-def topk_gating(logits: jax.Array, k: int) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """Softmax over experts, keep top-k, renormalize.
+def topk_gating(logits: jax.Array, k: int, renormalize: bool = True,
+                ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """Softmax over experts, keep the top k, and (``renormalize``) scale
+    the kept probabilities to sum to 1; without it each token's weights
+    are its experts' softmax probabilities as they stand (DeepSeek-V2's
+    ``norm_topk_prob`` false).
 
     logits: (T, E) float32.  Returns (weights (T,k), experts (T,k), probs (T,E)).
     This is the reference implementation; ``repro.kernels.moe_gating``
-    provides the fused TPU kernel.
+    provides the fused TPU kernel (renormalizing only).
     """
     probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
     weights, experts = jax.lax.top_k(probs, k)
-    weights = weights / jnp.maximum(
-        jnp.sum(weights, axis=-1, keepdims=True), 1e-9)
+    if renormalize:
+        weights = weights / jnp.maximum(
+            jnp.sum(weights, axis=-1, keepdims=True), 1e-9)
     return weights, experts, probs
+
+
+def expert_share(p: Params, cfg: ModelConfig, xt: jax.Array,
+                 ) -> Tuple[jax.Array, jax.Array]:
+    """The held experts' part of a MoE layer for tokens ``xt (T, D)``.
+
+    The router's float32 logits (at ``highest`` precision) score all
+    ``n_experts``; each token keeps its top ``moe_top_k``.  Every held
+    expert then runs on every token with the token's gate weight for it,
+    0 where the token did not pick it: capacity ``T`` per expert, so no
+    token is ever dropped, in prefill and decode alike.  Returns ``(y (T,
+    D), hits (T, n_held) bool)``: the routed part of the output (shared
+    experts not included) and which held experts each token picked.
+    """
+    logits = jnp.dot(xt.astype(jnp.float32), p["router"].astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    weights, experts, _ = topk_gating(logits, cfg.moe_top_k,
+                                      cfg.norm_topk_prob)
+    held = jnp.asarray(cfg.experts_held, jnp.int32)
+    pick = experts[:, :, None] == held                       # (T, K, n_held)
+    gate = jnp.sum(jnp.where(pick, weights[:, :, None], 0.0), axis=1)
+    h = jax.nn.silu(jnp.einsum("td,edf->etf", xt, p["w_gate"]))
+    h = h * jnp.einsum("td,edf->etf", xt, p["w_up"])
+    eo = jnp.einsum("etf,efd->etd", h, p["w_down"])
+    y = jnp.einsum("etd,te->td", eo, gate.astype(eo.dtype))
+    return y, jnp.any(pick, axis=1)
 
 
 def run_moe(p: Params, cfg: ModelConfig, x: jax.Array,
             use_kernel: bool = False, no_drop: bool = False,
+            rows_out: Optional[List[jax.Array]] = None,
+            live: Optional[jax.Array] = None,
             ) -> Tuple[jax.Array, jax.Array]:
     """x: (B, S, D) → (y, aux_loss).
 
@@ -82,11 +122,25 @@ def run_moe(p: Params, cfg: ModelConfig, x: jax.Array,
     case so no token is ever dropped mid-generation.  Training keeps the
     capacity-factor drop semantics (the aux loss pushes the router toward
     balance).
+
+    With ``cfg.experts_held`` the layer is :func:`expert_share` plus the
+    shared experts, dropless whatever ``no_drop`` says, with no aux loss;
+    ``rows_out``, when given, gets the rows routed to each held expert
+    ``(n_held,)`` int32, counting only the ``live`` tokens ``(B*S,)``.
     """
     B, S, D = x.shape
     E, K, F = cfg.n_experts, cfg.moe_top_k, cfg.d_exp
     T = B * S
     xt = x.reshape(T, D)
+    if cfg.experts_held:
+        y, hits = expert_share(p, cfg, xt)
+        if "shared" in p:
+            y = y + run_mlp(p["shared"], xt)
+        if rows_out is not None:
+            if live is not None:
+                hits = hits & live[:, None]
+            rows_out.append(jnp.sum(hits, axis=0, dtype=jnp.int32))
+        return y.reshape(B, S, D), jnp.zeros((), jnp.float32)
     logits = xt.astype(jnp.float32) @ p["router"]
     if use_kernel:
         from repro.kernels.ops import moe_gating

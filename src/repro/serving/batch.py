@@ -23,7 +23,12 @@ Two decode paths share the slot table:
   step: per layer, project q/k/v for the whole batch, run paged
   single-query attention (:mod:`repro.kernels.paged_attention`) over
   the block tables, and return the new k/v rows, which one donated
-  scatter then writes into the pool in place.  Prefill writes its pages
+  scatter then writes into the pool in place.  Latent-attention (MLA)
+  configs keep one row of ``latent_dim`` numbers per token and layer
+  instead of separate k and v, and decode with ``W_UK`` absorbed into
+  the query (:mod:`repro.models.mla`); a shard's layers may differ in
+  kind (a leading dense SwiGLU, then MoE layers running this device's
+  share of the experts).  Prefill writes its pages
   the same way, straight from the dense cache on the device: no step
   copies the pool between host and device.  The unfused path re-reads
   the shard weights once per session per token; the fused path reads
@@ -63,7 +68,9 @@ import numpy as np
 
 from repro.core import tracing
 from repro.core.simnet import Sim
-from repro.kernels.paged_attention import paged_attention_jnp
+from repro.kernels.paged_attention import (paged_attention_jnp,
+                                           paged_latent_attention_jnp)
+from repro.models import mla
 from repro.models.common import apply_rope, rms_norm, run_mlp
 from repro.models.moe import run_moe
 
@@ -109,13 +116,18 @@ class KVPool:
     Per layer ``k/v`` pools of shape ``(L, P, page, Hk, hd)``, held on
     ``device`` and grown geometrically, plus a free-page list — alloc and
     free are exact and symmetric.  ``quant`` stores int8 pages with
-    per-(page, kv-head) dequant scales ``(L, P, Hk)``.  Writes replace
+    per-(page, kv-head) dequant scales ``(L, P, Hk)``.  ``latent`` pools
+    (MLA) hold one row per token and layer and no values: ``kp`` is
+    ``(L, P, page, 1, latent_dim)`` and ``vp`` is None.  Writes replace
     the arrays with the outputs of donated in-place scatters
     (``_write_prefill``, ``_append_rows``); nothing copies the pool.
     """
 
     def __init__(self, n_layers: int, n_kv_heads: int, head_dim: int,
-                 page_size: int, quant: bool = False, device: Any = None):
+                 page_size: int, quant: bool = False, device: Any = None,
+                 latent: bool = False):
+        if latent and (quant or n_kv_heads != 1):
+            raise ValueError("latent pools are fp32 rows of one 'head'")
         self.L = n_layers
         self.Hk = n_kv_heads
         self.hd = head_dim
@@ -127,7 +139,7 @@ class KVPool:
         dt = jnp.int8 if quant else jnp.float32
         shape = (self.L, 0, page_size, self.Hk, self.hd)
         self.kp = jnp.zeros(shape, dt, device=device)
-        self.vp = jnp.zeros(shape, dt, device=device)
+        self.vp = None if latent else jnp.zeros(shape, dt, device=device)
         self.ks = (jnp.ones((self.L, 0, self.Hk), jnp.float32, device=device)
                    if quant else None)
         self.vs = (jnp.ones((self.L, 0, self.Hk), jnp.float32, device=device)
@@ -142,19 +154,25 @@ class KVPool:
         self.kp, self.vp, self.ks, self.vs = new
 
     @property
+    def _arrays_per_row(self) -> int:
+        return 1 if self.vp is None else 2
+
+    @property
     def page_bytes(self) -> int:
-        """Cache-resident bytes of one allocated page (k+v, + scales)."""
+        """Cache-resident bytes of one allocated page (k+v, + scales; a
+        latent pool's rows alone)."""
         per = self.L * self.page * self.Hk * self.hd * self.kp.dtype.itemsize
         scales = 2 * self.L * self.Hk * 4 if self.quant else 0
-        return 2 * per + scales
+        return self._arrays_per_row * per + scales
 
     @property
     def append_bytes(self) -> int:
-        """Pool bytes one appended token writes: its k/v row, or for int8
-        pages the whole requantized page and its scales."""
+        """Pool bytes one appended token writes: its k/v (or latent) row,
+        or for int8 pages the whole requantized page and its scales."""
         if self.quant:
             return self.page_bytes
-        return 2 * self.L * self.Hk * self.hd * self.kp.dtype.itemsize
+        return (self._arrays_per_row * self.L * self.Hk * self.hd
+                * self.kp.dtype.itemsize)
 
     def pages_in_use(self) -> int:
         return self.n_pages - len(self._free)
@@ -169,10 +187,13 @@ class KVPool:
         def ext(a: jax.Array, fill: float = 0.0) -> jax.Array:
             blk = jnp.full((self.L, add) + a.shape[2:], fill, a.dtype,
                            device=self.device)
-            return jnp.concatenate([a, blk], axis=1)
+            # an empty pool takes the block as it is: a concatenation would
+            # hold the new pages twice on the device for a moment
+            return jnp.concatenate([a, blk], axis=1) if a.shape[1] else blk
 
         self.kp = ext(self.kp)
-        self.vp = ext(self.vp)
+        if self.vp is not None:
+            self.vp = ext(self.vp)
         if self.quant:
             self.ks = ext(self.ks, 1.0)
             self.vs = ext(self.vs, 1.0)
@@ -193,15 +214,18 @@ def _write_prefill(pool: Tuple[Any, ...], tails: Any, slot: jax.Array,
                    pages: jax.Array, n_full: jax.Array, k: jax.Array,
                    v: jax.Array) -> Tuple[Tuple[Any, ...], Any]:
     """Write a prefilled slot's dense cache ``k/v (L, 1, cap, Hk, hd)``
-    into its ``cap // page`` pool ``pages`` in place.  Past the prompt
-    the dense cache holds zeros, which quantize to 0 under any scale.
-    int8 pools also keep page ``n_full`` (the partial one) in fp32 as
-    the slot's staging master (``tails``, row ``slot``), so appends
-    requantize from it and error never compounds."""
+    into its ``cap // page`` pool ``pages`` in place (a latent pool: ``k``
+    holds the rows, ``v`` is None).  Past the prompt the dense cache
+    holds zeros, which quantize to 0 under any scale.  int8 pools also
+    keep page ``n_full`` (the partial one) in fp32 as the slot's staging
+    master (``tails``, row ``slot``), so appends requantize from it and
+    error never compounds."""
     kp, vp, ks, vs = pool
     L, _, cap, Hk, hd = k.shape
     n = pages.shape[0]
     kpg = k[:, 0].reshape(L, n, cap // n, Hk, hd).astype(jnp.float32)
+    if vp is None:
+        return (kp.at[:, pages].set(kpg), None, None, None), tails
     vpg = v[:, 0].reshape(L, n, cap // n, Hk, hd).astype(jnp.float32)
     if ks is None:
         return (kp.at[:, pages].set(kpg), vp.at[:, pages].set(vpg),
@@ -221,11 +245,15 @@ def _append_rows(pool: Tuple[Any, ...], tails: Any, slots: jax.Array,
                  vn: Tuple[jax.Array, ...]) -> Tuple[Tuple[Any, ...], Any]:
     """Write row ``r``'s token k/v ``kn[r], vn[r] (L, Hk, hd)`` at
     ``(pages[r], offs[r])`` in place, for a fixed number of rows; padding
-    rows carry an out-of-range page (and slot) and are dropped.  int8
-    pools write the token into its slot's fp32 staging page (cleared at
-    offset 0) and requantize that whole page."""
+    rows carry an out-of-range page (and slot) and are dropped.  A latent
+    pool takes ``kn`` alone (``vn`` empty).  int8 pools write the token
+    into its slot's fp32 staging page (cleared at offset 0) and
+    requantize that whole page."""
     kp, vp, ks, vs = pool
     k = jnp.stack(kn, axis=1)                       # (L, M, Hk, hd)
+    if vp is None:
+        return (kp.at[:, pages, offs].set(k, mode="drop"),
+                None, None, None), tails
     v = jnp.stack(vn, axis=1)
     if ks is None:
         return (kp.at[:, pages, offs].set(k, mode="drop"),
@@ -246,6 +274,15 @@ def _append_rows(pool: Tuple[Any, ...], tails: Any, slots: jax.Array,
             vp.at[:, pages].set(qv, mode="drop"),
             ks.at[:, pages].set(sk, mode="drop"),
             vs.at[:, pages].set(sv, mode="drop")), (kt, vt)
+
+
+def _counts_experts(module: Any) -> bool:
+    """Whether ``module`` holds a MoE layer told which experts it holds."""
+    cfg = getattr(module, "cfg", None)
+    if cfg is None or not cfg.experts_held:
+        return False
+    lo = getattr(module, "lo", 0)
+    return any(cfg.is_moe_layer(lo + j) for j in range(module.n_layers))
 
 
 def _with_params(module: Any, params: Any) -> Any:
@@ -278,35 +315,51 @@ class SlotState:
 
 def _fused_block(cfg: Any, p: Any, x: jax.Array, positions: jax.Array,
                  bt: jax.Array, lengths: jax.Array, kp: jax.Array,
-                 vp: jax.Array, ks: Optional[jax.Array],
-                 vs: Optional[jax.Array],
-                 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """One attention-family block for a batch of single-token rows, with
-    KV read from the page pool.  Mirrors ``decoder.run_block``'s dense
-    decode math exactly (rms_norm -> q/k/v -> qk_norm -> rope -> masked
-    softmax over the cache -> wo -> residual -> ln2 -> mlp/moe)."""
+                 vp: Optional[jax.Array], ks: Optional[jax.Array],
+                 vs: Optional[jax.Array], layer: int,
+                 rows_out: Optional[List[jax.Array]] = None,
+                 ) -> Tuple[jax.Array, jax.Array, Optional[jax.Array]]:
+    """One attention-family block (global index ``layer``) for a batch of
+    single-token rows, with KV read from the page pool.  Mirrors
+    ``decoder.run_block``'s dense decode math exactly (rms_norm -> q/k/v
+    -> qk_norm -> rope -> masked softmax over the cache -> wo -> residual
+    -> ln2 -> mlp/moe).  Latent attention instead scores and sums the
+    cached latent rows with ``W_UK`` absorbed into the query and ``W_UV``
+    applied after (equal to ``mla.run_mla`` up to rounding); its new row
+    comes back as the "k" row, with no "v".  A MoE layer appends its rows
+    per held expert, over the live rows, to ``rows_out``."""
     ap = p["attn"]
     B = x.shape[0]
-    H, Hk, hd = cfg.n_heads, cfg.n_kv_heads, cfg.hd
     h = rms_norm(x, p["ln1"], cfg.norm_eps)
-    q = (h @ ap["wq"]).reshape(B, 1, H, hd)
-    k = (h @ ap["wk"]).reshape(B, 1, Hk, hd)
-    v = (h @ ap["wv"]).reshape(B, 1, Hk, hd)
-    if cfg.qk_norm:
-        q = rms_norm(q, ap["q_norm"], cfg.norm_eps)
-        k = rms_norm(k, ap["k_norm"], cfg.norm_eps)
-    q = apply_rope(q, positions, cfg.rope_theta)
-    k = apply_rope(k, positions, cfg.rope_theta)
-    attn = paged_attention_jnp(q[:, 0], kp, vp, bt, lengths,
-                               k[:, 0], v[:, 0], ks, vs)     # (B, H, hd)
-    x = x + attn.reshape(B, 1, H * hd) @ ap["wo"]
+    if cfg.mla:
+        row = mla.latent_rows(ap, cfg, h, positions)[:, 0]    # (B, W)
+        o_lat = paged_latent_attention_jnp(
+            mla.absorbed_query(ap, cfg, h[:, 0], positions), kp[:, :, 0],
+            bt, lengths, row, cfg.kv_lora_rank, mla.softmax_scale(cfg))
+        x = x + mla.absorbed_output(ap, cfg, o_lat)[:, None]
+        k_row, v_row = row[:, None], None
+    else:
+        H, Hk, hd = cfg.n_heads, cfg.n_kv_heads, cfg.hd
+        q = (h @ ap["wq"]).reshape(B, 1, H, hd)
+        k = (h @ ap["wk"]).reshape(B, 1, Hk, hd)
+        v = (h @ ap["wv"]).reshape(B, 1, Hk, hd)
+        if cfg.qk_norm:
+            q = rms_norm(q, ap["q_norm"], cfg.norm_eps)
+            k = rms_norm(k, ap["k_norm"], cfg.norm_eps)
+        q = apply_rope(q, positions, cfg.rope_theta)
+        k = apply_rope(k, positions, cfg.rope_theta)
+        attn = paged_attention_jnp(q[:, 0], kp, vp, bt, lengths,
+                                   k[:, 0], v[:, 0], ks, vs)     # (B, H, hd)
+        x = x + attn.reshape(B, 1, H * hd) @ ap["wo"]
+        k_row, v_row = k[:, 0], v[:, 0]
     h2 = rms_norm(x, p["ln2"], cfg.norm_eps)
-    if cfg.arch == "moe":
+    if cfg.is_moe_layer(layer):
         ffn, _ = run_moe(p["moe"], cfg, h2, use_kernel=cfg.use_flash_kernel,
-                         no_drop=True)
+                         no_drop=True, rows_out=rows_out,
+                         live=lengths > 0 if cfg.experts_held else None)
     else:
         ffn = run_mlp(p["mlp"], h2)
-    return x + ffn, k[:, 0], v[:, 0]
+    return x + ffn, k_row, v_row
 
 
 class BatchEngine:
@@ -328,8 +381,17 @@ class BatchEngine:
         # params are jit arguments (never closed over); shapes key the
         # trace cache, so steady-state decode is one compiled call per shape.
         # Named, so that prefill shows in a profile as ``jit_dense_apply``.
+        # A shard with MoE layers told which experts it holds also returns
+        # their rows per held expert ``(layers, n_held)``.
+        self._counts = _counts_experts(module)
+
         def dense_apply(params, x, pos, cache):
-            return _with_params(module, params).apply(x, pos, cache)
+            m = _with_params(module, params)
+            if not self._counts:
+                return m.apply(x, pos, cache)
+            rows: List[jax.Array] = []
+            out, cache = m.apply(x, pos, cache, rows_out=rows)
+            return out, cache, jnp.stack(rows)
 
         self._apply = jax.jit(dense_apply)
         supported = self._supports_fused(module)
@@ -346,9 +408,15 @@ class BatchEngine:
         if self.fused:
             cfg = module.cfg
             dev = _device_of(module.params)
-            self._pool = KVPool(module.n_layers, cfg.n_kv_heads, cfg.hd,
-                                page_size, quant=(self.kv_dtype == "int8"),
-                                device=dev)
+            if cfg.mla:
+                self._pool = KVPool(module.n_layers, 1, cfg.latent_dim,
+                                    page_size, quant=(self.kv_dtype == "int8"),
+                                    device=dev, latent=True)
+            else:
+                self._pool = KVPool(module.n_layers, cfg.n_kv_heads, cfg.hd,
+                                    page_size,
+                                    quant=(self.kv_dtype == "int8"),
+                                    device=dev)
             if self._pool.quant:
                 shape = (n_slots, module.n_layers, page_size,
                          cfg.n_kv_heads, cfg.hd)
@@ -378,6 +446,7 @@ class BatchEngine:
 
     def _build_fused_apply(self):
         cfg = self.module.cfg
+        count = self._counts
 
         def fused(params, x, positions, bt, lengths, kp, vp, ks, vs):
             m = _with_params(self.module, params)
@@ -387,21 +456,27 @@ class BatchEngine:
                 h = x[:, None, :]
             new_k: List[jax.Array] = []
             new_v: List[jax.Array] = []
+            expert_rows: List[jax.Array] = []
             for j in range(m.n_layers):
                 lp = m._layer_params(j)
                 h, kn, vn = _fused_block(
-                    cfg, lp, h, positions, bt, lengths, kp[j], vp[j],
+                    cfg, lp, h, positions, bt, lengths, kp[j],
+                    None if vp is None else vp[j],
                     None if ks is None else ks[j],
-                    None if vs is None else vs[j])
+                    None if vs is None else vs[j], m.lo + j, expert_rows)
                 new_k.append(kn)
                 new_v.append(vn)
             out = m.head(h)[:, 0] if m.is_last else h[:, 0]
             # each row's k/v (L, Hk, hd) as an output of its own, so the
             # engine hands rows to the append without slicing on the host
-            nk, nv = jnp.stack(new_k), jnp.stack(new_v)
+            nk = jnp.stack(new_k)
             rows = range(nk.shape[1])
-            return (out, tuple(nk[:, r] for r in rows),
-                    tuple(nv[:, r] for r in rows))
+            nv = None if vp is None else jnp.stack(new_v)
+            res = (out, tuple(nk[:, r] for r in rows),
+                   () if nv is None else tuple(nv[:, r] for r in rows))
+            # configs told which experts they hold also return each MoE
+            # layer's rows per held expert; no other config's program changes
+            return res + (jnp.stack(expert_rows),) if count else res
 
         return fused
 
@@ -519,6 +594,16 @@ class BatchEngine:
                 n += 1
         return n
 
+    def touch(self, sessions: List[Any]) -> None:
+        """Mark ``sessions`` used now: the service calls it as a reply
+        leaves, so time spent queued behind this server's own work never
+        counts as a client's idleness."""
+        now = self.sim.now
+        for sid in sessions:
+            st = self.by_session.get(sid)
+            if st is not None:
+                st.last_used = now
+
     def reap_idle(self, ttl: float) -> int:
         """Evict sessions untouched for ``ttl`` sim-seconds (crashed or
         timed-out clients must not pin slots forever)."""
@@ -574,7 +659,8 @@ class BatchEngine:
 
     def _pool_write_prefill(self, st: SlotState, k: jax.Array,
                             v: jax.Array) -> int:
-        """Write a prefilled slot's dense cache ``k/v (L, 1, cap, Hk, hd)``,
+        """Write a prefilled slot's dense cache ``k/v (L, 1, cap, Hk, hd)``
+        (latent pools: the rows ``(L, 1, cap, 1, latent_dim)`` and no v),
         as ``_apply`` left it on the device, into its pool pages in place
         (int8: and its fp32 staging page).  Returns the pool bytes
         written."""
@@ -587,8 +673,8 @@ class BatchEngine:
         return written
 
     def _pool_append(self, st: SlotState, kn: jax.Array,
-                     vn: jax.Array) -> None:
-        """Append one token's k/v ``(L, Hk, hd)`` at position
+                     vn: Optional[jax.Array]) -> None:
+        """Append one token's k/v ``(L, Hk, hd)`` (or latent row, no v) at position
         ``st.length`` (the page was allocated before the fused call).
         The write is queued; ``_flush_appends`` makes the step's writes."""
         pos = st.length
@@ -618,11 +704,25 @@ class BatchEngine:
         self.stats["kv_bytes_written"] += written
         return written
 
+    def _counted(self, span: Any, res: Tuple[Any, ...]) -> Tuple[Any, ...]:
+        """The outputs of ``_apply`` or ``_fused_apply`` without the rows
+        per held expert that a counting shard's programs add last; those
+        go on ``span`` while the tracer is on (and only then come back to
+        the host): ``expert_rows`` per MoE layer per held expert, and
+        ``experts_hit``, the held experts with a row, per MoE layer."""
+        if not self._counts:
+            return res
+        if tracing.TRACER.on:
+            rows = np.asarray(res[-1])
+            span.set(expert_rows=rows.tolist(),
+                     experts_hit=(rows > 0).sum(axis=1).tolist())
+        return res[:-1]
+
     def _prefill(self, session: Any, slot: int, x: np.ndarray,
                  max_len: int) -> Tuple[np.ndarray, float]:
         m = self.module
         with tracing.span("engine.prefill", flow=session, session=session,
-                          tokens=int(x.shape[1])):
+                          tokens=int(x.shape[1])) as span:
             self.stats["admitted"] += 1
             if self._slot_last_session[slot] not in (None, session):
                 self.stats["slot_reuse"] += 1
@@ -638,20 +738,21 @@ class BatchEngine:
                 # prefill runs through the unchanged dense path, then the
                 # resulting k/v move into pool pages and the dense cache is
                 # dropped — steady-state decode never touches it again
-                out, cache = self._apply(m.params, xj,
-                                         self._positions(0, 1, S), cache)
+                out, cache = self._counted(span, self._apply(
+                    m.params, xj, self._positions(0, 1, S), cache))
                 st.cache = None
                 st.length = S
                 st.pages = self._pool.alloc(cap // self.page_size)
+                layers = cache["layers"]
                 with tracing.span("kv.write_prefill",
                                   pages=len(st.pages)) as sp:
                     sp.set(bytes=self._pool_write_prefill(
-                        st, cache["layers"]["k"], cache["layers"]["v"]))
+                        st, *((layers["ckv"][..., None, :], None)
+                              if m.cfg.mla else (layers["k"], layers["v"]))))
             else:
                 self._fallback_pages += cap // self.page_size
-                out, st.cache = self._apply(m.params, xj,
-                                            self._positions(0, 1, S),
-                                            st.cache)
+                out, st.cache = self._counted(span, self._apply(
+                    m.params, xj, self._positions(0, 1, S), st.cache))
             self._note_pages()
             if m.is_last:
                 out = m.head(out[:, -1:])[:, 0]       # (1, vocab)
@@ -725,15 +826,15 @@ class BatchEngine:
         # them) are the part of the resident pool the step reads
         kv_read = sum(self._slot_kv_bytes(st) for _, _, st in live)
         self.stats["kv_bytes_live"] += int(kv_read)
-        with tracing.span("engine.fused", rows=len(live)):
-            out, nk, nv = self._fused_apply(
+        with tracing.span("engine.fused", rows=len(live)) as span:
+            out, nk, nv = self._counted(span, self._fused_apply(
                 m.params, jnp.asarray(xb), jnp.asarray(lengths[:, None]),
-                jnp.asarray(bt), jnp.asarray(lengths), *pool.arrays)
+                jnp.asarray(bt), jnp.asarray(lengths), *pool.arrays))
             out = np.asarray(out)
         served: List[Any] = []
         with tracing.span("kv.append", rows=len(live)) as sp:
             for r, (_, sid, st) in enumerate(live):
-                self._pool_append(st, nk[r], nv[r])
+                self._pool_append(st, nk[r], nv[r] if nv else None)
                 served.append(sid)
             sp.set(bytes=self._flush_appends())
         self.stats["step_sessions"] += len(served)

@@ -59,7 +59,8 @@ def tree_from_flat(flat: Dict[str, np.ndarray]) -> Any:
     """Rebuild a nested params pytree from ``{path: leaf}`` with
     ``/``-joined paths (the ``params_to_parts`` naming).  Levels whose keys
     are all decimal integers become lists — which is how list-of-dicts
-    block stacks (the ssm arch) flatten."""
+    block stacks (the ssm arch, and MoE stacks whose leading layers are
+    dense) flatten."""
     root: Dict[str, Any] = {}
     for path, leaf in flat.items():
         node = root

@@ -83,7 +83,7 @@ def split_params(cfg: ModelConfig, params: Any,
     shards = []
     for i, (lo, hi) in enumerate(plan):
         sub: Dict[str, Any] = {}
-        if cfg.arch == "ssm":
+        if decoder.blocks_listed(cfg):
             sub["blocks"] = params["blocks"][lo:hi]
         else:
             sub["blocks"] = jax.tree.map(lambda a: a[lo:hi], params["blocks"])
@@ -132,7 +132,7 @@ class ShardModule:
         return self.hi - self.lo
 
     def _layer_params(self, j: int) -> Any:
-        if self.cfg.arch == "ssm":
+        if decoder.blocks_listed(self.cfg):
             return self.params["blocks"][j]
         return jax.tree.map(lambda a: a[j], self.params["blocks"])
 
@@ -155,30 +155,26 @@ class ShardModule:
         return {"len": full["len"], "layers": layers}
 
     def apply(self, x: jax.Array, positions: jax.Array,
-              cache: Optional[Dict[str, Any]]) -> Tuple[jax.Array, Optional[Dict[str, Any]]]:
+              cache: Optional[Dict[str, Any]],
+              rows_out: Optional[List[jax.Array]] = None,
+              ) -> Tuple[jax.Array, Optional[Dict[str, Any]]]:
+        """The shard's layers over ``x``; ``rows_out`` collects each MoE
+        layer's rows per held expert (configs with ``experts_held``)."""
         cache_len = cache["len"] if cache is not None else None
         new_layers: List[Any] = []
         for j in range(self.n_layers):
             lp = self._layer_params(j)
-            if cache is not None:
-                if self.cfg.arch == "ssm":
-                    lc = cache["layers"][j]
-                else:
-                    lc = jax.tree.map(lambda a: a[j], cache["layers"])
-            else:
-                lc = None
+            lc = (decoder.layer_cache(self.cfg, cache["layers"], j)
+                  if cache is not None else None)
             x, nc, _ = decoder.run_block(
                 self.cfg, lp, x, positions, lc, cache_len,
-                layer_idx=self.lo + j)
+                layer_idx=self.lo + j, rows_out=rows_out)
             new_layers.append(nc)
         new_cache = None
         if cache is not None:
-            if self.cfg.arch == "ssm":
-                stacked = new_layers
-            else:
-                stacked = jax.tree.map(
-                    lambda *xs: jnp.stack(xs), *new_layers)
-            new_cache = {"len": cache_len + x.shape[1], "layers": stacked}
+            new_cache = {"len": cache_len + x.shape[1],
+                         "layers": decoder.stack_layer_caches(self.cfg,
+                                                              new_layers)}
         return x, new_cache
 
     def flops(self, tokens: int) -> float:
@@ -258,6 +254,7 @@ class InferenceV2Service(Service):
         tracing.phase(sid, "rpc.cpu_charge", session=sid, shard=shard,
                       virtual_s=cost)
         yield ctx.cpu(cost)
+        eng.touch([sid])
         tracing.phase(sid, "rpc.open.reply", session=sid, shard=shard)
         return {"x": out}
 
@@ -273,6 +270,7 @@ class InferenceV2Service(Service):
         shard = self.server.shard_idx
         tracing.phase(lead, "rpc.cpu_charge", shard=shard, virtual_s=cost)
         yield ctx.cpu(cost)
+        eng.touch(served)
         tracing.phase(lead, "rpc.step.reply", shard=shard)
         return {"x": out, "served": served}
 
@@ -295,7 +293,7 @@ class InferenceV2Service(Service):
 class ShardServer:
     def __init__(self, node: LatticaNode, cfg: ModelConfig, fleet: str,
                  shard_idx: int, module: ShardModule, n_slots: int = 8,
-                 page_size: int = 32, idle_ttl: float = 60.0,
+                 page_size: int = 32, idle_ttl: float = 300.0,
                  kv_dtype: str = "fp32"):
         self.node = node
         self.cfg = cfg

@@ -57,6 +57,25 @@ def test_slot_reuse_after_eviction(model):
     assert eng.stats["admitted"] == 2
 
 
+def test_idle_time_counts_from_the_last_reply(model):
+    """A session is idle from its last reply (``touch``, which the service
+    calls as a reply leaves), not from the compute before it: time spent
+    queued behind the server's own work is no idleness of the client."""
+    cfg, params = model
+    sim = Sim(seed=1)
+    eng = BatchEngine(_full_module(cfg, params), sim, n_slots=2, page_size=8)
+    x = np.asarray(jax.random.randint(jax.random.PRNGKey(1), (1, 4), 0,
+                                      cfg.vocab), np.int32)
+    sim.run_process(eng.open("A", x, 16))
+    sim.run(until=sim.now + 50)
+    eng.touch(["A", "never-opened"])
+    sim.run(until=sim.now + 40)
+    assert eng.reap_idle(60) == 0 and eng.slot_of("A") is not None
+    sim.run(until=sim.now + 30)
+    assert eng.reap_idle(60) == 1 and eng.slot_of("A") is None
+    assert eng.stats["idle_evicted"] == 1 and eng.slots_used == 0
+
+
 def test_admission_fifo_under_full_slot_table(model):
     cfg, params = model
     sim = Sim(seed=2)
