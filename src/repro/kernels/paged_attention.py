@@ -30,7 +30,9 @@ Two storage formats share the kernel:
 ``paged_latent_attention_jnp`` is the same gather-based decode over
 latent-attention (MLA) pools, whose pages hold one row per token: the
 row is the key and its first ``value_dim`` numbers the value, so each
-cached row is read once.
+cached row is read once.  Where the row's widths allow, the pool keeps
+a row's latent and its rope key in two arrays of whole lane tiles
+(``rope_pool``), so the engine writes them in place.
 
 ``paged_attention_jnp`` is the gather-based reference formulation used
 on CPU (Pallas interpret mode is far too slow for the serving hot loop)
@@ -117,7 +119,9 @@ def paged_attention_jnp(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
 def paged_latent_attention_jnp(q: jax.Array, pool: jax.Array,
                                block_tables: jax.Array, lengths: jax.Array,
                                row_new: jax.Array, value_dim: int,
-                               scale: float) -> jax.Array:
+                               scale: float,
+                               rope_pool: Optional[jax.Array] = None,
+                               ) -> jax.Array:
     """Gather-based paged decode attention over latent rows (MLA).
 
     q:            (M, H, W)    one latent-space query per slot and head
@@ -129,7 +133,18 @@ def paged_latent_attention_jnp(q: jax.Array, pool: jax.Array,
     Each cached row is gathered once and serves as key (all ``W``
     numbers) and as value (its first ``value_dim``).  Returns ``(M, H,
     value_dim)``: per head the softmax-weighted sum of the rows' latents.
+
+    With ``rope_pool`` the rows come split, in whole lane tiles: ``pool``
+    holds the latents ``c`` as ``(P, page, value_dim // 128, 128)`` and
+    ``rope_pool`` the rope keys ``k_pe`` as ``(P, page // g, 2, 128)``, g
+    tokens a slab in order.  The logits are then ``q_c · c + q_pe · k_pe``,
+    and the value ``c``: the two parts are never joined into one row, and
+    ``c`` keeps its tiles, since a ``(…, 512)`` view of the gathered rows
+    would be a copy of them.
     """
+    if rope_pool is not None:
+        return _split_latent_attention(q, pool, rope_pool, block_tables,
+                                       lengths, row_new, value_dim, scale)
     M, H, W = q.shape
     page = pool.shape[1]
     T = block_tables.shape[1] * page
@@ -143,6 +158,37 @@ def paged_latent_attention_jnp(q: jax.Array, pool: jax.Array,
     probs = jax.nn.softmax(logits + amask[:, None, :], axis=-1)
     out = jnp.einsum("mht,mtc->mhc", probs, rows[..., :value_dim])
     return out.astype(q.dtype)
+
+
+def _split_latent_attention(q: jax.Array, pool: jax.Array,
+                            rope_pool: jax.Array, block_tables: jax.Array,
+                            lengths: jax.Array, row_new: jax.Array,
+                            value_dim: int, scale: float) -> jax.Array:
+    """``paged_latent_attention_jnp`` over a split pool.  The new row
+    joins the softmax as a term of its own: written into the gathered
+    rows, it would be a loop of small operations, slot by slot."""
+    M, H, W = q.shape
+    T = block_tables.shape[1] * pool.shape[1]
+    tile = pool.shape[2:]                          # (value_dim // 128, 128)
+    qf, new = q.astype(jnp.float32), row_new.astype(jnp.float32)
+    q_c = qf[..., :value_dim].reshape((M, H) + tile)
+    q_pe = qf[..., value_dim:]
+    c_new = new[:, :value_dim].reshape((M,) + tile)
+    c = pool[block_tables].reshape((M, T) + tile)
+    pe = rope_pool[block_tables].reshape(M, T, W - value_dim)
+    kpos = jnp.arange(T, dtype=jnp.int32)
+    cached = (jnp.einsum("mhkl,mtkl->mht", q_c, c)
+              + jnp.einsum("mhr,mtr->mht", q_pe, pe)) * scale
+    cached = cached + jnp.where(kpos[None] < lengths[:, None], 0.0,
+                                -1e9).astype(jnp.float32)[:, None, :]
+    own = (jnp.einsum("mhkl,mkl->mh", q_c, c_new)
+           + jnp.einsum("mhr,mr->mh", q_pe, new[:, value_dim:])) * scale
+    top = jnp.maximum(cached.max(axis=-1), own)
+    p, p_own = jnp.exp(cached - top[..., None]), jnp.exp(own - top)
+    out = (jnp.einsum("mht,mtkl->mhkl", p, c)
+           + p_own[..., None, None] * c_new[:, None]
+           ) / (p.sum(axis=-1) + p_own)[..., None, None]
+    return out.reshape(M, H, value_dim).astype(q.dtype)
 
 
 # ======================================================================

@@ -25,7 +25,9 @@ Two decode paths share the slot table:
   the block tables, and return the new k/v rows, which one donated
   scatter then writes into the pool in place.  Latent-attention (MLA)
   configs keep one row of ``latent_dim`` numbers per token and layer
-  instead of separate k and v, and decode with ``W_UK`` absorbed into
+  instead of separate k and v (split into a latent and a rope-key array
+  of whole lane tiles where the widths allow, so that TPU scatters
+  write it in place), and decode with ``W_UK`` absorbed into
   the query (:mod:`repro.models.mla`); a shard's layers may differ in
   kind (a leading dense SwiGLU, then MoE layers running this device's
   share of the experts).  Prefill writes its pages
@@ -102,6 +104,17 @@ def _quant_page_int8(x: jax.Array) -> Tuple[jax.Array, jax.Array]:
     return q, scale
 
 
+def _latent_lanes(rank: int, rope: int, page: int) -> int:
+    """Tokens per rope slab of a latent pool laid out in whole lane tiles
+    (``rank`` latent numbers as ``(rank // 128, 128)``, ``g = 256 // rope``
+    tokens' rope keys per ``(2, 128)`` slab), or 0 where the widths do not
+    fit that layout and the pool keeps one ``(1, rank + rope)`` row."""
+    if (rank <= 0 or rope <= 0 or rank % 128 or 256 % rope
+            or page % (256 // rope)):
+        return 0
+    return 256 // rope
+
+
 def _device_of(params: Any) -> Any:
     """The device that holds ``params`` (None: JAX's default device)."""
     for leaf in jax.tree.leaves(params):
@@ -117,15 +130,20 @@ class KVPool:
     ``device`` and grown geometrically, plus a free-page list — alloc and
     free are exact and symmetric.  ``quant`` stores int8 pages with
     per-(page, kv-head) dequant scales ``(L, P, Hk)``.  ``latent`` pools
-    (MLA) hold one row per token and layer and no values: ``kp`` is
-    ``(L, P, page, 1, latent_dim)`` and ``vp`` is None.  Writes replace
-    the arrays with the outputs of donated in-place scatters
+    (MLA) hold one row of ``head_dim`` numbers per token and layer, the
+    latent ``c`` then ``rope_dim`` numbers of rope key, and no values.
+    Where the widths allow (``_latent_lanes``) the row is split into
+    whole lane tiles, which TPU scatters write in place: ``kp`` holds
+    ``c`` as ``(L, P, page, r // 128, 128)`` and ``vp`` the rope keys as
+    ``(L, P, page // g, 2, 128)``, g tokens a slab.  Otherwise ``kp`` is
+    ``(L, P, page, 1, head_dim)`` and ``vp`` is None.  Writes replace the
+    arrays with the outputs of donated in-place scatters
     (``_write_prefill``, ``_append_rows``); nothing copies the pool.
     """
 
     def __init__(self, n_layers: int, n_kv_heads: int, head_dim: int,
                  page_size: int, quant: bool = False, device: Any = None,
-                 latent: bool = False):
+                 latent: bool = False, rope_dim: int = 0):
         if latent and (quant or n_kv_heads != 1):
             raise ValueError("latent pools are fp32 rows of one 'head'")
         self.L = n_layers
@@ -133,13 +151,22 @@ class KVPool:
         self.hd = head_dim
         self.page = page_size
         self.quant = quant
+        self.latent = latent
         self.device = device
         self.n_pages = 0
         self._free: List[int] = []
         dt = jnp.int8 if quant else jnp.float32
         shape = (self.L, 0, page_size, self.Hk, self.hd)
-        self.kp = jnp.zeros(shape, dt, device=device)
-        self.vp = None if latent else jnp.zeros(shape, dt, device=device)
+        rank = head_dim - rope_dim
+        g = _latent_lanes(rank, rope_dim, page_size) if latent else 0
+        if g:
+            self.kp = jnp.zeros((self.L, 0, page_size, rank // 128, 128), dt,
+                                device=device)
+            self.vp = jnp.zeros((self.L, 0, page_size // g, 2, 128), dt,
+                                device=device)
+        else:
+            self.kp = jnp.zeros(shape, dt, device=device)
+            self.vp = None if latent else jnp.zeros(shape, dt, device=device)
         self.ks = (jnp.ones((self.L, 0, self.Hk), jnp.float32, device=device)
                    if quant else None)
         self.vs = (jnp.ones((self.L, 0, self.Hk), jnp.float32, device=device)
@@ -155,7 +182,7 @@ class KVPool:
 
     @property
     def _arrays_per_row(self) -> int:
-        return 1 if self.vp is None else 2
+        return 1 if self.latent else 2
 
     @property
     def page_bytes(self) -> int:
@@ -209,20 +236,61 @@ class KVPool:
         self._free.extend(pages)
 
 
+def _write_latent_lanes(kp: jax.Array, vp: jax.Array, pages: jax.Array,
+                        rows: jax.Array) -> Tuple[Any, ...]:
+    """Write the latent rows ``(L, cap, W)`` of ``cap // page`` whole
+    ``pages`` into a lane-layout pool: each token's ``c`` by a token-row
+    scatter, its rope key with the g tokens of its slab, slab by slab.
+    Both scatters write in place; a scatter of whole pages would not."""
+    L, cap, _ = rows.shape
+    n, page, slabs = pages.shape[0], kp.shape[2], vp.shape[2]
+    r = kp.shape[-2] * kp.shape[-1]
+    pg, off = jnp.repeat(pages, page), jnp.tile(jnp.arange(page), n)
+    kp = kp.at[:, pg, off].set(rows[..., :r].reshape((L, cap) + kp.shape[-2:]))
+    pg, off = jnp.repeat(pages, slabs), jnp.tile(jnp.arange(slabs), n)
+    vp = vp.at[:, pg, off].set(
+        rows[..., r:].reshape((L, n * slabs) + vp.shape[-2:]))
+    return kp, vp, None, None
+
+
+def _append_latent_lanes(kp: jax.Array, vp: jax.Array, pages: jax.Array,
+                         offs: jax.Array, rows: jax.Array) -> Tuple[Any, ...]:
+    """Write row ``m``'s latent row ``rows[:, m] (L, W)`` at ``(pages[m],
+    offs[m])`` of a lane-layout pool: ``c`` by a token-row scatter, the
+    rope key by reading its token's slab, putting the key in the token's
+    place and writing the slab back.  A slot appends at most one token a
+    step, so no two rows share a slab.  Padding rows' out-of-range pages
+    read zeros and are dropped."""
+    L, M, W = rows.shape
+    r = kp.shape[-2] * kp.shape[-1]
+    g = vp.shape[-2] * vp.shape[-1] // (W - r)       # tokens per slab
+    kp = kp.at[:, pages, offs].set(
+        rows[..., :r].reshape((L, M) + kp.shape[-2:]), mode="drop")
+    slab = offs // g
+    cur = vp.at[:, pages, slab].get(mode="fill", fill_value=0.0)
+    mine = jnp.arange(g * (W - r)) // (W - r) == (offs % g)[:, None]
+    new = jnp.where(mine, jnp.tile(rows[..., r:], g), cur.reshape(L, M, -1))
+    vp = vp.at[:, pages, slab].set(new.reshape(cur.shape), mode="drop")
+    return kp, vp, None, None
+
+
 @functools.partial(jax.jit, donate_argnums=(0, 1))
 def _write_prefill(pool: Tuple[Any, ...], tails: Any, slot: jax.Array,
                    pages: jax.Array, n_full: jax.Array, k: jax.Array,
                    v: jax.Array) -> Tuple[Tuple[Any, ...], Any]:
     """Write a prefilled slot's dense cache ``k/v (L, 1, cap, Hk, hd)``
     into its ``cap // page`` pool ``pages`` in place (a latent pool: ``k``
-    holds the rows, ``v`` is None).  Past the prompt the dense cache
-    holds zeros, which quantize to 0 under any scale.  int8 pools also
-    keep page ``n_full`` (the partial one) in fp32 as the slot's staging
-    master (``tails``, row ``slot``), so appends requantize from it and
-    error never compounds."""
+    holds the rows ``(L, 1, cap, 1, W)``, ``v`` is None).  Past the
+    prompt the dense cache holds zeros, which quantize to 0 under any
+    scale.  int8 pools also keep page ``n_full`` (the partial one) in fp32
+    as the slot's staging master (``tails``, row ``slot``), so appends
+    requantize from it and error never compounds."""
     kp, vp, ks, vs = pool
     L, _, cap, Hk, hd = k.shape
     n = pages.shape[0]
+    if v is None and vp is not None:
+        return _write_latent_lanes(kp, vp, pages,
+                                   k[:, 0, :, 0].astype(jnp.float32)), tails
     kpg = k[:, 0].reshape(L, n, cap // n, Hk, hd).astype(jnp.float32)
     if vp is None:
         return (kp.at[:, pages].set(kpg), None, None, None), tails
@@ -246,11 +314,14 @@ def _append_rows(pool: Tuple[Any, ...], tails: Any, slots: jax.Array,
     """Write row ``r``'s token k/v ``kn[r], vn[r] (L, Hk, hd)`` at
     ``(pages[r], offs[r])`` in place, for a fixed number of rows; padding
     rows carry an out-of-range page (and slot) and are dropped.  A latent
-    pool takes ``kn`` alone (``vn`` empty).  int8 pools write the token
+    pool takes ``kn`` alone (``vn`` empty), split into its two arrays in
+    the lane layout (``_append_latent_lanes``).  int8 pools write the token
     into its slot's fp32 staging page (cleared at offset 0) and
     requantize that whole page."""
     kp, vp, ks, vs = pool
     k = jnp.stack(kn, axis=1)                       # (L, M, Hk, hd)
+    if not vn and vp is not None:
+        return _append_latent_lanes(kp, vp, pages, offs, k[:, :, 0]), tails
     if vp is None:
         return (kp.at[:, pages, offs].set(k, mode="drop"),
                 None, None, None), tails
@@ -325,17 +396,20 @@ def _fused_block(cfg: Any, p: Any, x: jax.Array, positions: jax.Array,
     -> qk_norm -> rope -> masked softmax over the cache -> wo -> residual
     -> ln2 -> mlp/moe).  Latent attention instead scores and sums the
     cached latent rows with ``W_UK`` absorbed into the query and ``W_UV``
-    applied after (equal to ``mla.run_mla`` up to rounding); its new row
-    comes back as the "k" row, with no "v".  A MoE layer appends its rows
-    per held expert, over the live rows, to ``rows_out``."""
+    applied after (equal to ``mla.run_mla`` up to rounding), from one
+    pool array of rows or, in the lane layout, from ``kp`` (latents) and
+    ``vp`` (rope keys); its new row comes back as the "k" row, with no
+    "v".  A MoE layer appends its rows per held expert, over the live
+    rows, to ``rows_out``."""
     ap = p["attn"]
     B = x.shape[0]
     h = rms_norm(x, p["ln1"], cfg.norm_eps)
     if cfg.mla:
         row = mla.latent_rows(ap, cfg, h, positions)[:, 0]    # (B, W)
         o_lat = paged_latent_attention_jnp(
-            mla.absorbed_query(ap, cfg, h[:, 0], positions), kp[:, :, 0],
-            bt, lengths, row, cfg.kv_lora_rank, mla.softmax_scale(cfg))
+            mla.absorbed_query(ap, cfg, h[:, 0], positions),
+            kp[:, :, 0] if vp is None else kp, bt, lengths, row,
+            cfg.kv_lora_rank, mla.softmax_scale(cfg), rope_pool=vp)
         x = x + mla.absorbed_output(ap, cfg, o_lat)[:, None]
         k_row, v_row = row[:, None], None
     else:
@@ -411,7 +485,8 @@ class BatchEngine:
             if cfg.mla:
                 self._pool = KVPool(module.n_layers, 1, cfg.latent_dim,
                                     page_size, quant=(self.kv_dtype == "int8"),
-                                    device=dev, latent=True)
+                                    device=dev, latent=True,
+                                    rope_dim=cfg.qk_rope_head_dim)
             else:
                 self._pool = KVPool(module.n_layers, cfg.n_kv_heads, cfg.hd,
                                     page_size,
@@ -471,7 +546,7 @@ class BatchEngine:
             # engine hands rows to the append without slicing on the host
             nk = jnp.stack(new_k)
             rows = range(nk.shape[1])
-            nv = None if vp is None else jnp.stack(new_v)
+            nv = None if cfg.mla else jnp.stack(new_v)
             res = (out, tuple(nk[:, r] for r in rows),
                    () if nv is None else tuple(nv[:, r] for r in rows))
             # configs told which experts they hold also return each MoE
@@ -697,7 +772,8 @@ class BatchEngine:
             slots[r], pages[r], offs[r] = slot, pid, off
         pad = M - len(queued)
         kn = tuple(q[3] for q in queued) + (queued[0][3],) * pad
-        vn = tuple(q[4] for q in queued) + (queued[0][4],) * pad
+        vn = (() if pool.latent
+              else tuple(q[4] for q in queued) + (queued[0][4],) * pad)
         pool.arrays, self._tails = _append_rows(
             pool.arrays, self._tails, slots, pages, offs, kn, vn)
         written = len(queued) * pool.append_bytes

@@ -4,7 +4,11 @@ Each test lowers one kernel at a published model's head layout for a
 described (not attached) ``v5e:2x2`` topology and compiles it with the
 TPU compiler, which refuses what interpret mode accepts: blocks that break
 the tiling rule, VMEM overuse, unsupported in-kernel ops.  Nothing runs,
-so these say nothing about results or times.
+so these say nothing about results or times.  The KV pool's write
+programs and its latent attention are compiled the same way at
+DeepSeek-V2-Lite's serving shapes: the compiler's memory analysis must
+find no copy of the pool, and the attention over the split pool must
+read no more than over one array of rows.
 
 The topology is described inside a module fixture, never at import: only
 one process at a time may load the TPU compiler library, and every test
@@ -20,7 +24,9 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.kernels.flash_attention import flash_attention_bhsd
 from repro.kernels.moe_gating import moe_gating_tokens
-from repro.kernels.paged_attention import paged_attention_pallas
+from repro.kernels.paged_attention import (paged_attention_pallas,
+                                           paged_latent_attention_jnp)
+from repro.serving import batch
 
 #: (name, n_heads, n_kv_heads, head_dim) of the configs the paged path serves
 PAGED_LAYOUTS = [("minicpm-2b", 36, 36, 64), ("granite-8b", 32, 8, 128)]
@@ -97,3 +103,64 @@ def test_moe_gating_compiles(one_chip):
         return moe_gating_tokens(logits, 4, interpret=False)
 
     assert "tpu_custom_call" in _compiled_text(fn, spec)
+
+
+@pytest.mark.parametrize("program", ["append", "prefill"])
+def test_latent_pool_writes_compile_in_place(one_chip, program):
+    """DeepSeek-V2-Lite's pool at its serving shapes (7 layers, 3,840
+    pages of 32 rows of 512 + 64 numbers, 48 slots): the donated append
+    and prefill scatters write in place.  A pool laid out anew around the
+    scatter shows as temporaries the size of the pool (2.5 GB)."""
+    L, P, page, M, W, rope = 7, 3840, 32, 48, 576, 64
+    pool = batch.KVPool(L, 1, W, page, latent=True, rope_dim=rope)
+    assert pool.vp is not None                       # the lane layout
+    arrays = tuple(None if a is None else
+                   _spec(one_chip, (L, P) + a.shape[2:], a.dtype)
+                   for a in pool.arrays)
+    i32 = jnp.int32
+    if program == "append":
+        fn = batch._append_rows
+        args = (arrays, None, _spec(one_chip, (M,), i32),
+                _spec(one_chip, (M,), i32), _spec(one_chip, (M,), i32),
+                (_spec(one_chip, (L, 1, W), jnp.float32),) * M, ())
+    else:
+        n = 65                                       # a 2,048-token prompt
+        fn = batch._write_prefill
+        args = (arrays, None, _spec(one_chip, (), i32),
+                _spec(one_chip, (n,), i32), _spec(one_chip, (), i32),
+                _spec(one_chip, (L, 1, n * page, 1, W), jnp.float32), None)
+    mem = fn.lower(*args).compile().memory_analysis()
+    assert mem.temp_size_in_bytes < 64 << 20, mem.temp_size_in_bytes
+
+
+def test_split_latent_attention_reads_no_more_than_rows(one_chip):
+    """48 slots of block tables padded to 128 pages of 32 over 3,840
+    pages: by the compiler's cost analysis the split pool's attention
+    accesses fewer bytes, and keeps fewer temporaries, than the one-array
+    form.  Reading the gathered latents as rows of 512 would relayout
+    them (about 10% more bytes than the one-array form)."""
+    P, page, M, H, NP, r, rope = 3840, 32, 48, 16, 128, 512, 64
+    i32 = jnp.int32
+    common = [_spec(one_chip, (M, NP), i32), _spec(one_chip, (M,), i32),
+              _spec(one_chip, (M, r + rope), jnp.float32)]
+    q = _spec(one_chip, (M, H, r + rope), jnp.float32)
+
+    def rows(q, pool, bt, n, new):
+        return paged_latent_attention_jnp(q, pool, bt, n, new, r, 0.1)
+
+    def split(q, c, pe, bt, n, new):
+        return paged_latent_attention_jnp(q, c, bt, n, new, r, 0.1,
+                                          rope_pool=pe)
+
+    def cost(fn, *specs):
+        c = jax.jit(fn).lower(*specs).compile()
+        ca = c.cost_analysis()
+        ca = ca[0] if isinstance(ca, list) else ca
+        return ca["bytes accessed"], c.memory_analysis().temp_size_in_bytes
+
+    want = cost(rows, q, _spec(one_chip, (P, page, r + rope), jnp.float32),
+                *common)
+    got = cost(split, q, _spec(one_chip, (P, page, r // 128, 128),
+                               jnp.float32),
+               _spec(one_chip, (P, page // 4, 2, 128), jnp.float32), *common)
+    assert got[0] <= want[0] and got[1] <= want[1], (got, want)
