@@ -1,27 +1,21 @@
-"""Fused paged-decode vs per-slot decode, and int8 vs fp32 KV cache.
+"""Fused paged-decode vs per-slot decode.
 
-Drives three :class:`~repro.serving.batch.BatchEngine` configurations
+Drives two :class:`~repro.serving.batch.BatchEngine` configurations
 over the same prompt/token feed at example scale:
 
 * ``unfused`` — the per-slot fallback loop (one ``module.apply`` per
   session per token: M weight passes per decode step),
 * ``fused``   — the batched paged-attention path (one weight pass per
-  step, KV gathered from the shared page pool),
-* ``int8``    — fused with the quantized pool (per-page per-kv-head
-  scales, fp32 staging tail for the partial page).
+  step, KV gathered from the shared page pool).
 
 Throughput is tokens per *simulated* second under the engine's roofline
 cost model (max of compute time and weight+KV bandwidth time at
 ``PEER_FLOPS``/``PEER_BW``): decode at batch M is bandwidth-bound, so
 charging the weight read once per batch instead of once per session is
 the fused win and the simnet cost model prices exactly that.  Cache
-bytes are the engine's actual resident pool/cache bytes.  Logit fidelity
-is measured, not assumed: the int8 engine's final-step logits are
-compared against the fp32 fused engine's on the same feed, with the
-max deviation reported next to the gate bound.
+bytes are the engine's actual resident pool/cache bytes.
 
-``--kernel-smoke`` gates: fused ≥2× unfused tokens/s, int8 cache ≤0.55×
-fp32 bytes, int8 max logit deviation ≤ LOGIT_DEV_BOUND.
+``--kernel-smoke`` gates: fused ≥2× unfused tokens/s.
 """
 
 from __future__ import annotations
@@ -37,13 +31,6 @@ from repro.core.simnet import Sim
 from repro.models import ops_for
 from repro.serving.batch import BatchEngine
 from repro.serving.sharded import ShardModule
-
-#: accepted max |logit_int8 - logit_fp32| at this scale.  Per-page int8
-#: bounds each cached K/V element's error by page_absmax/254 (<1%-scale
-#: relative); measured deviation after attention + 4 layers is ~0.03 on
-#: unnormalized ~[-15, 15] logits here, and the gate pins it at ~8x that
-#: so quantization drift shows up as a red CI, not silent decay.
-LOGIT_DEV_BOUND = 0.25
 
 N_SESSIONS = 8
 PROMPT_LEN = 12
@@ -94,29 +81,21 @@ def main(report: List[str], smoke: bool = False) -> Dict[str, Any]:
     params = ops_for(cfg).init(cfg, jax.random.PRNGKey(0))
 
     rows = {}
-    logits = {}
     feed = None
-    # the fp32 fused run goes first and records its greedy token feed;
-    # the other engines replay it, so logit deltas are pure cache numerics
-    for name, kw in (("fused", {}),
-                     ("unfused", {"fused": False}),
-                     ("int8", {"kv_dtype": "int8"})):
+    # the fused run goes first and records its greedy token feed; the
+    # unfused engine replays it, so both decode the same tokens
+    for name, kw in (("fused", {}), ("unfused", {"fused": False})):
         sim = Sim(seed=3)
         eng = _build_engine(cfg, params, sim, **kw)
-        cost, tokens, cache_bytes, last, fed = _drive(eng, sim, feed)
+        cost, tokens, cache_bytes, _, fed = _drive(eng, sim, feed)
         if feed is None:
             feed = fed
         rows[name] = {"decode_cost_s": cost, "tokens": tokens,
                       "tokens_per_s": tokens / max(cost, 1e-12),
                       "cache_bytes": cache_bytes,
-                      "fused": eng.fused, "kv_dtype": eng.kv_dtype}
-        logits[name] = last
+                      "fused": eng.fused}
 
     speedup = rows["fused"]["tokens_per_s"] / rows["unfused"]["tokens_per_s"]
-    byte_ratio = rows["int8"]["cache_bytes"] / rows["fused"]["cache_bytes"]
-    same_path = np.array_equal(np.argmax(logits["int8"], axis=-1),
-                               np.argmax(logits["fused"], axis=-1))
-    logit_dev = float(np.abs(logits["int8"] - logits["fused"]).max())
 
     report.append(f"# Decode step: {N_SESSIONS} sessions, "
                   f"{PROMPT_LEN}-token prompts, {DECODE_STEPS} decode steps "
@@ -127,30 +106,20 @@ def main(report: List[str], smoke: bool = False) -> Dict[str, Any]:
         report.append(f"{name:<10}{r['tokens_per_s']:>12.0f}"
                       f"{r['decode_cost_s']:>12.2e}"
                       f"{r['cache_bytes'] / 1024:>12.1f}")
-    report.append(f"fused speedup: {speedup:.2f}x   int8 cache: "
-                  f"{byte_ratio:.2f}x fp32 bytes")
-    report.append(f"int8 max logit deviation: {logit_dev:.4f} "
-                  f"(bound {LOGIT_DEV_BOUND}, greedy path "
-                  f"{'identical' if same_path else 'DIVERGED'})")
+    report.append(f"fused speedup: {speedup:.2f}x")
 
     metrics = {
         "engines": rows,
         "fused_speedup": speedup,
-        "int8_cache_ratio": byte_ratio,
-        "int8_max_logit_dev": logit_dev,
-        "logit_dev_bound": LOGIT_DEV_BOUND,
-        "greedy_path_identical": bool(same_path),
-        "gates": {"fused_speedup_min": 2.0, "int8_cache_ratio_max": 0.55},
+        "gates": {"fused_speedup_min": 2.0},
     }
     if smoke:
-        ok = (speedup >= 2.0 and byte_ratio <= 0.55
-              and logit_dev <= LOGIT_DEV_BOUND)
+        ok = speedup >= 2.0
         report.append(f"smoke: {'OK' if ok else 'FAIL'}")
         if not ok:
             raise SystemExit(
                 f"decode_step smoke failed: speedup={speedup:.2f} "
-                f"(need >=2), int8_ratio={byte_ratio:.2f} (need <=0.55), "
-                f"logit_dev={logit_dev:.4f} (need <={LOGIT_DEV_BOUND})")
+                f"(need >=2)")
     return metrics
 
 

@@ -2,10 +2,10 @@
 per-token latency, and failover cost when a shard dies mid-service.
 
 ``main_serving`` benchmarks the continuous-batching plane: N concurrent
-clients against a 2-shard × 2-replica fleet, sequential v1 baseline vs
-batched v2 tokens/s, p50/p95 request latency, one provider killed
-mid-run (must lose zero sessions), and a pressure-spawned hot-shard
-replica.  Emits ``BENCH_serving.json``; ``--serve-smoke`` runs the
+clients against a 2-shard × 2-replica fleet, tokens/s of one request at
+a time vs all of them batched, p50/p95 request latency, one provider
+killed mid-run (must lose zero sessions), and a pressure-spawned
+hot-shard replica.  Emits ``BENCH_serving.json``; ``--serve-smoke`` runs the
 reduced gating variant used by CI.
 """
 
@@ -49,7 +49,8 @@ def main(report: List[str]) -> Dict[str, Any]:
 
     def generate(n_tokens: int) -> Generator:
         t0 = sim.now
-        out = yield from client.generate(toks, n_tokens)
+        out = yield from client.generate_concurrent(
+            [{"tokens": t, "n_tokens": n_tokens} for t in toks])
         return out, sim.now - t0
 
     out, t_gen = sim.run_process(generate(16), until=sim.now + 3600)
@@ -64,7 +65,8 @@ def main(report: List[str]) -> Dict[str, Any]:
     t0 = sim.now
 
     def one_more() -> Generator:
-        out = yield from client.generate(toks, 1)
+        out = yield from client.generate_concurrent(
+            [{"tokens": t, "n_tokens": 1} for t in toks])
         return out
 
     sim.run_process(one_more(), until=sim.now + 3600)
@@ -99,14 +101,13 @@ def main_serving(report: List[str], smoke: bool = False) -> Dict[str, Any]:
         jax.random.randint(jax.random.PRNGKey(100 + i), (1, 8), 0, cfg.vocab),
         np.int32) for i in range(8)]
 
-    # -- sequential baseline: same fleet, one v1 request at a time ----------
+    # -- sequential baseline: same fleet, one request at a time -------------
     seq_client = ShardClient(fleet.peers[-1], cfg, "bench", n_shards=2)
 
     def sequential() -> Generator:
         t0 = sim.now
         for i in range(seq_probe):
-            yield from seq_client.generate(prompts[i % len(prompts)],
-                                           n_tokens)
+            yield seq_client.submit(prompts[i % len(prompts)], n_tokens)
         return sim.now - t0
 
     seq_time = sim.run_process(sequential(), until=sim.now + 3600)
@@ -186,9 +187,9 @@ def main_serving(report: List[str], smoke: bool = False) -> Dict[str, Any]:
     }
     report.append(f"# Serving: {n_clients} concurrent clients, "
                   f"2 shards x 2 replicas, {n_slots} slots/replica")
-    report.append(f"sequential v1: {seq_tps:8.1f} tok/s "
+    report.append(f"sequential: {seq_tps:8.1f} tok/s "
                   f"({seq_probe} requests probed)")
-    report.append(f"batched v2:   {bat_tps:8.1f} tok/s "
+    report.append(f"batched:    {bat_tps:8.1f} tok/s "
                   f"({metrics['speedup']:.1f}x, "
                   f"p50={p50*1000:.0f}ms p95={p95*1000:.0f}ms)")
     report.append(f"provider killed mid-run: {bool(killed)}  "

@@ -11,8 +11,8 @@ Walks through the paper's four scenarios at toy scale:
   5. concurrent serving: continuous batching over a 2-shard × 2-replica
      fleet, with pressure-driven replica spawn on the hot shard
   5b. the decode hot path: fused paged-attention decode (one weight pass
-     per batch) vs the per-slot loop, int8 KV cache, and int8_block wire
-     quantization for checkpoint sync
+     per batch) vs the per-slot loop, and int8_block wire quantization
+     for checkpoint sync
   6. a typed RPC service (MethodSpec-declared unary + streaming methods,
      called through a generated stub)
   7. the analysis plane: latlint rules + sanitized simulation
@@ -248,16 +248,12 @@ def main():
     # per-slot fallback pays a full weight read per session per token —
     # at decode, which is bandwidth-bound, that is the whole difference
     # (measured 6.4x tokens/s at 8 sessions: BENCH_decode_step.json).
-    # `kv_dtype="int8"` stores pool pages quantized with per-page
-    # per-kv-head scales: 0.38x the fp32 cache bytes, greedy tokens
-    # identical at this scale.
     from repro.core.simnet import Sim as _Sim
     from repro.serving.batch import BatchEngine
     from repro.serving.sharded import ShardModule
 
     perf = {}
-    for label, kw in (("fused", {}), ("unfused", {"fused": False}),
-                      ("int8", {"kv_dtype": "int8"})):
+    for label, kw in (("fused", {}), ("unfused", {"fused": False})):
         dsim = _Sim(seed=5)
         eng = BatchEngine(
             ShardModule(scfg, sparams, (0, scfg.n_layers), is_first=True,
@@ -274,11 +270,10 @@ def main():
                 toks[sid] = int(np.argmax(row))
             cost += c
             n_tok += len(served)
-        perf[label] = (n_tok / cost, eng.kv_bytes())
-    print(f"== 5b. decode: fused {perf['fused'][0]:.0f} tok/s vs per-slot "
-          f"{perf['unfused'][0]:.0f} "
-          f"({perf['fused'][0] / perf['unfused'][0]:.1f}x); int8 KV pool "
-          f"{perf['int8'][1] / perf['fused'][1]:.2f}x fp32 cache bytes ==")
+        perf[label] = n_tok / cost
+    print(f"== 5b. decode: fused {perf['fused']:.0f} tok/s vs per-slot "
+          f"{perf['unfused']:.0f} "
+          f"({perf['fused'] / perf['unfused']:.1f}x) ==")
 
     # Checkpoint sync can quantize the *wire* the same way: int8 per
     # 4096-element block with f32 scale+zero-point, per-tensor parts, the

@@ -71,7 +71,7 @@ def _finish(sim: Sim, fingerprint: Any) -> GateRun:
 
 
 # ---------------------------------------------------------------------------
-# serving gate: sharded inference fleet, score + generate round-trips
+# serving gate: sharded inference fleet, one generation round-trip
 # ---------------------------------------------------------------------------
 
 
@@ -104,7 +104,7 @@ def serving_gate(seed: int = 0, perturb: Optional[int] = None) -> GateRun:
     toks = np.arange(8, dtype=np.int32)[None, :] % cfg.vocab
 
     def ask() -> Generator:
-        out = yield from client.generate(toks, 3)
+        out = yield client.submit(toks, 3)
         return out
 
     # warm-up dials every shard and populates the connection pool; only

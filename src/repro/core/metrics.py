@@ -66,7 +66,7 @@ def node_snapshot(node: "LatticaNode") -> Dict[str, Any]:
     clients = getattr(node, "shard_clients", [])
     if clients:
         for key in ("requests", "completed", "failed_sessions",
-                    "sessions_migrated", "failovers", "hedged", "calls"):
+                    "sessions_migrated", "failovers", "calls"):
             snap[f"serving.client.{key}"] = sum(c.stats[key] for c in clients)
     return snap
 

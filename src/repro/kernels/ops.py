@@ -44,25 +44,22 @@ def moe_gating(logits: jax.Array, k: int,
 
 
 @jax.jit
-def _paged_pallas(q, k_pool, v_pool, block_tables, lengths, k_new, v_new,
-                  k_scales=None, v_scales=None):
+def _paged_pallas(q, k_pool, v_pool, block_tables, lengths, k_new, v_new):
     return paged_attention_pallas(q, k_pool, v_pool, block_tables, lengths,
-                                  k_new, v_new, k_scales, v_scales,
-                                  interpret=_interpret())
+                                  k_new, v_new, interpret=_interpret())
 
 
 @jax.jit
-def _paged_jnp(q, k_pool, v_pool, block_tables, lengths, k_new, v_new,
-               k_scales=None, v_scales=None):
+def _paged_jnp(q, k_pool, v_pool, block_tables, lengths, k_new, v_new):
     return paged_attention_jnp(q, k_pool, v_pool, block_tables, lengths,
-                               k_new, v_new, k_scales, v_scales)
+                               k_new, v_new)
 
 
 def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths,
-                           k_new, v_new, k_scales=None, v_scales=None):
+                           k_new, v_new):
     """Single-query decode attention over a paged KV pool.
 
-    q (M,H,hd); pools (P,page,Hk,hd) fp32 or int8 (+ (P,Hk) scales);
+    q (M,H,hd); pools (P,page,Hk,hd);
     block_tables (M,NP) int32; lengths (M,) cached tokens; k/v_new
     (M,Hk,hd) the current token (attended at position ``lengths``).
     On TPU this runs the Pallas kernel (block-table scalar prefetch);
@@ -70,8 +67,7 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths,
     orders of magnitude too slow for a serving hot loop.
     """
     fn = _paged_pallas if jax.default_backend() == "tpu" else _paged_jnp
-    return fn(q, k_pool, v_pool, block_tables, lengths, k_new, v_new,
-              k_scales, v_scales)
+    return fn(q, k_pool, v_pool, block_tables, lengths, k_new, v_new)
 
 
 @functools.partial(jax.jit, static_argnames=("chunk",))

@@ -20,13 +20,6 @@ initialising the running state with its contribution: ``m = s_self``,
 stale page contents (including the just-allocated page the engine will
 write this token into *after* the call) never leak into the output.
 
-Two storage formats share the kernel:
-
-* fp32 pools — exact.
-* int8 pools with per-(page, kv-head) scales (``k_scales``/``v_scales``
-  of shape ``(P, Hk)``) — dequantised inside the kernel, quartering
-  pool bytes for a bounded logit error (|x̂-x| <= page_absmax/254).
-
 ``paged_latent_attention_jnp`` is the same gather-based decode over
 latent-attention (MLA) pools, whose pages hold one row per token: the
 row is the key and its first ``value_dim`` numbers the value, so each
@@ -66,21 +59,18 @@ NEG_INF = -1e30
 
 def paged_attention_jnp(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
                         block_tables: jax.Array, lengths: jax.Array,
-                        k_new: jax.Array, v_new: jax.Array,
-                        k_scales: Optional[jax.Array] = None,
-                        v_scales: Optional[jax.Array] = None) -> jax.Array:
+                        k_new: jax.Array, v_new: jax.Array) -> jax.Array:
     """Gather-based paged decode attention.
 
     q:            (M, H, hd)   one query per slot
-    k/v_pool:     (P, page, Hk, hd)  fp32, or int8 when scales given
+    k/v_pool:     (P, page, Hk, hd)
     block_tables: (M, NP) int32 pool page ids (padded entries masked out)
     lengths:      (M,) int32   cached tokens per slot (query position)
     k/v_new:      (M, Hk, hd)  this step's k/v, attended at position
                   ``lengths`` (the engine writes it to the pool after)
-    k/v_scales:   (P, Hk) fp32 per-page per-kv-head dequant scales
 
     Returns (M, H, hd).  Matches the dense-cache decode path of
-    ``models/common.run_attention`` bit-for-bit for fp32 pools: the
+    ``models/common.run_attention`` bit-for-bit: the
     gathered cache is laid out exactly like the dense cache (new token
     scattered at index ``lengths``), masked additively with -1e9, and
     reduced with the same fp32 einsum/softmax contractions.
@@ -91,9 +81,6 @@ def paged_attention_jnp(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     T = NP * page
     kg = k_pool[block_tables]                      # (M, NP, page, Hk, hd)
     vg = v_pool[block_tables]
-    if k_scales is not None:
-        kg = kg.astype(jnp.float32) * k_scales[block_tables][:, :, None, :, None]
-        vg = vg.astype(jnp.float32) * v_scales[block_tables][:, :, None, :, None]
     kg = kg.reshape(M, T, Hk, hd).astype(jnp.float32)
     vg = vg.reshape(M, T, Hk, hd).astype(jnp.float32)
     # place the current token at its true cache index so the layout (and
@@ -196,13 +183,8 @@ def _split_latent_attention(q: jax.Array, pool: jax.Array,
 # ======================================================================
 
 def _paged_kernel(bt_ref, len_ref, q_ref, kn_ref, vn_ref, k_ref, v_ref,
-                  *rest, page: int, n_pages: int, rep: int, scale: float,
-                  quantized: bool):
-    if quantized:
-        ks_ref, vs_ref, o_ref, acc_ref, m_ref, l_ref = rest
-    else:
-        o_ref, acc_ref, m_ref, l_ref = rest
-        ks_ref = vs_ref = None
+                  o_ref, acc_ref, m_ref, l_ref, *, page: int, n_pages: int,
+                  rep: int, scale: float):
     im = pl.program_id(0)
     ip = pl.program_id(1)
     Hk, hd = k_ref.shape[2], k_ref.shape[3]
@@ -222,9 +204,6 @@ def _paged_kernel(bt_ref, len_ref, q_ref, kn_ref, vn_ref, k_ref, v_ref,
 
     k = k_ref[0].astype(jnp.float32)               # (page, Hk, hd)
     v = v_ref[0].astype(jnp.float32)
-    if quantized:
-        k = k * ks_ref[0][:, :, None]               # (1, Hk, 1) scales
-        v = v * vs_ref[0][:, :, None]
     kT = jnp.transpose(k, (1, 0, 2))               # (Hk, page, hd)
     vT = jnp.transpose(v, (1, 0, 2))
     s = jax.lax.dot_general(q3, kT,
@@ -252,22 +231,17 @@ def _paged_kernel(bt_ref, len_ref, q_ref, kn_ref, vn_ref, k_ref, v_ref,
 def paged_attention_pallas(q: jax.Array, k_pool: jax.Array,
                            v_pool: jax.Array, block_tables: jax.Array,
                            lengths: jax.Array, k_new: jax.Array,
-                           v_new: jax.Array,
-                           k_scales: Optional[jax.Array] = None,
-                           v_scales: Optional[jax.Array] = None, *,
-                           interpret: bool) -> jax.Array:
+                           v_new: jax.Array, *, interpret: bool) -> jax.Array:
     """Same contract as :func:`paged_attention_jnp`, as a pallas_call."""
     M, H, hd = q.shape
-    P, page, Hk, _ = k_pool.shape
+    _, page, Hk, _ = k_pool.shape
     NP = block_tables.shape[1]
     rep = H // Hk
     assert rep * Hk == H, (H, Hk)
-    quantized = k_scales is not None
     scale = 1.0 / math.sqrt(hd)
 
     kernel = functools.partial(
-        _paged_kernel, page=page, n_pages=NP, rep=rep, scale=scale,
-        quantized=quantized)
+        _paged_kernel, page=page, n_pages=NP, rep=rep, scale=scale)
     in_specs = [
         pl.BlockSpec((1, H, hd), lambda m, p, bt, ln: (m, 0, 0)),       # q
         pl.BlockSpec((1, Hk, hd), lambda m, p, bt, ln: (m, 0, 0)),      # k_new
@@ -277,16 +251,6 @@ def paged_attention_pallas(q: jax.Array, k_pool: jax.Array,
         pl.BlockSpec((1, page, Hk, hd),
                      lambda m, p, bt, ln: (bt[m, p], 0, 0, 0)),         # v page
     ]
-    args = [q, k_new, v_new, k_pool, v_pool]
-    if quantized:
-        # scales viewed as (P, 1, Hk): a (1, 1, Hk) block spans the full
-        # last two dims, which the TPU tiling rule accepts; a (1, Hk)
-        # block over (P, Hk) would be a sub-8-row slice and is refused
-        in_specs += [
-            pl.BlockSpec((1, 1, Hk), lambda m, p, bt, ln: (bt[m, p], 0, 0)),
-            pl.BlockSpec((1, 1, Hk), lambda m, p, bt, ln: (bt[m, p], 0, 0)),
-        ]
-        args += [k_scales.reshape(P, 1, Hk), v_scales.reshape(P, 1, Hk)]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
@@ -306,4 +270,5 @@ def paged_attention_pallas(q: jax.Array, k_pool: jax.Array,
         interpret=interpret,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
-    )(block_tables.astype(jnp.int32), lengths.astype(jnp.int32), *args)
+    )(block_tables.astype(jnp.int32), lengths.astype(jnp.int32),
+      q, k_new, v_new, k_pool, v_pool)

@@ -35,22 +35,19 @@ Two decode paths share the slot table:
   copies the pool between host and device.  The unfused path re-reads
   the shard weights once per session per token; the fused path reads
   them once per *batch* — in a roofline cost model that is where
-  batched decode actually wins.  ``kv_dtype="int8"`` stores pool pages
-  quantized (per-page per-kv-head scales, dequantized inside the
-  attention kernel) for ~4x fewer cache-resident bytes; the partial
-  (current) page keeps an fp32 staging master per slot on the device,
-  so requantization never compounds error.
+  batched decode actually wins.  Pool pages are float32: one KV format
+  per attention kind.
 
 * **Per-slot fallback** (ssm/hybrid/mrope/windowed): the original
   batch=1 ``module.apply`` loop with whole-page dense cache growth,
-  numerics bit-identical to the v1 path.
+  numerics bit-identical to the model's own dense decode.
 
 Page accounting is exact in both paths: the pool's free list makes
 alloc/free symmetric by construction, the fallback keeps a running
 counter (no O(slots) rescans on grow), and ``stats["pages"]`` always
 equals pages currently in use (0 when every session is closed).
 
-The fp32 fused path is argmax-equivalent to the v1 path (same
+The fused path is argmax-equivalent to the dense decode path (same
 projection/rope/mask/softmax formulation on the same cached values), so
 greedy decode through the batched plane still matches
 :class:`repro.serving.engine.GenerationEngine`.
@@ -91,19 +88,6 @@ _FUSED_ARCHS = ("dense", "moe", "vlm", "audio")
 _ENGINE_SEQ = itertools.count()
 
 
-def _quant_page_int8(x: jax.Array) -> Tuple[jax.Array, jax.Array]:
-    """Symmetric int8 quantization of pages ``(..., page, Hk, hd)`` with
-    one scale per (leading index, kv-head): |x - x̂| <= absmax/254
-    elementwise; ties round half to even."""
-    amax = jnp.abs(x).max(axis=(-3, -1))
-    # a true division: XLA would turn one by a literal into a product with
-    # its rounded reciprocal, 1 ulp off the scale in some elements
-    q_max = jax.lax.optimization_barrier(jnp.float32(127.0))
-    scale = jnp.where(amax > 0, amax / q_max, 1.0).astype(jnp.float32)
-    q = jnp.rint(x / scale[..., None, :, None]).astype(jnp.int8)
-    return q, scale
-
-
 def _latent_lanes(rank: int, rope: int, page: int) -> int:
     """Tokens per rope slab of a latent pool laid out in whole lane tiles
     (``rank`` latent numbers as ``(rank // 128, 128)``, ``g = 256 // rope``
@@ -128,34 +112,32 @@ class KVPool:
 
     Per layer ``k/v`` pools of shape ``(L, P, page, Hk, hd)``, held on
     ``device`` and grown geometrically, plus a free-page list — alloc and
-    free are exact and symmetric.  ``quant`` stores int8 pages with
-    per-(page, kv-head) dequant scales ``(L, P, Hk)``.  ``latent`` pools
-    (MLA) hold one row of ``head_dim`` numbers per token and layer, the
-    latent ``c`` then ``rope_dim`` numbers of rope key, and no values.
-    Where the widths allow (``_latent_lanes``) the row is split into
-    whole lane tiles, which TPU scatters write in place: ``kp`` holds
-    ``c`` as ``(L, P, page, r // 128, 128)`` and ``vp`` the rope keys as
-    ``(L, P, page // g, 2, 128)``, g tokens a slab.  Otherwise ``kp`` is
+    free are exact and symmetric.  ``latent`` pools (MLA) hold one row
+    of ``head_dim`` numbers per token and layer, the latent ``c`` then
+    ``rope_dim`` numbers of rope key, and no values.  Where the widths
+    allow (``_latent_lanes``) the row is split into whole lane tiles,
+    which TPU scatters write in place: ``kp`` holds ``c`` as ``(L, P,
+    page, r // 128, 128)`` and ``vp`` the rope keys as ``(L, P, page //
+    g, 2, 128)``, g tokens a slab.  Otherwise ``kp`` is
     ``(L, P, page, 1, head_dim)`` and ``vp`` is None.  Writes replace the
     arrays with the outputs of donated in-place scatters
     (``_write_prefill``, ``_append_rows``); nothing copies the pool.
     """
 
     def __init__(self, n_layers: int, n_kv_heads: int, head_dim: int,
-                 page_size: int, quant: bool = False, device: Any = None,
-                 latent: bool = False, rope_dim: int = 0):
-        if latent and (quant or n_kv_heads != 1):
-            raise ValueError("latent pools are fp32 rows of one 'head'")
+                 page_size: int, device: Any = None, latent: bool = False,
+                 rope_dim: int = 0):
+        if latent and n_kv_heads != 1:
+            raise ValueError("latent pools are rows of one 'head'")
         self.L = n_layers
         self.Hk = n_kv_heads
         self.hd = head_dim
         self.page = page_size
-        self.quant = quant
         self.latent = latent
         self.device = device
         self.n_pages = 0
         self._free: List[int] = []
-        dt = jnp.int8 if quant else jnp.float32
+        dt = jnp.float32                # one KV format: float32 pages
         shape = (self.L, 0, page_size, self.Hk, self.hd)
         rank = head_dim - rope_dim
         g = _latent_lanes(rank, rope_dim, page_size) if latent else 0
@@ -167,18 +149,14 @@ class KVPool:
         else:
             self.kp = jnp.zeros(shape, dt, device=device)
             self.vp = None if latent else jnp.zeros(shape, dt, device=device)
-        self.ks = (jnp.ones((self.L, 0, self.Hk), jnp.float32, device=device)
-                   if quant else None)
-        self.vs = (jnp.ones((self.L, 0, self.Hk), jnp.float32, device=device)
-                   if quant else None)
 
     @property
     def arrays(self) -> Tuple[Any, ...]:
-        return self.kp, self.vp, self.ks, self.vs
+        return self.kp, self.vp
 
     @arrays.setter
     def arrays(self, new: Tuple[Any, ...]) -> None:
-        self.kp, self.vp, self.ks, self.vs = new
+        self.kp, self.vp = new
 
     @property
     def _arrays_per_row(self) -> int:
@@ -186,18 +164,13 @@ class KVPool:
 
     @property
     def page_bytes(self) -> int:
-        """Cache-resident bytes of one allocated page (k+v, + scales; a
-        latent pool's rows alone)."""
-        per = self.L * self.page * self.Hk * self.hd * self.kp.dtype.itemsize
-        scales = 2 * self.L * self.Hk * 4 if self.quant else 0
-        return self._arrays_per_row * per + scales
+        """Cache-resident bytes of one allocated page (k+v; a latent
+        pool's rows alone)."""
+        return self.page * self.append_bytes
 
     @property
     def append_bytes(self) -> int:
-        """Pool bytes one appended token writes: its k/v (or latent) row,
-        or for int8 pages the whole requantized page and its scales."""
-        if self.quant:
-            return self.page_bytes
+        """Pool bytes one appended token writes: its k/v (or latent) row."""
         return (self._arrays_per_row * self.L * self.Hk * self.hd
                 * self.kp.dtype.itemsize)
 
@@ -211,9 +184,9 @@ class KVPool:
         total = max(min_total, self.n_pages * 2, 8)
         add = total - self.n_pages
 
-        def ext(a: jax.Array, fill: float = 0.0) -> jax.Array:
-            blk = jnp.full((self.L, add) + a.shape[2:], fill, a.dtype,
-                           device=self.device)
+        def ext(a: jax.Array) -> jax.Array:
+            blk = jnp.zeros((self.L, add) + a.shape[2:], a.dtype,
+                            device=self.device)
             # an empty pool takes the block as it is: a concatenation would
             # hold the new pages twice on the device for a moment
             return jnp.concatenate([a, blk], axis=1) if a.shape[1] else blk
@@ -221,9 +194,6 @@ class KVPool:
         self.kp = ext(self.kp)
         if self.vp is not None:
             self.vp = ext(self.vp)
-        if self.quant:
-            self.ks = ext(self.ks, 1.0)
-            self.vs = ext(self.vs, 1.0)
         self._free.extend(range(self.n_pages, total))
         self.n_pages = total
 
@@ -250,7 +220,7 @@ def _write_latent_lanes(kp: jax.Array, vp: jax.Array, pages: jax.Array,
     pg, off = jnp.repeat(pages, slabs), jnp.tile(jnp.arange(slabs), n)
     vp = vp.at[:, pg, off].set(
         rows[..., r:].reshape((L, n * slabs) + vp.shape[-2:]))
-    return kp, vp, None, None
+    return kp, vp
 
 
 def _append_latent_lanes(kp: jax.Array, vp: jax.Array, pages: jax.Array,
@@ -271,80 +241,47 @@ def _append_latent_lanes(kp: jax.Array, vp: jax.Array, pages: jax.Array,
     mine = jnp.arange(g * (W - r)) // (W - r) == (offs % g)[:, None]
     new = jnp.where(mine, jnp.tile(rows[..., r:], g), cur.reshape(L, M, -1))
     vp = vp.at[:, pages, slab].set(new.reshape(cur.shape), mode="drop")
-    return kp, vp, None, None
+    return kp, vp
 
 
-@functools.partial(jax.jit, donate_argnums=(0, 1))
-def _write_prefill(pool: Tuple[Any, ...], tails: Any, slot: jax.Array,
-                   pages: jax.Array, n_full: jax.Array, k: jax.Array,
-                   v: jax.Array) -> Tuple[Tuple[Any, ...], Any]:
+@functools.partial(jax.jit, donate_argnums=0)
+def _write_prefill(pool: Tuple[Any, ...], pages: jax.Array, k: jax.Array,
+                   v: Optional[jax.Array]) -> Tuple[Any, ...]:
     """Write a prefilled slot's dense cache ``k/v (L, 1, cap, Hk, hd)``
     into its ``cap // page`` pool ``pages`` in place (a latent pool: ``k``
     holds the rows ``(L, 1, cap, 1, W)``, ``v`` is None).  Past the
-    prompt the dense cache holds zeros, which quantize to 0 under any
-    scale.  int8 pools also keep page ``n_full`` (the partial one) in fp32
-    as the slot's staging master (``tails``, row ``slot``), so appends
-    requantize from it and error never compounds."""
-    kp, vp, ks, vs = pool
+    prompt the dense cache holds zeros."""
+    kp, vp = pool
     L, _, cap, Hk, hd = k.shape
     n = pages.shape[0]
     if v is None and vp is not None:
         return _write_latent_lanes(kp, vp, pages,
-                                   k[:, 0, :, 0].astype(jnp.float32)), tails
+                                   k[:, 0, :, 0].astype(jnp.float32))
     kpg = k[:, 0].reshape(L, n, cap // n, Hk, hd).astype(jnp.float32)
     if vp is None:
-        return (kp.at[:, pages].set(kpg), None, None, None), tails
+        return kp.at[:, pages].set(kpg), None
     vpg = v[:, 0].reshape(L, n, cap // n, Hk, hd).astype(jnp.float32)
-    if ks is None:
-        return (kp.at[:, pages].set(kpg), vp.at[:, pages].set(vpg),
-                None, None), tails
-    qk, sk = _quant_page_int8(kpg)
-    qv, sv = _quant_page_int8(vpg)
-    kt, vt = tails
-    kt = kt.at[slot].set(jax.lax.dynamic_index_in_dim(kpg, n_full, 1, False))
-    vt = vt.at[slot].set(jax.lax.dynamic_index_in_dim(vpg, n_full, 1, False))
-    return (kp.at[:, pages].set(qk), vp.at[:, pages].set(qv),
-            ks.at[:, pages].set(sk), vs.at[:, pages].set(sv)), (kt, vt)
+    return kp.at[:, pages].set(kpg), vp.at[:, pages].set(vpg)
 
 
-@functools.partial(jax.jit, donate_argnums=(0, 1))
-def _append_rows(pool: Tuple[Any, ...], tails: Any, slots: jax.Array,
-                 pages: jax.Array, offs: jax.Array, kn: Tuple[jax.Array, ...],
-                 vn: Tuple[jax.Array, ...]) -> Tuple[Tuple[Any, ...], Any]:
+@functools.partial(jax.jit, donate_argnums=0)
+def _append_rows(pool: Tuple[Any, ...], pages: jax.Array, offs: jax.Array,
+                 kn: Tuple[jax.Array, ...],
+                 vn: Tuple[jax.Array, ...]) -> Tuple[Any, ...]:
     """Write row ``r``'s token k/v ``kn[r], vn[r] (L, Hk, hd)`` at
     ``(pages[r], offs[r])`` in place, for a fixed number of rows; padding
-    rows carry an out-of-range page (and slot) and are dropped.  A latent
-    pool takes ``kn`` alone (``vn`` empty), split into its two arrays in
-    the lane layout (``_append_latent_lanes``).  int8 pools write the token
-    into its slot's fp32 staging page (cleared at offset 0) and
-    requantize that whole page."""
-    kp, vp, ks, vs = pool
+    rows carry an out-of-range page and are dropped.  A latent pool takes
+    ``kn`` alone (``vn`` empty), split into its two arrays in the lane
+    layout (``_append_latent_lanes``)."""
+    kp, vp = pool
     k = jnp.stack(kn, axis=1)                       # (L, M, Hk, hd)
     if not vn and vp is not None:
-        return _append_latent_lanes(kp, vp, pages, offs, k[:, :, 0]), tails
+        return _append_latent_lanes(kp, vp, pages, offs, k[:, :, 0])
     if vp is None:
-        return (kp.at[:, pages, offs].set(k, mode="drop"),
-                None, None, None), tails
+        return kp.at[:, pages, offs].set(k, mode="drop"), None
     v = jnp.stack(vn, axis=1)
-    if ks is None:
-        return (kp.at[:, pages, offs].set(k, mode="drop"),
-                vp.at[:, pages, offs].set(v, mode="drop"), None, None), tails
-    rows = jnp.arange(pages.shape[0])
-    fresh = (offs == 0)[:, None, None, None, None]
-
-    def stage(t: jax.Array, x: jax.Array) -> Tuple[jax.Array, jax.Array]:
-        cur = jnp.where(fresh, 0.0, t[slots])       # (M, L, page, Hk, hd)
-        cur = cur.at[rows, :, offs].set(x.swapaxes(0, 1))
-        return t.at[slots].set(cur, mode="drop"), cur.swapaxes(0, 1)
-
-    kt, kcur = stage(tails[0], k)
-    vt, vcur = stage(tails[1], v)
-    qk, sk = _quant_page_int8(kcur)
-    qv, sv = _quant_page_int8(vcur)
-    return (kp.at[:, pages].set(qk, mode="drop"),
-            vp.at[:, pages].set(qv, mode="drop"),
-            ks.at[:, pages].set(sk, mode="drop"),
-            vs.at[:, pages].set(sv, mode="drop")), (kt, vt)
+    return (kp.at[:, pages, offs].set(k, mode="drop"),
+            vp.at[:, pages, offs].set(v, mode="drop"))
 
 
 def _counts_experts(module: Any) -> bool:
@@ -386,8 +323,7 @@ class SlotState:
 
 def _fused_block(cfg: Any, p: Any, x: jax.Array, positions: jax.Array,
                  bt: jax.Array, lengths: jax.Array, kp: jax.Array,
-                 vp: Optional[jax.Array], ks: Optional[jax.Array],
-                 vs: Optional[jax.Array], layer: int,
+                 vp: Optional[jax.Array], layer: int,
                  rows_out: Optional[List[jax.Array]] = None,
                  ) -> Tuple[jax.Array, jax.Array, Optional[jax.Array]]:
     """One attention-family block (global index ``layer``) for a batch of
@@ -423,7 +359,7 @@ def _fused_block(cfg: Any, p: Any, x: jax.Array, positions: jax.Array,
         q = apply_rope(q, positions, cfg.rope_theta)
         k = apply_rope(k, positions, cfg.rope_theta)
         attn = paged_attention_jnp(q[:, 0], kp, vp, bt, lengths,
-                                   k[:, 0], v[:, 0], ks, vs)     # (B, H, hd)
+                                   k[:, 0], v[:, 0])             # (B, H, hd)
         x = x + attn.reshape(B, 1, H * hd) @ ap["wo"]
         k_row, v_row = k[:, 0], v[:, 0]
     h2 = rms_norm(x, p["ln2"], cfg.norm_eps)
@@ -438,10 +374,7 @@ def _fused_block(cfg: Any, p: Any, x: jax.Array, positions: jax.Array,
 
 class BatchEngine:
     def __init__(self, module: Any, sim: Sim, n_slots: int = 8,
-                 page_size: int = 32, kv_dtype: str = "fp32",
-                 fused: Optional[bool] = None):
-        if kv_dtype not in ("fp32", "int8"):
-            raise ValueError(f"unknown kv_dtype {kv_dtype!r}")
+                 page_size: int = 32, fused: Optional[bool] = None):
         self.module = module
         self.sim = sim
         self.n_slots = n_slots
@@ -470,34 +403,20 @@ class BatchEngine:
         self._apply = jax.jit(dense_apply)
         supported = self._supports_fused(module)
         self.fused = supported if fused is None else (fused and supported)
-        self.kv_dtype = kv_dtype if self.fused else "fp32"
         self._pool: Optional[KVPool] = None
-        # int8 pools: each slot's fp32 staging master of its partial page,
-        # (n_slots, L, page, Hk, hd) for k and for v, on the pool's device
-        self._tails: Optional[Tuple[jax.Array, jax.Array]] = None
-        self._tail_bytes = 0          # both staging pages of one slot
         # this step's appends, written by one scatter after the step
-        self._appends: List[Tuple[int, int, int, jax.Array, jax.Array]] = []
+        self._appends: List[Tuple[int, int, jax.Array, jax.Array]] = []
         self._fallback_pages = 0      # exact page counter for the dense path
         if self.fused:
             cfg = module.cfg
             dev = _device_of(module.params)
             if cfg.mla:
                 self._pool = KVPool(module.n_layers, 1, cfg.latent_dim,
-                                    page_size, quant=(self.kv_dtype == "int8"),
-                                    device=dev, latent=True,
+                                    page_size, device=dev, latent=True,
                                     rope_dim=cfg.qk_rope_head_dim)
             else:
                 self._pool = KVPool(module.n_layers, cfg.n_kv_heads, cfg.hd,
-                                    page_size,
-                                    quant=(self.kv_dtype == "int8"),
-                                    device=dev)
-            if self._pool.quant:
-                shape = (n_slots, module.n_layers, page_size,
-                         cfg.n_kv_heads, cfg.hd)
-                self._tails = (jnp.zeros(shape, jnp.float32, device=dev),
-                               jnp.zeros(shape, jnp.float32, device=dev))
-                self._tail_bytes = 2 * int(np.prod(shape[1:])) * 4
+                                    page_size, device=dev)
             self._fused_apply = jax.jit(self._build_fused_apply())
         self.stats = {
             "admitted": 0, "evicted": 0, "steps": 0,
@@ -523,7 +442,7 @@ class BatchEngine:
         cfg = self.module.cfg
         count = self._counts
 
-        def fused(params, x, positions, bt, lengths, kp, vp, ks, vs):
+        def fused(params, x, positions, bt, lengths, kp, vp):
             m = _with_params(self.module, params)
             if m.is_first and x.dtype == jnp.int32:
                 h = m.embed(x[:, None])                      # (M, 1, D)
@@ -536,9 +455,7 @@ class BatchEngine:
                 lp = m._layer_params(j)
                 h, kn, vn = _fused_block(
                     cfg, lp, h, positions, bt, lengths, kp[j],
-                    None if vp is None else vp[j],
-                    None if ks is None else ks[j],
-                    None if vs is None else vs[j], m.lo + j, expert_rows)
+                    None if vp is None else vp[j], m.lo + j, expert_rows)
                 new_k.append(kn)
                 new_v.append(vn)
             out = m.head(h)[:, 0] if m.is_last else h[:, 0]
@@ -621,19 +538,17 @@ class BatchEngine:
 
     def _slot_kv_bytes(self, st: SlotState) -> float:
         if self.fused:
-            return float(len(st.pages) * self._pool.page_bytes
-                         + self._tail_bytes)
+            return float(len(st.pages) * self._pool.page_bytes)
         if st.cache is None:
             return 0.0
         return float(sum(leaf.nbytes
                          for leaf in jax.tree.leaves(st.cache["layers"])))
 
     def kv_bytes(self) -> float:
-        """Current cache-resident bytes across all live slots (pool pages
-        + fp32 staging tails, or dense per-slot caches)."""
+        """Current cache-resident bytes across all live slots (pool pages,
+        or dense per-slot caches)."""
         if self.fused:
-            return float(self._pool.bytes_in_use()
-                         + self._tail_bytes * len(self.by_session))
+            return float(self._pool.bytes_in_use())
         return sum(self._slot_kv_bytes(st) for st in self.by_session.values())
 
     def _cost(self, flops: float, bytes_moved: float) -> float:
@@ -736,13 +651,11 @@ class BatchEngine:
                             v: jax.Array) -> int:
         """Write a prefilled slot's dense cache ``k/v (L, 1, cap, Hk, hd)``
         (latent pools: the rows ``(L, 1, cap, 1, latent_dim)`` and no v),
-        as ``_apply`` left it on the device, into its pool pages in place
-        (int8: and its fp32 staging page).  Returns the pool bytes
-        written."""
+        as ``_apply`` left it on the device, into its pool pages in place.
+        Returns the pool bytes written."""
         pool = self._pool
-        pool.arrays, self._tails = _write_prefill(
-            pool.arrays, self._tails, st.slot,
-            np.asarray(st.pages, np.int32), st.length // self.page_size, k, v)
+        pool.arrays = _write_prefill(pool.arrays,
+                                     np.asarray(st.pages, np.int32), k, v)
         written = len(st.pages) * pool.page_bytes
         self.stats["kv_bytes_written"] += written
         return written
@@ -753,7 +666,7 @@ class BatchEngine:
         ``st.length`` (the page was allocated before the fused call).
         The write is queued; ``_flush_appends`` makes the step's writes."""
         pos = st.length
-        self._appends.append((st.slot, st.pages[pos // self.page_size],
+        self._appends.append((st.pages[pos // self.page_size],
                               pos % self.page_size, kn, vn))
         st.length = pos + 1
 
@@ -765,17 +678,15 @@ class BatchEngine:
         if not queued:
             return 0
         pool, M = self._pool, self.n_slots
-        slots = np.full((M,), M, np.int32)
         pages = np.full((M,), pool.n_pages, np.int32)
         offs = np.zeros((M,), np.int32)
-        for r, (slot, pid, off, _, _) in enumerate(queued):
-            slots[r], pages[r], offs[r] = slot, pid, off
+        for r, (pid, off, _, _) in enumerate(queued):
+            pages[r], offs[r] = pid, off
         pad = M - len(queued)
-        kn = tuple(q[3] for q in queued) + (queued[0][3],) * pad
+        kn = tuple(q[2] for q in queued) + (queued[0][2],) * pad
         vn = (() if pool.latent
-              else tuple(q[4] for q in queued) + (queued[0][4],) * pad)
-        pool.arrays, self._tails = _append_rows(
-            pool.arrays, self._tails, slots, pages, offs, kn, vn)
+              else tuple(q[3] for q in queued) + (queued[0][3],) * pad)
+        pool.arrays = _append_rows(pool.arrays, pages, offs, kn, vn)
         written = len(queued) * pool.append_bytes
         self.stats["kv_bytes_written"] += written
         return written

@@ -12,8 +12,8 @@ Also provides :func:`hedged_call` — a tail-latency hedge for *idempotent*
 calls: the primary attempt races a hedge timer, and when the timer fires
 first a backup attempt is launched on the next-best provider; the first
 success wins.  Stateful decode steps must not be hedged (a duplicate
-attempt would advance a second KV cache), so the serving driver only
-hedges stateless ops and handles decode failures by session migration.
+attempt would advance a second KV cache); the serving client hedges
+none of its calls and handles failures by session migration.
 """
 
 from __future__ import annotations

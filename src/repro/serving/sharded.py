@@ -7,21 +7,18 @@ stub resolves providers per hop, streams activations through the pipeline,
 and **transparently fails over** to replica shards via a fresh DHT lookup
 when a provider dies — the availability story of the paper's §2 RPC layer.
 
-Two RPC surfaces per shard:
-
-* ``infer.<fleet>.<i>`` — the v1 single-session ops (prefill/decode/score),
-  kept for back-compat.
-* ``infer.v2.*.<fleet>.<i>`` — the continuous-batching plane: ``open``
-  admits a session into the shard's :class:`~repro.serving.batch.BatchEngine`
-  slot table (FIFO-queueing when full), ``step`` advances *many* sessions in
-  one wire message, ``close`` evicts.  One RPC per shard hop per decode
-  iteration is shared by every active session, which is where batching beats
-  the sequential path: per-message CPU and link latency amortize across the
-  batch while per-token FLOPs stay identical.
+Each shard serves one RPC surface, ``infer.v2.*.<fleet>.<i>``, the
+continuous-batching plane: ``open`` admits a session into the shard's
+:class:`~repro.serving.batch.BatchEngine` slot table (FIFO-queueing when
+full), ``step`` advances *many* sessions in one wire message, ``close``
+evicts.  One RPC per shard hop per decode iteration is shared by every
+active session, which is where batching beats one request at a time:
+per-message CPU and link latency amortize across the batch while
+per-token FLOPs stay identical.
 
 :class:`ShardClient` routes via a load-aware :class:`LoadAwareRouter`
 (EWMA latency / error rate / in-flight depth per provider) instead of
-first-successful-dial, hedges idempotent calls, and **migrates** sessions
+first-successful-dial, and **migrates** sessions
 mid-generation: when a provider dies between decode steps the driver
 replays prompt ⊕ generated-so-far through a freshly routed chain, so a
 crash loses no session (``sessions_migrated`` in the dashboard).
@@ -53,8 +50,8 @@ from repro.models import decoder
 from repro.models.common import rms_norm
 from repro.models.config import ModelConfig
 
-from .batch import PEER_FLOPS, BatchEngine
-from .router import LoadAwareRouter, hedged_call
+from .batch import BatchEngine
+from .router import LoadAwareRouter
 
 _session_seq = itertools.count(1)
 _request_seq = itertools.count(1)
@@ -188,43 +185,6 @@ class ShardModule:
                    for leaf in jax.tree.leaves(self.params))
 
 
-class InferenceService(Service):
-    """One pipeline shard's v1 RPC surface.  ``scope`` carries the fleet
-    name and shard index, so each shard serves ``infer.<fleet>.<i>``.  The
-    infer method is *not* idempotent (decode advances per-session KV
-    caches); failover is handled explicitly by :class:`ShardClient`."""
-
-    name = "infer"
-
-    def __init__(self, server: "ShardServer"):
-        self.server = server
-        self.scope = f"{server.fleet}.{server.shard_idx}"
-
-    @unary("infer", request=TensorDictCodec(), response=TensorDictCodec(),
-           timeout=120.0)
-    def infer(self, payload: Any, ctx: RpcContext) -> Generator:
-        if not self.server.alive:
-            raise ServiceError(RpcStatus.UNAVAILABLE,
-                               f"shard {self.server.shard_idx} is down")
-        resp = yield from self.server._handle(payload, ctx)
-        return resp
-
-    @unary("score", request=TensorDictCodec(), response=TensorDictCodec(),
-           timeout=120.0, idempotent=True)
-    def score(self, payload: Any, ctx: RpcContext) -> Generator:
-        """Stateless forward pass: touches no session state, so it is the
-        one v1 op that may be hedged/retried (latlint L004 requires the
-        idempotency to be declared on the MethodSpec, not assumed)."""
-        if payload.get("op") != "score":
-            raise ServiceError(RpcStatus.NOT_FOUND,
-                               "score method only serves op == 'score'")
-        if not self.server.alive:
-            raise ServiceError(RpcStatus.UNAVAILABLE,
-                               f"shard {self.server.shard_idx} is down")
-        resp = yield from self.server._handle(payload, ctx)
-        return resp
-
-
 class InferenceV2Service(Service):
     """The continuous-batching surface: per-step admission/eviction against
     the shard's slot table.  ``open``/``step`` are *not* idempotent (they
@@ -295,18 +255,17 @@ class ShardServer:
                  shard_idx: int, module: ShardModule, n_slots: int = 8,
                  page_size: int = 32, idle_ttl: float = 300.0,
                  kv_dtype: str = "fp32"):
+        if kv_dtype != "fp32":
+            raise ValueError(f"pool pages are fp32, not {kv_dtype!r}")
         self.node = node
         self.cfg = cfg
         self.fleet = fleet
         self.shard_idx = shard_idx
         self.module = module
-        self.sessions: Dict[Any, Dict[str, Any]] = {}    # v1 sessions
         self.alive = True
         self.idle_ttl = idle_ttl
-        self.stats = {"prefill": 0, "decode": 0, "score": 0}
         self.engine = BatchEngine(module, node.sim, n_slots=n_slots,
-                                  page_size=page_size, kv_dtype=kv_dtype)
-        node.serve(InferenceService(self))
+                                  page_size=page_size)
         node.serve(InferenceV2Service(self))
         if not hasattr(node, "shard_servers"):
             node.shard_servers = []                      # metrics registry
@@ -339,72 +298,8 @@ class ShardServer:
             self.engine.reap_idle(self.idle_ttl)
         return None
 
-    def _handle(self, payload: Any, ctx: RpcContext) -> Generator:
-        op = payload["op"]
-        m = self.module
-        if op == "prefill":
-            self.stats["prefill"] += 1
-            x = jnp.asarray(payload["x"])
-            if m.is_first and x.dtype == jnp.int32:
-                x = m.embed(x)
-            B, S = x.shape[0], x.shape[1]
-            cache = m.init_cache(B, payload["max_len"])
-            positions = jnp.broadcast_to(
-                jnp.arange(S, dtype=jnp.int32)[None], (B, S))
-            if self.cfg.mrope:
-                positions = jnp.broadcast_to(positions[None], (3, B, S))
-            out, cache = m.apply(x, positions, cache)
-            self.sessions[payload["session"]] = cache
-            if m.is_last:
-                out = m.head(out[:, -1:])[:, 0]
-            else:
-                out = out
-            yield ctx.cpu(m.flops(B * S) / PEER_FLOPS)
-            return {"x": np.asarray(out)}
-        if op == "decode":
-            self.stats["decode"] += 1
-            cache = self.sessions.get(payload["session"])
-            if cache is None:
-                # a replica that never saw this session's prefill: typed
-                # NOT_FOUND so the client migrates instead of treating the
-                # replica as dead
-                raise ServiceError(
-                    RpcStatus.NOT_FOUND,
-                    f"unknown session {payload['session']!r}")
-            x = jnp.asarray(payload["x"])
-            if m.is_first and x.dtype == jnp.int32:
-                x = m.embed(x[:, None])
-            B = x.shape[0]
-            pos = jnp.broadcast_to(
-                cache["len"][None, None], (B, 1)).astype(jnp.int32)
-            if self.cfg.mrope:
-                pos = jnp.broadcast_to(pos[None], (3, B, 1))
-            out, cache = m.apply(x, pos, cache)
-            self.sessions[payload["session"]] = cache
-            if m.is_last:
-                out = m.head(out)[:, 0]
-            yield ctx.cpu(m.flops(B) / PEER_FLOPS)
-            return {"x": np.asarray(out)}
-        if op == "score":
-            self.stats["score"] += 1
-            x = jnp.asarray(payload["x"])
-            if m.is_first and x.dtype == jnp.int32:
-                x = m.embed(x)
-            B, S = x.shape[0], x.shape[1]
-            positions = jnp.broadcast_to(
-                jnp.arange(S, dtype=jnp.int32)[None], (B, S))
-            if self.cfg.mrope:
-                positions = jnp.broadcast_to(positions[None], (3, B, S))
-            out, _ = m.apply(x, positions, None)
-            if m.is_last:
-                out = m.head(out)
-            yield ctx.cpu(m.flops(B * S) / PEER_FLOPS)
-            return {"x": np.asarray(out)}
-        raise ServiceError(RpcStatus.NOT_FOUND, f"unknown op {op}")
-
-
 class _Request:
-    """One in-flight generation request inside the v2 driver."""
+    """One in-flight generation request inside the client's scheduler."""
 
     __slots__ = ("rid", "prompt", "n_tokens", "temperature", "rng",
                  "generated", "session", "chain", "done", "attempts",
@@ -438,25 +333,22 @@ class ShardClient:
     """Shard-aware stub: DHT provider resolution, load-aware routing,
     transparent failover, and a continuous-batching driver.
 
-    The v1 methods (``prefill``/``decode_step``/``score``/``generate``)
-    keep their one-session-at-a-time semantics.  The v2 driver
-    (``submit``/``generate_concurrent``) multiplexes any number of
-    concurrent sessions over one ``infer.v2.step`` RPC per shard hop per
-    decode iteration, sampling client-side, and migrates sessions off dead
-    providers by replaying prompt ⊕ generated-so-far on a fresh chain.
+    Its scheduler (``submit``/``generate_concurrent``) multiplexes any
+    number of concurrent sessions over one ``infer.v2.step`` RPC per shard
+    hop per decode iteration, sampling client-side, and migrates sessions
+    off dead providers by replaying prompt ⊕ generated-so-far on a fresh
+    chain.
     """
 
     def __init__(self, node: LatticaNode, cfg: ModelConfig, fleet: str,
                  n_shards: int, resolve_ttl: float = 5.0,
-                 hedge_after: float = 0.08, max_session_attempts: int = 8,
-                 max_migrations: int = 10,
+                 max_session_attempts: int = 8, max_migrations: int = 10,
                  on_token: Optional[Callable[..., None]] = None):
         self.node = node
         self.cfg = cfg
         self.fleet = fleet
         self.n_shards = n_shards
         self.resolve_ttl = resolve_ttl
-        self.hedge_after = hedge_after
         self.max_session_attempts = max_session_attempts
         self.max_migrations = max_migrations
         #: ``on_token(request, token, logits)``, called for every token
@@ -467,8 +359,7 @@ class ShardClient:
         self._providers: Dict[int, List[PeerInfo]] = {}
         self._resolved_at: Dict[int, float] = {}
         self.stats = {"failovers": 0, "calls": 0, "sessions_migrated": 0,
-                      "hedged": 0, "requests": 0, "completed": 0,
-                      "failed_sessions": 0}
+                      "requests": 0, "completed": 0, "failed_sessions": 0}
         self._pending: Deque[_Request] = deque()
         self._admitting: Set[_Request] = set()
         self._active: List[_Request] = []
@@ -497,134 +388,7 @@ class ShardClient:
         self._providers[idx] = [p for p in provs
                                 if p.peer_id != info.peer_id]
 
-    # -- v1 surface ----------------------------------------------------------
-    def _call_shard(self, idx: int, payload: Dict[str, Any]) -> Generator:
-        provs = yield from self._resolve(idx)
-        if payload.get("op") == "score" and len(provs) > 1:
-            # stateless + idempotent: hedge the tail on the next-best replica
-            resp = yield from self._hedged_score(idx, provs, payload)
-            if resp is not None:
-                return resp
-            provs = yield from self._resolve(idx, refresh=True)
-        last: Optional[Exception] = None
-        for round_ in range(2):
-            ranked = self.router.rank(idx, list(provs),
-                                      lambda p: p.peer_id)
-            for info in ranked:
-                self.stats["calls"] += 1
-                t0 = self.node.sim.now
-                self.router.begin(idx, info.peer_id)
-                try:
-                    stub = self.node.stub(InferenceService, info,
-                                          scope=f"{self.fleet}.{idx}")
-                    resp = yield from stub.infer(payload)
-                    self.router.observe(idx, info.peer_id,
-                                        self.node.sim.now - t0, True)
-                    return resp
-                except (RpcError, DialError) as e:
-                    self.router.observe(idx, info.peer_id,
-                                        self.node.sim.now - t0, False)
-                    if (isinstance(e, ServiceError)
-                            and not e.status.retryable):
-                        raise     # NOT_FOUND etc: a healthy replica answered
-                    last = e
-                    self.stats["failovers"] += 1
-                    self._drop_provider(idx, info)
-                finally:
-                    self.router.end(idx, info.peer_id)
-            provs = yield from self._resolve(idx, refresh=True)
-        raise RpcError(f"all providers for shard {idx} failed: {last}")
-
-    def _hedged_score(self, idx: int, provs: List[PeerInfo],
-                      payload: Dict[str, Any]) -> Generator:
-        ranked = self.router.rank(idx, list(provs), lambda p: p.peer_id)
-
-        def attempt(info: PeerInfo):
-            def run() -> Generator:
-                self.stats["calls"] += 1
-                t0 = self.node.sim.now
-                self.router.begin(idx, info.peer_id)
-                try:
-                    stub = self.node.stub(InferenceService, info,
-                                          scope=f"{self.fleet}.{idx}")
-                    # the dedicated score method declares idempotent=True;
-                    # hedging the stateful `infer` would violate L004
-                    resp = yield from stub.score(payload)
-                    self.router.observe(idx, info.peer_id,
-                                        self.node.sim.now - t0, True)
-                    return resp
-                except (RpcError, DialError):
-                    self.router.observe(idx, info.peer_id,
-                                        self.node.sim.now - t0, False)
-                    self.stats["failovers"] += 1
-                    self._drop_provider(idx, info)
-                    raise
-                finally:
-                    self.router.end(idx, info.peer_id)
-            return run
-
-        try:
-            resp = yield from hedged_call(
-                self.node.sim, [attempt(p) for p in ranked[:3]],
-                self.hedge_after, self.stats)
-            return resp
-        except (RpcError, DialError):
-            return None           # caller falls back to sequential failover
-
-    # -- v1 pipeline ops -----------------------------------------------------
-    def prefill(self, tokens: np.ndarray, max_len: int) -> Generator:
-        session = (self.node.host.name, next(_session_seq))
-        x: Any = tokens
-        for i in range(self.n_shards):
-            payload = {"op": "prefill", "session": session, "x": x,
-                       "max_len": max_len}
-            resp = yield from self._call_shard(i, payload)
-            x = resp["x"]
-        return session, x                        # x = last-position logits
-
-    def decode_step(self, session: Any, token: np.ndarray) -> Generator:
-        x: Any = token
-        for i in range(self.n_shards):
-            payload = {"op": "decode", "session": session, "x": x}
-            resp = yield from self._call_shard(i, payload)
-            x = resp["x"]
-        return x
-
-    def score(self, tokens: np.ndarray) -> Generator:
-        x: Any = tokens
-        for i in range(self.n_shards):
-            payload = {"op": "score", "x": x}
-            resp = yield from self._call_shard(i, payload)
-            x = resp["x"]
-        return x
-
-    def generate(self, tokens: np.ndarray, n_tokens: int) -> Generator:
-        """Greedy v1 generation with mid-generation session migration: when
-        a provider dies between decode steps, the session's KV state is gone
-        with it — replay prompt ⊕ generated on a freshly resolved chain and
-        keep going rather than losing the session."""
-        max_len = tokens.shape[1] + n_tokens + 1
-        session, logits = yield from self.prefill(tokens, max_len)
-        out: List[np.ndarray] = []
-        migrations = 0
-        while len(out) < n_tokens:
-            tok = np.argmax(logits, axis=-1).astype(np.int32)
-            out.append(tok)
-            if len(out) == n_tokens:
-                break
-            try:
-                logits = yield from self.decode_step(session, tok)
-            except (RpcError, DialError):
-                migrations += 1
-                if migrations > self.max_migrations:
-                    raise
-                self.stats["sessions_migrated"] += 1
-                replay = np.concatenate(
-                    [tokens] + [t[:, None] for t in out], axis=1)
-                session, logits = yield from self.prefill(replay, max_len)
-        return np.stack(out, axis=1)
-
-    # -- v2 continuous-batching driver --------------------------------------
+    # -- continuous-batching scheduler --------------------------------------
     def submit(self, tokens: np.ndarray, n_tokens: int,
                temperature: float = 0.0, seed: int = 0) -> Any:
         """Enqueue one generation request; returns an Event that succeeds
@@ -874,7 +638,6 @@ class ShardClient:
 def deploy_sharded(nodes: List[LatticaNode], cfg: ModelConfig,
                    params: Optional[Any], fleet: str, replicas: int = 1,
                    n_slots: int = 8, page_size: int = 32,
-                   kv_dtype: str = "fp32",
                    init_key: Optional[jax.Array] = None,
                    devices: Optional[List[Any]] = None) -> List[ShardServer]:
     """Place ``n_shards = len(nodes) // replicas`` pipeline shards, each
@@ -920,14 +683,13 @@ def deploy_sharded(nodes: List[LatticaNode], cfg: ModelConfig,
                                  (lo, hi), is_first=(i == 0),
                                  is_last=(i == n_shards - 1))
             servers.append(ShardServer(node, cfg, fleet, i, module,
-                                       n_slots=n_slots, page_size=page_size,
-                                       kv_dtype=kv_dtype))
+                                       n_slots=n_slots, page_size=page_size))
     return servers
 
 
 def serve_fleet(nodes: List[LatticaNode], cfg: ModelConfig, params: Any,
                 fleet: str, replicas: int = 1, n_slots: int = 8,
-                page_size: int = 32, kv_dtype: str = "fp32",
+                page_size: int = 32,
                 publisher: Optional[LatticaNode] = None) -> Generator:
     """Full serving bring-up: deploy shards, announce DHT providers,
     publish every shard's param sub-DAG + the serving plan into the CRDT
@@ -936,8 +698,7 @@ def serve_fleet(nodes: List[LatticaNode], cfg: ModelConfig, params: Any,
     from .pressure import load_publisher, publish_serving_plan
 
     servers = deploy_sharded(nodes, cfg, params, fleet, replicas=replicas,
-                             n_slots=n_slots, page_size=page_size,
-                             kv_dtype=kv_dtype)
+                             n_slots=n_slots, page_size=page_size)
     for s in servers:
         yield from s.announce()
     n_shards = len(servers) // replicas

@@ -1,7 +1,7 @@
 """The fused path's KV page pool lives on the shard's device and is
 written in place: prefill pages and each step's appended rows land where
-they belong and nowhere else, int8 pages quantize exactly as the numpy
-formulation does, and the append scatter compiles once per pool size.
+they belong and nowhere else, for the dense pool and both latent
+layouts, and the append scatter compiles once per pool size.
 A latent (MLA) pool whose widths fit whole lane tiles keeps its rows as
 two arrays, latents and rope keys, and holds exactly what the one-array
 pool of rows holds after the same writes."""
@@ -44,12 +44,32 @@ def mla_model():
     return cfg, ops_for(cfg).init(cfg, jax.random.PRNGKey(9))
 
 
-def engine(model, n_slots=3, kv_dtype="fp32"):
+def engine(model, n_slots=3):
     cfg, params = model
     module = ShardModule(cfg, params, (0, cfg.n_layers), is_first=True,
                          is_last=True)
-    return BatchEngine(module, Sim(seed=2), n_slots=n_slots, page_size=PAGE,
-                       kv_dtype=kv_dtype)
+    return BatchEngine(module, Sim(seed=2), n_slots=n_slots, page_size=PAGE)
+
+
+@pytest.fixture(params=["dense", "lane_latent", "row_latent"])
+def pool_model(request, monkeypatch):
+    """The model whose engine keeps each pool layout: the dense k/v pool,
+    the latent pool in lane tiles, and the latent pool of rows."""
+    if request.param == "dense":
+        return request.getfixturevalue("model")
+    if request.param == "row_latent":
+        monkeypatch.setattr(batch, "_latent_lanes", lambda *a: 0)
+    return request.getfixturevalue("mla_model")
+
+
+def token_rows(pool):
+    """Everything ``pool`` holds per token, ``(L, P, page, n)``: k then v,
+    or a latent row (in the lane layout, its latent then its rope key)."""
+    L, P = pool.kp.shape[:2]
+    parts = [np.asarray(pool.kp).reshape(L, P, pool.page, -1)]
+    if pool.vp is not None:
+        parts.append(np.asarray(pool.vp).reshape(L, P, pool.page, -1))
+    return np.concatenate(parts, axis=-1)
 
 
 def prompt(cfg, length, seed):
@@ -73,19 +93,12 @@ def step(eng, toks):
     return {s: int(np.argmax(o)) for s, o in zip(sids, out)}
 
 
-def quant_ref(x):
-    """The numpy formulation of int8 page quantization: per (layer,
-    kv-head) absmax/127 scales, round half to even."""
-    amax = np.abs(x).max(axis=(-3, -1))
-    scale = np.where(amax > 0, amax / 127.0, 1.0).astype(np.float32)
-    q = np.rint(x / scale[..., None, :, None]).astype(np.int8)
-    return q, scale
-
-
-def test_pool_is_resident_and_counts_the_bytes_it_writes(model):
+@pytest.mark.parametrize("name", ["model", "mla_model"])
+def test_pool_is_resident_and_counts_the_bytes_it_writes(request, name):
     """After prefills and steps the pool arrays are jax.Arrays on the
     params' device, and ``kv_bytes_written`` is the prefill pages plus one
-    token's k/v per appended row."""
+    token's k/v (or latent row) per appended row."""
+    model = request.getfixturevalue(name)
     cfg, params = model
     eng = engine(model)
     pool = eng._pool
@@ -98,67 +111,30 @@ def test_pool_is_resident_and_counts_the_bytes_it_writes(model):
     dev = next(iter(jax.tree.leaves(params)[0].devices()))
     for a in (pool.kp, pool.vp):
         assert isinstance(a, jax.Array) and a.devices() == {dev}
-    token = 2 * cfg.n_layers * cfg.n_kv_heads * cfg.hd * 4
+    token = 4 * cfg.n_layers * (cfg.latent_dim if cfg.mla
+                                else 2 * cfg.n_kv_heads * cfg.hd)
     assert pool.append_bytes == token
+    assert pool.page_bytes == PAGE * token
     assert eng.stats["kv_bytes_written"] == (
         rows * token + prefill_pages * pool.page_bytes)
 
 
-def test_an_append_changes_only_its_own_page_offset(model):
-    cfg, _ = model
-    eng = engine(model)
+def test_an_append_changes_only_its_own_page_offset(pool_model):
+    cfg, _ = pool_model
+    eng = engine(pool_model)
     toks = open_sessions(eng, cfg, [6, 8])
-    before_k = np.asarray(eng._pool.kp)
-    before_v = np.asarray(eng._pool.vp)
+    before = token_rows(eng._pool)
     at = {s: (st.pages[st.length // PAGE], st.length % PAGE)
           for s, st in eng.by_session.items()}
     step(eng, toks)
-    after_k = np.asarray(eng._pool.kp)
-    after_v = np.asarray(eng._pool.vp)
-    changed = np.zeros(after_k.shape[1:3], bool)          # (P, page)
+    after = token_rows(eng._pool)
+    changed = np.zeros(after.shape[1:3], bool)            # (P, page)
     for pid, off in at.values():
         changed[pid, off] = True
         # the new token's row was written (not zero, not the old value)
-        assert not np.array_equal(after_k[:, pid, off], before_k[:, pid, off])
+        assert not np.array_equal(after[:, pid, off], before[:, pid, off])
     # every other (page, offset) is bitwise unchanged
-    np.testing.assert_array_equal(after_k[:, ~changed], before_k[:, ~changed])
-    np.testing.assert_array_equal(after_v[:, ~changed], before_v[:, ~changed])
-
-
-def test_int8_quantizer_matches_numpy_bitwise():
-    rng = np.random.default_rng(0)
-    x = rng.standard_normal((3, 4, PAGE, 2, 16)).astype(np.float32) * 3.0
-    x[1, 2, :, 0] = 0.0                                # all-zero kv heads
-    x[:, 3, :, 1] = 0.0
-    # exact ties: amax 127 gives scale 1.0, so k + 0.5 rounds to even
-    x[2, 0, :, 1] = (np.arange(PAGE * 16).reshape(PAGE, 16) % 9 - 4) + 0.5
-    x[2, 0, 0, 1, 0] = 127.0
-    q, s = jax.jit(batch._quant_page_int8)(jnp.asarray(x))
-    q_ref, s_ref = quant_ref(x)
-    assert q.dtype == jnp.int8 and s.dtype == jnp.float32
-    np.testing.assert_array_equal(np.asarray(q), q_ref)
-    np.testing.assert_array_equal(np.asarray(s), s_ref)
-    assert np.all(np.asarray(s)[1, 2, 0] == 1.0)
-
-
-def test_int8_prefill_pages_are_the_fp32_pages_quantized(model):
-    """The same prompt prefills the same dense k/v in both pools; the int8
-    pool holds exactly its quantization, and the staging master holds the
-    partial page in fp32."""
-    cfg, _ = model
-    fp, q8 = engine(model), engine(model, kv_dtype="int8")
-    open_sessions(fp, cfg, [13])
-    open_sessions(q8, cfg, [13])
-    st_f, st_q = fp.by_session[0], q8.by_session[0]
-    k = np.asarray(fp._pool.kp)[:, st_f.pages]         # (L, n, page, Hk, hd)
-    v = np.asarray(fp._pool.vp)[:, st_f.pages]
-    for pool_q, pool_s, x in ((q8._pool.kp, q8._pool.ks, k),
-                              (q8._pool.vp, q8._pool.vs, v)):
-        q_ref, s_ref = quant_ref(x)
-        np.testing.assert_array_equal(np.asarray(pool_q)[:, st_q.pages], q_ref)
-        np.testing.assert_array_equal(np.asarray(pool_s)[:, st_q.pages], s_ref)
-    tail_k = np.asarray(q8._tails[0])[st_q.slot]
-    np.testing.assert_array_equal(tail_k, k[:, 13 // PAGE])
+    np.testing.assert_array_equal(after[:, ~changed], before[:, ~changed])
 
 
 def assert_appends_compile_once(eng, cfg):
@@ -181,10 +157,9 @@ def assert_appends_compile_once(eng, cfg):
 
 def test_append_scatter_compiles_once_for_any_number_of_rows(model):
     """One scatter of ``n_slots`` rows serves every step: 1 to ``n_slots``
-    live rows, fp32 and int8, never add a program at a fixed pool size."""
+    live rows never add a program at a fixed pool size."""
     cfg, _ = model
-    for kv_dtype in ("fp32", "int8"):
-        assert_appends_compile_once(engine(model, kv_dtype=kv_dtype), cfg)
+    assert_appends_compile_once(engine(model), cfg)
 
 
 def test_lane_latent_append_compiles_once_for_any_number_of_rows(mla_model):
@@ -203,31 +178,18 @@ def latent_pools(n_layers=2):
     return lanes, rows
 
 
-def pool_rows(pool, layer):
-    """Layer ``layer``'s rows ``(P, page, W)`` of either layout."""
-    kp = np.asarray(pool.kp[layer])
-    if pool.vp is None:
-        return kp[:, :, 0]
-    P = kp.shape[0]
-    pe = np.asarray(pool.vp[layer]).reshape(P, LPAGE, -1)
-    return np.concatenate([kp.reshape(P, LPAGE, -1), pe], axis=-1)
-
-
 def test_pool_arrays_keep_their_shapes_and_byte_counts():
-    """Dense, int8 and latent pools whose widths do not fit lane tiles
+    """Dense pools and latent pools whose widths do not fit lane tiles
     keep their arrays; a lane-layout pool counts the bytes the pool of
     rows counts, since it holds the same numbers without padding."""
     L, P = 3, 8
     dense = KVPool(L, 2, 16, PAGE)
-    q8 = KVPool(L, 2, 16, PAGE, quant=True)
     tiny = KVPool(L, 1, 40, 4, latent=True, rope_dim=8)    # latent 32
     lanes, rows = latent_pools(L)
-    for p in (dense, q8, tiny, lanes, rows):
+    for p in (dense, tiny, lanes, rows):
         p.free(p.alloc(P))
     assert dense.kp.shape == dense.vp.shape == (L, P, PAGE, 2, 16)
-    assert dense.kp.dtype == jnp.float32 and dense.ks is None
-    assert q8.kp.shape == q8.vp.shape == (L, P, PAGE, 2, 16)
-    assert q8.kp.dtype == jnp.int8 and q8.ks.shape == q8.vs.shape == (L, P, 2)
+    assert dense.kp.dtype == jnp.float32 and len(dense.arrays) == 2
     assert tiny.kp.shape == (L, P, 4, 1, 40) and tiny.vp is None
     assert rows.kp.shape == (L, P, LPAGE, 1, W) and rows.vp is None
     assert lanes.kp.shape == (L, P, LPAGE, RANK // 128, 128)
@@ -250,13 +212,12 @@ def test_lane_latent_pool_holds_the_rows_the_row_pool_holds():
     L, M = 2, 3
     pools = latent_pools(L)
 
-    def prefill(slot, pages, length):
+    def prefill(pages, length):
         k = np.zeros((L, 1, len(pages) * LPAGE, 1, W), np.float32)
         k[:, :, :length] = rng.standard_normal((L, 1, length, 1, W))
         for p in pools:
-            p.arrays, _ = batch._write_prefill(
-                p.arrays, None, slot, np.asarray(pages, np.int32),
-                length // LPAGE, jnp.asarray(k), None)
+            p.arrays = batch._write_prefill(
+                p.arrays, np.asarray(pages, np.int32), jnp.asarray(k), None)
 
     def append(at):
         """One step's rows at ``at = [(page, offset), ...]``, padded to
@@ -268,21 +229,17 @@ def test_lane_latent_pool_holds_the_rows_the_row_pool_holds():
         kn = tuple(jnp.asarray(rng.standard_normal((L, 1, W)), jnp.float32)
                    for _ in range(M))
         for p in pools:
-            p.arrays, _ = batch._append_rows(
-                p.arrays, None, np.arange(M, dtype=np.int32), pages, offs,
-                kn, ())
+            p.arrays = batch._append_rows(p.arrays, pages, offs, kn, ())
 
     def same():
-        for layer in range(L):
-            assert np.array_equal(pool_rows(pools[0], layer),
-                                  pool_rows(pools[1], layer)), layer
+        assert np.array_equal(token_rows(pools[0]), token_rows(pools[1]))
 
     a = [p.alloc(3) for p in pools]
     b = [p.alloc(1) for p in pools]
     assert a[0] == a[1] and b[0] == b[1]
     a, b = a[0], b[0]
-    prefill(0, a, 2 * LPAGE + 2)
-    prefill(1, b, 29)
+    prefill(a, 2 * LPAGE + 2)
+    prefill(b, 29)
     same()
     for t in range(5):                 # A at 66-70 crosses a slab at 68
         append([(a[2], 2 + t)] + ([(b[0], 29 + t)] if t < 3 else []))
@@ -292,7 +249,7 @@ def test_lane_latent_pool_holds_the_rows_the_row_pool_holds():
     c = [p.alloc(3) for p in pools]
     assert c[0] == c[1] and sorted(c[0]) == sorted(a)
     c = c[0]
-    prefill(2, c, 3 * LPAGE - 1)
+    prefill(c, 3 * LPAGE - 1)
     same()
     append([(c[2], LPAGE - 1)])        # the last row of C's last page
     append([])                         # padding rows alone

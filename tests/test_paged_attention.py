@@ -1,7 +1,5 @@
 """Paged single-query decode attention: jnp path vs the dense oracle,
-Pallas interpret vs jnp, and the int8-pool error bound."""
-
-import math
+and Pallas interpret vs jnp across head layouts."""
 
 import jax
 import jax.numpy as jnp
@@ -27,16 +25,16 @@ def _pallas_interpret(*args, **kw):
     return paged_attention_pallas(*args, interpret=True, **kw)
 
 
-def _problem(seed=0, lengths=LENGTHS, pool_pages=None):
+def _problem(seed=0, lengths=LENGTHS, pool_pages=None, hk=HK, rep=REP):
     rng = np.random.default_rng(seed)
     M = len(lengths)
     P = pool_pages or (NP * M + 3)
-    kp = rng.normal(size=(P, PAGE, HK, HD)).astype(np.float32)
-    vp = rng.normal(size=(P, PAGE, HK, HD)).astype(np.float32)
+    kp = rng.normal(size=(P, PAGE, hk, HD)).astype(np.float32)
+    vp = rng.normal(size=(P, PAGE, hk, HD)).astype(np.float32)
     bt = rng.permutation(P)[: NP * M].reshape(M, NP).astype(np.int32)
-    q = rng.normal(size=(M, HQ, HD)).astype(np.float32)
-    kn = rng.normal(size=(M, HK, HD)).astype(np.float32)
-    vn = rng.normal(size=(M, HK, HD)).astype(np.float32)
+    q = rng.normal(size=(M, hk * rep, HD)).astype(np.float32)
+    kn = rng.normal(size=(M, hk, HD)).astype(np.float32)
+    vn = rng.normal(size=(M, hk, HD)).astype(np.float32)
     return (q, jnp.asarray(kp), jnp.asarray(vp), jnp.asarray(bt),
             jnp.asarray(np.asarray(lengths, np.int32)), jnp.asarray(kn),
             jnp.asarray(vn))
@@ -77,6 +75,18 @@ def test_pallas_interpret_matches_jnp():
     np.testing.assert_allclose(pa, jn, rtol=2e-5, atol=2e-6)
 
 
+@pytest.mark.parametrize("hk,rep", [(1, 4), (4, 1), (2, 4)],
+                         ids=["one_kv_head", "mha", "gqa4"])
+def test_pallas_interpret_matches_jnp_across_head_layouts(hk, rep):
+    """One KV head shared by every query head, one KV head per query head,
+    and granite's four query heads per KV head."""
+    args = _problem(seed=8, hk=hk, rep=rep)
+    jn = np.asarray(paged_attention_jnp(jnp.asarray(args[0]), *args[1:]))
+    pa = np.asarray(paged_attention_pallas(jnp.asarray(args[0]), *args[1:],
+                                           interpret=True))
+    np.testing.assert_allclose(pa, jn, rtol=2e-5, atol=2e-6)
+
+
 def test_dispatch_wrapper_runs_on_cpu():
     args = _problem(seed=3)
     got = np.asarray(paged_decode_attention(jnp.asarray(args[0]), *args[1:]))
@@ -107,49 +117,6 @@ def test_stale_page_contents_never_leak():
         poisoned = np.asarray(fn(jnp.asarray(q), jnp.asarray(kp),
                                  jnp.asarray(vp), bt, lengths, kn, vn))
         np.testing.assert_allclose(poisoned, base, rtol=2e-5, atol=2e-6)
-
-
-def _quantize_pool(pool):
-    """Per-(page, kv-head) maxabs int8, matching serving/batch.py."""
-    amax = np.abs(pool).max(axis=(1, 3))
-    scales = np.where(amax > 0, amax / 127.0, 1.0).astype(np.float32)
-    q = np.rint(pool / scales[:, None, :, None]).astype(np.int8)
-    return q, scales
-
-
-def test_int8_pool_error_is_bounded():
-    args = _problem(seed=5)
-    q, kp, vp, bt, lengths, kn, vn = args
-    kq, ks = _quantize_pool(np.asarray(kp))
-    vq, vs = _quantize_pool(np.asarray(vp))
-    # element-wise dequant bound: |x_hat - x| <= page_absmax / 254
-    for pool, qz, sc in ((np.asarray(kp), kq, ks), (np.asarray(vp), vq, vs)):
-        err = np.abs(qz.astype(np.float32) * sc[:, None, :, None] - pool)
-        bound = np.abs(pool).max(axis=(1, 3)) / 254.0 + 1e-6
-        assert (err <= bound[:, None, :, None]).all()
-    fp = np.asarray(paged_attention_jnp(jnp.asarray(q), kp, vp, bt,
-                                        lengths, kn, vn))
-    for fn in (paged_attention_jnp, _pallas_interpret):
-        qa = np.asarray(fn(jnp.asarray(q), jnp.asarray(kq), jnp.asarray(vq),
-                           bt, lengths, kn, vn,
-                           k_scales=jnp.asarray(ks), v_scales=jnp.asarray(vs)))
-        # unit-normal values, <=1% relative cache error: outputs stay close
-        assert np.abs(qa - fp).max() < 0.08
-
-
-def test_int8_quantized_pallas_matches_jnp():
-    args = _problem(seed=6)
-    q, kp, vp, bt, lengths, kn, vn = args
-    kq, ks = _quantize_pool(np.asarray(kp))
-    vq, vs = _quantize_pool(np.asarray(vp))
-    common = (jnp.asarray(q), jnp.asarray(kq), jnp.asarray(vq), bt, lengths,
-              kn, vn)
-    jn = np.asarray(paged_attention_jnp(
-        *common, k_scales=jnp.asarray(ks), v_scales=jnp.asarray(vs)))
-    pa = np.asarray(paged_attention_pallas(
-        *common, k_scales=jnp.asarray(ks), v_scales=jnp.asarray(vs),
-        interpret=True))
-    np.testing.assert_allclose(pa, jn, rtol=2e-5, atol=2e-6)
 
 
 def test_single_full_pool_exact_page_multiple():

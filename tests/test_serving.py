@@ -8,18 +8,18 @@ import pytest
 from repro.configs import get_config
 from repro.core.fleet import make_fleet
 from repro.models import ops_for
-from repro.serving.sharded import ShardClient, deploy_sharded
+from repro.serving.sharded import ShardClient, ShardServer, deploy_sharded
 
 
-@pytest.fixture(scope="module")
-def served():
+def _deploy(seed, fleet_name):
+    """A 2 shards × 2 replicas fleet on the first 4 of 9 peers, announced."""
     cfg = get_config("granite-8b").reduced(n_layers=4, d_model=64, vocab=256)
     ops = ops_for(cfg)
     params = ops.init(cfg, jax.random.PRNGKey(0))
-    fleet = make_fleet(9, seed=21, same_region="us")
+    fleet = make_fleet(9, seed=seed, same_region="us")
     sim = fleet.sim
-    # 2 shards × 2 replicas on the first 4 peers
-    servers = deploy_sharded(fleet.peers[:4], cfg, params, "svc", replicas=2)
+    servers = deploy_sharded(fleet.peers[:4], cfg, params, fleet_name,
+                             replicas=2)
 
     def announce():
         for s in servers:
@@ -29,58 +29,100 @@ def served():
     return cfg, ops, params, fleet, servers
 
 
+@pytest.fixture(scope="module")
+def served():
+    return _deploy(21, "svc")
+
+
+def _prompts(cfg, seed, n, length=8):
+    return [np.asarray(jax.random.randint(jax.random.PRNGKey(seed + i),
+                                          (1, length), 0, cfg.vocab),
+                       np.int32) for i in range(n)]
+
+
+def _generate(client, prompts, n_tokens, until=900):
+    """Every prompt submitted at once; their tokens, in order."""
+    sim = client.node.sim
+    return sim.run_process(client.generate_concurrent(
+        [{"tokens": p, "n_tokens": n_tokens} for p in prompts]),
+        until=sim.now + until)
+
+
+def _local_tokens(cfg, params, prompt, n_tokens):
+    from repro.serving.engine import GenerationEngine
+    eng = GenerationEngine(cfg, params, max_len=32)
+    local, _ = eng.generate({"tokens": jnp.asarray(prompt)}, n_tokens)
+    return np.asarray(local)[0]
+
+
 def test_pipeline_score_matches_local(served):
+    """The logits the client samples its first token from (passed to
+    ``on_token``) are the local forward pass's at the prompt's last
+    position."""
     cfg, ops, params, fleet, servers = served
-    sim = fleet.sim
-    client = ShardClient(fleet.peers[-1], cfg, "svc", n_shards=2)
-    toks = np.asarray(jax.random.randint(jax.random.PRNGKey(1), (2, 16),
+    seen = []
+    client = ShardClient(fleet.peers[-1], cfg, "svc", n_shards=2,
+                         on_token=lambda req, tok, logits: seen.append(logits))
+    toks = np.asarray(jax.random.randint(jax.random.PRNGKey(1), (1, 16),
                                          0, cfg.vocab), np.int32)
-
-    def run():
-        out = yield from client.score(toks)
-        return out
-
-    remote = sim.run_process(run(), until=sim.now + 600)
+    _generate(client, [toks], 1)
     local, _ = ops.forward(params, cfg, {"tokens": jnp.asarray(toks)})
-    np.testing.assert_allclose(remote, np.asarray(local), atol=1e-4, rtol=1e-4)
+    assert len(seen) == 1
+    np.testing.assert_allclose(seen[0], np.asarray(local)[0, -1],
+                               atol=1e-4, rtol=1e-4)
 
 
 def test_generation_matches_local_engine(served):
     cfg, ops, params, fleet, servers = served
-    sim = fleet.sim
     client = ShardClient(fleet.peers[-2], cfg, "svc", n_shards=2)
-    toks = np.asarray(jax.random.randint(jax.random.PRNGKey(2), (1, 8),
-                                         0, cfg.vocab), np.int32)
-
-    def run():
-        out = yield from client.generate(toks, 4)
-        return out
-
-    remote = sim.run_process(run(), until=sim.now + 600)
-    from repro.serving.engine import GenerationEngine
-    eng = GenerationEngine(cfg, params, max_len=32)
-    local, _ = eng.generate({"tokens": jnp.asarray(toks)}, 4)
-    np.testing.assert_array_equal(remote, local)
+    (toks,) = _prompts(cfg, 2, 1)
+    (remote,) = _generate(client, [toks], 4)
+    np.testing.assert_array_equal(remote,
+                                  _local_tokens(cfg, params, toks, 4))
 
 
-def test_failover_to_replica_shard(served):
+def test_finished_requests_leave_no_session_or_page(served):
+    """Once requests finish and their closes settle, every shard's engine
+    holds no session and no page."""
     cfg, ops, params, fleet, servers = served
-    sim = fleet.sim
-    # kill the first replica of shard 0
-    dead = [s for s in servers if s.shard_idx == 0][0]
-    dead.stop()
-    client = ShardClient(fleet.peers[-1], cfg, "svc", n_shards=2)
-    toks = np.asarray(jax.random.randint(jax.random.PRNGKey(3), (1, 8),
-                                         0, cfg.vocab), np.int32)
+    client = ShardClient(fleet.peers[-3], cfg, "svc", n_shards=2)
+    out = _generate(client, _prompts(cfg, 40, 5), 6)
+    assert all(o is not None and len(o) == 6 for o in out)
+    fleet.sim.run(until=fleet.sim.now + 30)
+    assert sum(s.engine.stats["admitted"] for s in servers) >= 10
+    for s in servers:
+        assert not s.engine.by_session, s.shard_idx
+        assert s.engine.stats["pages"] == 0, s.shard_idx
 
-    def run():
-        out = yield from client.score(toks)
-        return out
 
-    remote = sim.run_process(run(), until=sim.now + 900)
-    local, _ = ops.forward(params, cfg, {"tokens": jnp.asarray(toks)})
-    np.testing.assert_allclose(remote, np.asarray(local), atol=1e-4, rtol=1e-4)
+def test_shard_server_keeps_one_kv_format(served):
+    cfg, ops, params, fleet, servers = served
+    with pytest.raises(ValueError):
+        ShardServer(fleet.peers[4], cfg, "svc-int8", 0, servers[0].module,
+                    kv_dtype="int8")
+
+
+@pytest.fixture()
+def served_one_dead():
+    """Its own fleet, with the first replica of shard 0 stopped."""
+    deployed = _deploy(23, "svc-dead")
+    servers = deployed[-1]
+    [s for s in servers if s.shard_idx == 0][0].stop()
+    return deployed
+
+
+def test_failover_to_replica_shard(served_one_dead):
+    """Admissions routed to the stopped replica fail over to the live
+    one; the tokens are still the local engine's."""
+    cfg, ops, params, fleet, servers = served_one_dead
+    client = ShardClient(fleet.peers[-1], cfg, "svc-dead", n_shards=2)
+    prompts = _prompts(cfg, 3, 3)
+    remote = _generate(client, prompts, 4)
+    for toks, got in zip(prompts, remote):
+        np.testing.assert_array_equal(got,
+                                      _local_tokens(cfg, params, toks, 4))
     assert client.stats["failovers"] >= 1
+    assert client.stats["failed_sessions"] == 0
 
 
 @pytest.mark.parametrize("arch", ["minicpm-2b", "granite-8b"])

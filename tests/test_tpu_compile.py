@@ -5,10 +5,11 @@ described (not attached) ``v5e:2x2`` topology and compiles it with the
 TPU compiler, which refuses what interpret mode accepts: blocks that break
 the tiling rule, VMEM overuse, unsupported in-kernel ops.  Nothing runs,
 so these say nothing about results or times.  The KV pool's write
-programs and its latent attention are compiled the same way at
-DeepSeek-V2-Lite's serving shapes: the compiler's memory analysis must
-find no copy of the pool, and the attention over the split pool must
-read no more than over one array of rows.
+programs are compiled the same way at the serving shapes of
+granite-8b-9l's shard and DeepSeek-V2-Lite, and the latent attention at
+DeepSeek-V2-Lite's: the compiler's memory analysis must find no copy of
+the pool, and the attention over the split pool must read no more than
+over one array of rows.
 
 The topology is described inside a module fixture, never at import: only
 one process at a time may load the TPU compiler library, and every test
@@ -60,11 +61,10 @@ def _compiled_text(fn, *specs) -> str:
     return jax.jit(fn).lower(*specs).compile().as_text()
 
 
-@pytest.mark.parametrize("kv_dtype", ["fp32", "int8"])
 @pytest.mark.parametrize("layout", PAGED_LAYOUTS, ids=lambda l: l[0])
-def test_paged_decode_compiles(one_chip, layout, kv_dtype):
+def test_paged_decode_compiles(one_chip, layout):
     _, H, Hk, hd = layout
-    pool_dt = jnp.int8 if kv_dtype == "int8" else jnp.float32
+    pool_dt = jnp.float32
     specs = [
         _spec(one_chip, (SLOTS, H, hd), jnp.float32),                 # q
         _spec(one_chip, (POOL_PAGES, PAGE, Hk, hd), pool_dt),         # k pool
@@ -74,8 +74,6 @@ def test_paged_decode_compiles(one_chip, layout, kv_dtype):
         _spec(one_chip, (SLOTS, Hk, hd), jnp.float32),                # k new
         _spec(one_chip, (SLOTS, Hk, hd), jnp.float32),                # v new
     ]
-    if kv_dtype == "int8":
-        specs += [_spec(one_chip, (POOL_PAGES, Hk), jnp.float32)] * 2
 
     def fn(*args):
         return paged_attention_pallas(*args, interpret=False)
@@ -105,32 +103,50 @@ def test_moe_gating_compiles(one_chip):
     assert "tpu_custom_call" in _compiled_text(fn, spec)
 
 
+def _pool_write_temps(one_chip, pool, P, M, program):
+    """Temporary bytes of ``pool``'s append (``M`` rows) or prefill (a
+    2,048-token prompt: 65 pages) program, compiled with ``P`` pages."""
+    L, page, Hk, hd = pool.L, pool.page, pool.Hk, pool.hd
+    arrays = tuple(None if a is None else
+                   _spec(one_chip, (L, P) + a.shape[2:], a.dtype)
+                   for a in pool.arrays)
+    i32, f32 = jnp.int32, jnp.float32
+    row = _spec(one_chip, (L, Hk, hd), f32)
+    if program == "append":
+        fn = batch._append_rows
+        args = (arrays, _spec(one_chip, (M,), i32),
+                _spec(one_chip, (M,), i32), (row,) * M,
+                () if pool.latent else (row,) * M)
+    else:
+        n = 65
+        fn = batch._write_prefill
+        cache = _spec(one_chip, (L, 1, n * page, Hk, hd), f32)
+        args = (arrays, _spec(one_chip, (n,), i32), cache,
+                None if pool.latent else cache)
+    return fn.lower(*args).compile().memory_analysis().temp_size_in_bytes
+
+
 @pytest.mark.parametrize("program", ["append", "prefill"])
 def test_latent_pool_writes_compile_in_place(one_chip, program):
     """DeepSeek-V2-Lite's pool at its serving shapes (7 layers, 3,840
     pages of 32 rows of 512 + 64 numbers, 48 slots): the donated append
     and prefill scatters write in place.  A pool laid out anew around the
     scatter shows as temporaries the size of the pool (2.5 GB)."""
-    L, P, page, M, W, rope = 7, 3840, 32, 48, 576, 64
-    pool = batch.KVPool(L, 1, W, page, latent=True, rope_dim=rope)
+    pool = batch.KVPool(7, 1, 576, 32, latent=True, rope_dim=64)
     assert pool.vp is not None                       # the lane layout
-    arrays = tuple(None if a is None else
-                   _spec(one_chip, (L, P) + a.shape[2:], a.dtype)
-                   for a in pool.arrays)
-    i32 = jnp.int32
-    if program == "append":
-        fn = batch._append_rows
-        args = (arrays, None, _spec(one_chip, (M,), i32),
-                _spec(one_chip, (M,), i32), _spec(one_chip, (M,), i32),
-                (_spec(one_chip, (L, 1, W), jnp.float32),) * M, ())
-    else:
-        n = 65                                       # a 2,048-token prompt
-        fn = batch._write_prefill
-        args = (arrays, None, _spec(one_chip, (), i32),
-                _spec(one_chip, (n,), i32), _spec(one_chip, (), i32),
-                _spec(one_chip, (L, 1, n * page, 1, W), jnp.float32), None)
-    mem = fn.lower(*args).compile().memory_analysis()
-    assert mem.temp_size_in_bytes < 64 << 20, mem.temp_size_in_bytes
+    temps = _pool_write_temps(one_chip, pool, 3840, 48, program)
+    assert temps < 64 << 20, temps
+
+
+@pytest.mark.parametrize("program", ["append", "prefill"])
+def test_dense_pool_writes_compile_in_place(one_chip, program):
+    """granite-8b-9l's second shard at its serving shapes (5 layers, 8
+    KV heads of 128, 264 pages of 32, 8 slots): the donated append and
+    prefill scatters write k and v in place, far below the pool's
+    346 MB."""
+    pool = batch.KVPool(5, 8, 128, 32)
+    temps = _pool_write_temps(one_chip, pool, 264, 8, program)
+    assert temps < 16 << 20, temps
 
 
 def test_split_latent_attention_reads_no_more_than_rows(one_chip):
